@@ -1,11 +1,15 @@
-# Developer entry points.  All targets run on CPU (no TPU needed);
-# JAX_PLATFORMS=cpu keeps jax from probing for accelerators.
+# Developer entry points.  Every target but `bench` runs on the CPU
+# (JAX_PLATFORMS=cpu, which jax honours).  `bench` is the chip path: it
+# exits non-zero without a TPU, so run it — like `python chip_smoke.py`
+# — through the chip tool.  `bench-cpu-counts` is the explicit CPU
+# count lane that feeds `perf-check`.
 
 PY ?= python
 
 .PHONY: smoke test test-fast verify-fast lint-graph obs-check \
 	health-check aot-check cluster-check chaos-check \
-	durability-check sp-check perf-report perf-check bench
+	durability-check sp-check perf-report perf-check bench \
+	bench-cpu-counts
 
 # <3 min sanity gate: import + one eager op, one jitted llama forward
 # step (the driver's entry()), and a 2-virtual-device multichip train
@@ -144,3 +148,6 @@ verify-fast: lint-graph perf-check
 
 bench:
 	$(PY) bench.py
+
+bench-cpu-counts:
+	JAX_PLATFORMS=cpu $(PY) bench.py --cpu-counts

@@ -48,7 +48,7 @@ ELEMENTWISE_FLOP_PRIMS = {
 REDUCTION_FLOP_PRIMS = {
     "reduce_sum", "reduce_max", "reduce_min", "reduce_prod",
     "argmax", "argmin", "cumsum", "cumprod", "cumlogsumexp", "cummax",
-    "cummin", "reduce_precision", "psum", "psum2",
+    "cummin", "reduce_precision", "psum", "psum_invariant",
 }
 
 

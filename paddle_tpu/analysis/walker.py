@@ -11,16 +11,16 @@ from __future__ import annotations
 import numpy as np
 
 # Collective primitives audited per shard_map body.  jax lowers pmean
-# to psum+div and names the bound-axis psum "psum2" in recent versions;
-# the inventory normalizes both spellings to "psum" so contracts stay
-# version-stable.
+# to psum+div, and inside a shard_map body (the installed jax 0.9, with
+# varying-manual-axes checking on) a psum traces as "psum_invariant";
+# the inventory reports it as "psum", the name contracts are written in.
 COLLECTIVE_PRIMS = {
-    "psum", "psum2", "all_to_all", "all_gather", "all_gather_invariant",
-    "reduce_scatter", "ppermute", "pmax", "pmin",
-    # NB: shard_map's `pbroadcast` is a replication-annotation cast, not
-    # a wire collective — deliberately excluded.
+    "psum", "psum_invariant", "all_to_all", "all_gather",
+    "all_gather_invariant", "reduce_scatter", "ppermute", "pmax", "pmin",
+    # NB: shard_map's `pbroadcast`/`pvary` are replication-annotation
+    # casts, not wire collectives — deliberately excluded.
 }
-_NORMALIZE = {"psum2": "psum"}
+_NORMALIZE = {"psum_invariant": "psum"}
 
 # Primitives that force a host round-trip inside a device program.
 HOST_SYNC_PRIMS = {
@@ -111,7 +111,7 @@ def primitive_inventory(jaxpr):
 
 
 def collective_inventory(jaxpr):
-    """{collective: count} with version normalization (psum2 -> psum)."""
+    """{collective: count}, psum_invariant counted as psum."""
     inv: dict[str, int] = {}
     for eqn in iter_eqns(jaxpr):
         n = eqn.primitive.name
@@ -121,6 +121,21 @@ def collective_inventory(jaxpr):
     return inv
 
 
+def pallas_kernels(jaxpr):
+    """``[(kernel, interpreted)]`` for every ``pallas_call`` in the
+    tree: ``kernel`` is the body's ``"fn at file:line"`` source marker
+    (how a Pallas kernel announces itself under the installed jax) and
+    ``interpreted`` says whether the call runs in the Pallas
+    interpreter instead of being compiled by Mosaic."""
+    out = []
+    for eqn in iter_eqns(jaxpr):
+        if eqn.primitive.name == "pallas_call":
+            info = eqn.params["jaxpr"].debug_info
+            out.append((str(info.func_src_info),
+                        bool(eqn.params["interpret"])))
+    return out
+
+
 def name_inventory(jaxpr):
     """Set of name-ish strings in the tree: primitive names, ``name``
     params (pjit bodies), and pallas kernel src markers — the structured
@@ -128,8 +143,8 @@ def name_inventory(jaxpr):
     names: set[str] = set()
     for eqn in iter_eqns(jaxpr):
         names.add(eqn.primitive.name)
-        for key in ("name", "name_and_src_info"):
-            v = eqn.params.get(key)
-            if v is not None:
-                names.add(v if isinstance(v, str) else str(v))
+        v = eqn.params.get("name")
+        if v is not None:
+            names.add(v if isinstance(v, str) else str(v))
+    names.update(k for k, _ in pallas_kernels(jaxpr))
     return names

@@ -1,8 +1,8 @@
 """AOT compilation plane — kill cold-start with warmed executables.
 
-BENCH_r03/r04 record 18-492 s first-step compiles: fatal for elastic
-serving (a preempted replica re-compiles the world before its first
-token) and for the guardian rollback path.  The fix has three parts,
+A fresh process compiles every program before its first token: fatal
+for elastic serving (a preempted replica re-compiles the world first)
+and for the guardian rollback path.  The fix has three parts,
 mirroring what the alpa/levanter-style JAX stacks do:
 
 1. **AOT compile without real buffers** — ``CountedJit.aot_compile``
@@ -34,12 +34,15 @@ r17 — no ladder, no table, no signature hashing on the dispatch path.
 :class:`AotMissError` — the serving-fleet contract (a replica that
 would silently compile mid-traffic must fail loudly instead).
 
-Cache layout: ``PT_CACHE_DIR`` (default ``~/.cache/paddle_tpu``) is
-the shared cache root (the autotune cache lives beside it);
-``PT_COMPILE_CACHE`` (default ``<root>/compile``) holds
-``manifest.json`` + one pickled serialized executable per entry, and
-the XLA-level ``jax_compilation_cache_dir`` is pointed at an ``xla/``
-subdir so both layers persist together.
+Cache layout: ``PT_CACHE_DIR`` is the shared cache root (the autotune
+cache lives beside it); unset, it is ``paddle_tpu/`` inside jax's own
+compilation-cache directory (``JAX_COMPILATION_CACHE_DIR``, else the
+fixed ``<checkout>/.jax_cache`` — ``utils.jax_cache_dir``), so what
+decides which kernel ``auto`` picks travels with the placed cache and
+never comes from a home directory.  ``PT_COMPILE_CACHE`` (default
+``<root>/compile``) holds ``manifest.json`` + one pickled serialized
+executable per entry.  Building a :class:`CompileCache` never touches
+the process-global ``jax_compilation_cache_dir``.
 """
 from __future__ import annotations
 
@@ -52,8 +55,9 @@ import time
 MODES = ("off", "warm", "strict")
 
 #: manifest/entry schema version — bump on any layout change so stale
-#: caches are dropped (never mis-deserialized).
-CACHE_VERSION = 1
+#: caches are dropped (never mis-deserialized).  v2: entries record the
+#: device assignment they were compiled for.
+CACHE_VERSION = 2
 
 
 class AotMissError(RuntimeError):
@@ -70,10 +74,14 @@ def mode() -> str:
 
 def cache_root() -> str:
     """Shared on-disk cache root (``PT_CACHE_DIR``): the compile cache
-    and the autotune cache both live under it."""
-    return os.environ.get(
-        "PT_CACHE_DIR",
-        os.path.join(os.path.expanduser("~"), ".cache", "paddle_tpu"))
+    and the autotune cache both live under it.  Default: ``paddle_tpu/``
+    inside jax's compilation-cache directory."""
+    root = os.environ.get("PT_CACHE_DIR")
+    if root:
+        return root
+    from ..utils import jax_cache_dir
+
+    return os.path.join(jax_cache_dir(), "paddle_tpu")
 
 
 def compile_cache_dir() -> str:
@@ -213,21 +221,13 @@ class CompileCache:
     serviceability tests can inject exactly those failures.
     """
 
-    def __init__(self, path=None, wire_xla=True):
+    def __init__(self, path=None):
         self.path = str(path) if path is not None else compile_cache_dir()
         self.hits = 0
         self.misses = 0
         self.stores = 0
         self.errors = 0
         self.bytes_written = 0
-        if wire_xla:
-            # the XLA-level persistent cache rides along under xla/:
-            # even a program compiled through plain jit (PT_AOT=off, or
-            # a warm-mode miss) persists its HLO->binary step
-            from ..utils import enable_compile_cache
-
-            enable_compile_cache(
-                cache_dir=os.path.join(self.path, "xla"))
 
     # -- keys ---------------------------------------------------------------
 
@@ -316,11 +316,17 @@ class CompileCache:
                     or blob.get("versions") != list(self._versions())
                     or blob.get("cache_version") != CACHE_VERSION):
                 raise ValueError("compile-cache entry version skew")
+            import jax
             from jax.experimental.serialize_executable import (
                 deserialize_and_load)
 
-            exe = deserialize_and_load(blob["payload"], blob["in_tree"],
-                                       blob["out_tree"])
+            # load onto the device assignment the entry was compiled
+            # for — the default is EVERY local device, which a
+            # single-device program then refuses at dispatch
+            by_id = {d.id: d for d in jax.devices()}
+            exe = deserialize_and_load(
+                blob["payload"], blob["in_tree"], blob["out_tree"],
+                execution_devices=[by_id[i] for i in blob["device_ids"]])
             faults.fire("aot.cache", "after", path=fpath)
         except FileNotFoundError:
             self._count(program, hit=False)
@@ -345,9 +351,13 @@ class CompileCache:
             from jax.experimental.serialize_executable import serialize
 
             payload, in_tree, out_tree = serialize(exe)
+            # the ordered device assignment the program was compiled
+            # for (one process: every device is local)
+            devices = exe.runtime_executable().local_devices()
             blob = {"cache_version": CACHE_VERSION,
                     "versions": list(self._versions()),
                     "program": program,
+                    "device_ids": [d.id for d in devices],
                     "payload": payload,
                     "in_tree": in_tree, "out_tree": out_tree}
             os.makedirs(self.path, exist_ok=True)

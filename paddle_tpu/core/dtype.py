@@ -18,35 +18,6 @@ import numpy as np
 # creation path in this framework passes an explicit dtype.
 jax.config.update("jax_enable_x64", True)
 
-# Every Pallas call site traces under `with jax.enable_x64(False):`
-# (Mosaic rejects i64 grid constants).  Newer jax removed the top-level
-# alias, keeping only jax.experimental.enable_x64 — restore it so the
-# kernel package works across the versions we run against.  This module
-# is imported before any kernel module can be.
-if not hasattr(jax, "enable_x64"):
-    from jax.experimental import enable_x64 as _enable_x64
-
-    jax.enable_x64 = _enable_x64
-
-# Same story for shard_map: promoted to the jax namespace in newer
-# releases, only jax.experimental.shard_map here — and the replication
-# check kwarg is the old ``check_rep`` spelling, not ``check_vma``.
-# The whole distributed stack (spmd.py, pipeline.py, ring_attention.py,
-# mpu.py, moe_layer.py, cpp_extension.py) calls ``jax.shard_map`` with
-# the new spelling.
-if not hasattr(jax, "shard_map"):
-    import functools as _functools
-
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    @_functools.wraps(_shard_map)
-    def _shard_map_compat(*args, **kwargs):
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        return _shard_map(*args, **kwargs)
-
-    jax.shard_map = _shard_map_compat
-
 # Canonical dtype objects (numpy dtype instances — what jax uses natively).
 bool_ = jnp.dtype("bool")
 uint8 = jnp.dtype("uint8")

@@ -1,7 +1,7 @@
 """Error enforcement.
 
 Reference: ``paddle/common/enforce.h`` — ``PADDLE_ENFORCE_*`` macros raising
-typed errors with rich messages; error taxonomy in
+typed errors with rich messages; error classes in
 ``paddle/common/errors.h`` (InvalidArgument, NotFound, OutOfRange, ...).
 """
 from __future__ import annotations

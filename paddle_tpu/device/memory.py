@@ -6,8 +6,8 @@ peak stats) surfaced as ``paddle.device.cuda.max_memory_allocated`` etc.
 
 TPU-native: the allocator is PJRT's.  When the backend exposes
 ``jax.Device.memory_stats()`` (bytes_in_use / peak_bytes_in_use /
-bytes_limit) those are authoritative; backends that don't (e.g. tunneled
-plugins) fall back to client-side live-buffer accounting over
+bytes_limit) those are authoritative; backends that don't (the CPU
+client) fall back to client-side live-buffer accounting over
 ``jax.live_arrays()`` — the StatAllocator strategy, with the peak tracked
 as the max observed at stat calls.  ``reset_max_memory_allocated``
 establishes a session baseline in both regimes (PJRT cannot reset its

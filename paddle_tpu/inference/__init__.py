@@ -81,12 +81,12 @@ class Config:
     def set_optim_cache_dir(self, path):
         """Reference Config::SetOptimCacheDir — persists optimized
         programs.  TPU analog: the jax persistent compilation cache (the
-        compiled XLA executable IS the optimized program)."""
-        import jax
+        compiled XLA executable IS the optimized program).  Where
+        ``JAX_COMPILATION_CACHE_DIR`` places the cache from outside it
+        stays there; the directory in use is what gets recorded."""
+        from ..utils import enable_compile_cache
 
-        jax.config.update("jax_compilation_cache_dir", str(path))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        self._optim_cache_dir = str(path)
+        self._optim_cache_dir = enable_compile_cache(cache_dir=path)
 
     def use_gpu(self):
         d = getattr(self, "_device", None)

@@ -403,7 +403,8 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
     return registry.apply(nn_ops.sdpa_op, query, key, value, attn_mask,
                           drop_key, dropout=float(dropout_p),
                           causal=bool(is_causal), impl=impl,
-                          flash_blocks=flash_blocks)
+                          flash_blocks=flash_blocks,
+                          shard=nn_ops.current_kernel_mesh())
 
 
 def flash_attention(query, key, value, dropout=0.0, causal=False,

@@ -28,7 +28,9 @@ from __future__ import annotations
 import jax
 
 #: Per-chip peak dense FLOP/s (bf16) by device_kind substring.  One
-#: table for the whole repo — bench.py delegates here.
+#: table for the whole repo — bench.py delegates here.  Figures are the
+#: published per-chip peaks (Google Cloud TPU documentation; v5e: 197
+#: TFLOP/s bf16, 819 GB/s HBM).  A device_kind with no row raises.
 PEAK_FLOPS = (
     ("v6", 918e12),
     ("v5p", 459e12),
@@ -55,18 +57,18 @@ _failed_cost = set()     # programs whose cost trace raised: don't retry
 
 
 def _device_kind():
-    try:
-        d = jax.devices()[0]
-        return (getattr(d, "device_kind", "") or d.platform).lower()
-    except Exception:
-        return "cpu"
+    d = jax.devices()[0]
+    return (getattr(d, "device_kind", "") or d.platform).lower()
 
 
 def _lookup(table, kind):
     for sub, v in table:
         if sub in kind:
             return v
-    return table[-1][1]
+    raise LookupError(
+        f"no peak figures for device_kind {kind!r}: add a row (with its "
+        f"source) to obs/perf.py — an unknown device is an error, not "
+        f"a default")
 
 
 def peak_flops_per_chip(device_kind=None):

@@ -13,6 +13,8 @@ layout assignment makes this free inside a jit region.
 """
 from __future__ import annotations
 
+import contextlib as _contextlib
+import contextvars as _contextvars
 import os
 import functools as _functools
 
@@ -647,6 +649,70 @@ def dropout_raw(x, p=0.5, training=True, mode="upscale_in_train"):
 
 # -- attention --------------------------------------------------------------
 
+# Mosaic kernels cannot be partitioned by GSPMD: lowering one inside a
+# jit whose operands span several devices raises "Mosaic kernels cannot
+# be automatically partitioned. Please wrap the call in a shard_map".
+# So a sharded step names, while it traces, the mesh it runs on and the
+# axes that split the batch and the heads (``kernel_mesh``); attention
+# picks that up as the static ``shard`` argument and runs its Pallas
+# kernels per (batch, head) shard.  One device: ``shard`` is None and
+# the kernel is called directly.
+_KERNEL_MESH = _contextvars.ContextVar("kernel_mesh", default=None)
+
+
+@_contextlib.contextmanager
+def kernel_mesh(mesh, batch_axis=None, head_axis=None):
+    """While active, attention traced in this context (thread or task)
+    shards its Pallas kernels over ``mesh`` (a ``jax.sharding.Mesh``):
+    dim 0 of [B, H, S, D] over ``batch_axis``, dim 1 over ``head_axis``
+    (each a mesh axis name, a tuple of names, or None)."""
+    token = _KERNEL_MESH.set((mesh, batch_axis, head_axis))
+    try:
+        yield
+    finally:
+        _KERNEL_MESH.reset(token)
+
+
+def current_kernel_mesh():
+    return _KERNEL_MESH.get()
+
+
+def _per_shard(shard, kernel, *bhsd, seed=None):
+    """``kernel(*bhsd)`` over [B, H, S, D] operands — per shard under
+    ``shard = (mesh, batch_axis, head_axis)``, directly when None.
+
+    ``seed`` (int32 scalar), when given, is the kernel's last argument.
+    A kernel that hashes ``(seed, program id)`` sees only its shard's
+    LOCAL program ids, so under a mesh each shard's seed is moved on by
+    the programs of the shards before it
+    (``short_attention.seed_at_program``): no two shards draw the same
+    dropout mask."""
+    if shard is None:
+        return kernel(*bhsd) if seed is None else kernel(*bhsd, seed)
+    mesh, batch_axis, head_axis = shard
+    P = jax.sharding.PartitionSpec
+    spec = P(batch_axis, head_axis, None, None)
+    if seed is None:
+        body, operands, in_specs = kernel, bhsd, (spec,) * len(bhsd)
+    else:
+        from .pallas_kernels.short_attention import seed_at_program
+
+        axes = tuple(a for ax in (batch_axis, head_axis) if ax is not None
+                     for a in ((ax,) if isinstance(ax, str) else ax))
+
+        def body(*args):
+            *local, seed = args
+            b, h = local[0].shape[:2]
+            first = (jax.lax.axis_index(axes) * (b * h)) if axes else 0
+            return kernel(*local, seed_at_program(seed, first))
+
+        operands, in_specs = bhsd + (seed,), (spec,) * len(bhsd) + (P(),)
+    # check_vma=False: a pallas_call's outputs carry no varying-axes
+    # annotation for the check to verify
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=spec, check_vma=False)(*operands)
+
+
 def _fa_mod():
     from jax.experimental.pallas.ops.tpu import flash_attention as m
 
@@ -744,7 +810,7 @@ def _flash_attention_tpu(qt, kt, vt, causal, scale, blocks=None):
 
 
 def _sdpa_plain(q, k, v, mask=None, key=None, dropout=0.0, causal=False,
-                scale=None, impl="auto", flash_blocks=None):
+                scale=None, impl="auto", flash_blocks=None, shard=None):
     """Scaled dot-product attention, [B, S, H, D] layout (paddle flash-attn
     layout, nn/functional/flash_attention.py).  Computed in the MXU-friendly
     [B, H, S, D] internally.  ``key`` enables attention dropout.
@@ -764,6 +830,11 @@ def _sdpa_plain(q, k, v, mask=None, key=None, dropout=0.0, causal=False,
     round differently from einsum (bf16 MXU accumulation) and the
     short kernel's dropout mask comes from its in-kernel counter hash,
     not the host key stream.
+
+    shard: ``(mesh, batch_axis, head_axis)`` from :func:`kernel_mesh`
+    when the caller's step spans several devices — the Pallas kernels
+    then run per shard (GSPMD cannot partition a Mosaic kernel); the
+    einsum path needs nothing, GSPMD partitions it.
     """
     B, Sq, H, D = q.shape
     Hkv, Sk = k.shape[2], k.shape[1]
@@ -792,8 +863,10 @@ def _sdpa_plain(q, k, v, mask=None, key=None, dropout=0.0, causal=False,
 
         block_q = int(_autotune.lookup("long_attention_block_q",
                                        (Sq, D), default=256))
-        out = long_attention(qt, kt, vt, float(scale), block_q,
-                             bool(causal), None)
+        out = _per_shard(
+            shard, lambda q, k, v: long_attention(
+                q, k, v, float(scale), block_q, bool(causal), None),
+            qt, kt, vt)
         return jnp.swapaxes(out, 1, 2)
     # Self-authored short-sequence kernel (pallas_kernels/short_attention):
     # whole [S,S] scores VMEM-resident, in-kernel counter-hash dropout.
@@ -821,8 +894,10 @@ def _sdpa_plain(q, k, v, mask=None, key=None, dropout=0.0, causal=False,
             seed = jnp.zeros((), jnp.int32)
             p_drop = 0.0
         with jax.enable_x64(False):
-            out = short_attention(qt, kt, vt, seed, float(scale),
-                                  p_drop, bool(causal))
+            out = _per_shard(
+                shard, lambda q, k, v, seed: short_attention(
+                    q, k, v, seed, float(scale), p_drop, bool(causal)),
+                qt, kt, vt, seed=seed)
         return jnp.swapaxes(out, 1, 2)
 
     flash_ok = (mask is None and key is None and Sq == Sk
@@ -843,8 +918,10 @@ def _sdpa_plain(q, k, v, mask=None, key=None, dropout=0.0, causal=False,
         if Hkv != H:
             kt = jnp.repeat(kt, H // Hkv, axis=1)
             vt = jnp.repeat(vt, H // Hkv, axis=1)
-        out = _flash_attention_tpu(qt, kt, vt, causal, scale,
-                                   blocks=flash_blocks)
+        out = _per_shard(
+            shard, lambda q, k, v: _flash_attention_tpu(
+                q, k, v, causal, scale, blocks=flash_blocks),
+            qt, kt, vt)
         return jnp.swapaxes(out, 1, 2)
 
     grouped = Hkv != H
@@ -881,7 +958,8 @@ def _sdpa_plain(q, k, v, mask=None, key=None, dropout=0.0, causal=False,
 
 sdpa_op = register_op(
     "scaled_dot_product_attention", _sdpa_plain,
-    static_argnames=("dropout", "causal", "scale", "impl", "flash_blocks"),
+    static_argnames=("dropout", "causal", "scale", "impl", "flash_blocks",
+                     "shard"),
     nondiff_argnums=(3, 4))
 
 

@@ -39,7 +39,8 @@ Layout contract (matches PagedKVCache):
 returns        [B, KV, G, D]
 
 TPU constraints (callers gate, inference/paged.py): D % 128 == 0 (lane
-tiling), page_size % 8 == 0 (f32 sublane tiling of the DMA'd page).
+tiling), page_size % 8 == 0 (sublane tiling of the DMA'd page; 32 for
+the int8 pools).
 Off-TPU the kernel runs in interpreter mode (tests); serving uses the
 dense jnp path there.
 """
@@ -127,8 +128,8 @@ def _call(q, k_pages, v_pages, lengths, page_indices, scale):
         in_specs=[
             pl.BlockSpec((1, 1, G, D),
                          lambda b, kv, lens, tbl: (b, kv, 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),   # K pool stays in HBM
-            pl.BlockSpec(memory_space=pltpu.ANY),   # V pool stays in HBM
+            pl.BlockSpec(memory_space=pl.ANY),   # K pool stays in HBM
+            pl.BlockSpec(memory_space=pl.ANY),   # V pool stays in HBM
         ],
         out_specs=pl.BlockSpec((1, 1, G, D),
                                lambda b, kv, lens, tbl: (b, kv, 0, 0)),
@@ -250,8 +251,8 @@ def _call_quant(q, k_pages, v_pages, lengths, page_indices, k_scales,
         in_specs=[
             pl.BlockSpec((1, 1, G, D),
                          lambda b, kv, lens, tbl, ks, vs: (b, kv, 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),   # K pool stays in HBM
-            pl.BlockSpec(memory_space=pltpu.ANY),   # V pool stays in HBM
+            pl.BlockSpec(memory_space=pl.ANY),   # K pool stays in HBM
+            pl.BlockSpec(memory_space=pl.ANY),   # V pool stays in HBM
         ],
         out_specs=pl.BlockSpec((1, 1, G, D),
                                lambda b, kv, lens, tbl, ks, vs:
@@ -296,7 +297,11 @@ def paged_decode_quant(q, k_pages, v_pages, lengths, page_indices,
 
 def supported(head_dim, page_size, on_tpu):
     """Shape gate for the compiled (non-interpret) kernel: D must tile
-    to 128 lanes and a page must tile to 8 f32 sublanes.  Off-TPU the
+    to 128 lanes and a page to 8 sublanes.  The 8 holds for a bf16
+    pool too, although bf16 packs 16 rows to a tile: on the v5e
+    (jax 0.9) page_size 8 with a bf16 pool compiles and agrees with
+    the dense reference (PERF.md "Bring-up on v5e") — the DMA lands a
+    page at a static half-tile offset without complaint.  Off-TPU the
     interpreter imposes no tiling, but serving takes the dense path
     there (kernel-in-interpreter is test machinery, not a fast path)."""
     if not on_tpu:

@@ -34,6 +34,20 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
+# odd multiplier that spreads consecutive program ids over the 32-bit
+# counter space before the element index is added (_keep_mask)
+_PROGRAM_STRIDE = 747796405
+
+
+def seed_at_program(seed, first_program):
+    """The seed for a call that runs programs ``first_program ..`` of a
+    larger logical grid (one shard of a sharded batch): its local
+    program ids then count on from ``first_program``, so the shards of
+    one step draw distinct dropout masks (shard-major program order,
+    not the unsharded call's).  int32, wraps."""
+    return (jnp.asarray(seed, jnp.int32)
+            + jnp.asarray(first_program, jnp.int32)
+            * jnp.int32(_PROGRAM_STRIDE))
 
 
 def _keep_mask(seed_ref, shape, keep_prob):
@@ -47,7 +61,7 @@ def _keep_mask(seed_ref, shape, keep_prob):
     b = pl.program_id(0)
     h = pl.program_id(1)
     nh = pl.num_programs(1)
-    per_program = (seed_ref[0] + (b * nh + h) * 747796405).astype(
+    per_program = (seed_ref[0] + (b * nh + h) * _PROGRAM_STRIDE).astype(
         jnp.uint32)
     rows = jax.lax.broadcasted_iota(jnp.uint32, shape, 0)
     cols = jax.lax.broadcasted_iota(jnp.uint32, shape, 1)

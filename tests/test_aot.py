@@ -204,7 +204,7 @@ def _sds(*shape):
 def test_aot_compile_then_zero_trace_dispatch(tmp_path):
     import jax.numpy as jnp
 
-    cc = aot.CompileCache(str(tmp_path), wire_xla=False)
+    cc = aot.CompileCache(str(tmp_path))
     prog = _unit_prog()
     assert prog.aot_compile((_sds(4),), cache=cc) == "compile"
     assert prog.traces == 1
@@ -221,11 +221,11 @@ def test_aot_compile_then_zero_trace_dispatch(tmp_path):
 def test_second_program_resolves_from_disk(tmp_path):
     import jax.numpy as jnp
 
-    cc = aot.CompileCache(str(tmp_path), wire_xla=False)
+    cc = aot.CompileCache(str(tmp_path))
     _unit_prog().aot_compile((_sds(4),), cache=cc)
     assert cc.stores == 1 and cc.bytes_written > 0
     # fresh program object, fresh cache handle = a new process's view
-    cc2 = aot.CompileCache(str(tmp_path), wire_xla=False)
+    cc2 = aot.CompileCache(str(tmp_path))
     prog2 = _unit_prog()
     assert prog2.aot_compile((_sds(4),), cache=cc2) == "disk"
     assert prog2.traces == 0
@@ -249,7 +249,7 @@ def test_sealed_miss_raises(tmp_path):
 
 
 def test_corrupt_entry_drops_and_recompiles(tmp_path):
-    cc = aot.CompileCache(str(tmp_path), wire_xla=False)
+    cc = aot.CompileCache(str(tmp_path))
     _unit_prog().aot_compile((_sds(4),), cache=cc)
     ents = cc.manifest()["entries"]
     assert len(ents) == 1
@@ -257,7 +257,7 @@ def test_corrupt_entry_drops_and_recompiles(tmp_path):
                          next(iter(ents.values()))["file"])
     with open(fpath, "wb") as f:
         f.write(b"not a pickle")
-    cc2 = aot.CompileCache(str(tmp_path), wire_xla=False)
+    cc2 = aot.CompileCache(str(tmp_path))
     prog2 = _unit_prog()
     assert prog2.aot_compile((_sds(4),), cache=cc2) == "compile"
     assert cc2.errors >= 1
@@ -271,7 +271,7 @@ def test_corrupt_entry_drops_and_recompiles(tmp_path):
 
 
 def test_version_skewed_manifest_dropped(tmp_path):
-    cc = aot.CompileCache(str(tmp_path), wire_xla=False)
+    cc = aot.CompileCache(str(tmp_path))
     with open(os.path.join(str(tmp_path), "manifest.json"), "w") as f:
         json.dump({"version": 999, "entries": {"k": {}}}, f)
     assert cc.manifest()["entries"] == {}
@@ -479,7 +479,7 @@ def test_warmup_fault_fails_only_that_entry(model, point, phase):
     eng = ServingEngine(model, aot="off", **KW)
     faults.arm(point, phase, 1, "raise")
     with tempfile.TemporaryDirectory() as d:
-        cc = aot.CompileCache(d, wire_xla=False)
+        cc = aot.CompileCache(d)
         rep = eng.executor.aot_warmup(
             prefill_chunk=8, compile_cache=cc,
             ladder=aot.BucketLadder((8,)))
@@ -498,7 +498,7 @@ def test_warmup_fault_fails_only_that_entry(model, point, phase):
 def test_cache_fault_degrades_to_recompile(model, cache_dir,
                                            warm_engine, phase):
     eng = ServingEngine(model, aot="off", **KW)
-    cc = aot.CompileCache(cache_dir, wire_xla=False)
+    cc = aot.CompileCache(cache_dir)
     faults.arm("aot.cache", phase, 1, "raise")
     rep = eng.executor.aot_warmup(prefill_chunk=8, compile_cache=cc)
     assert not rep["failed"], phase

@@ -180,8 +180,15 @@ def _run_guarded(n, manager=None, policy=None):
 
 
 def test_guarded_clean_run_matches_plain_step():
-    """With no anomalies the gate must be a bit-exact no-op versus the
-    plain compiled step (same trees, +0.0 injections, ok=True)."""
+    """With no anomalies the gate must be a no-op versus the plain
+    compiled step (same trees, +0.0 injections, ok=True).
+
+    Agreement is to a few float32 ulps, not bit-exact: the guarded and
+    plain steps are two different XLA programs, and the installed
+    jaxlib 0.9's XLA:CPU fuses the AdamW chain differently in each
+    (observed 3.7e-7 relative after 4 steps; bit-equal again under
+    XLA_FLAGS=--xla_disable_hlo_passes=fusion,cpu-instruction-fusion,
+    so it is instruction fusion, not state or the compile cache)."""
     plain = _compiled()
     for i in range(4):
         plain.step(*_reg_batch(i + 1))
@@ -192,8 +199,9 @@ def test_guarded_clean_run_matches_plain_step():
         assert ok and np.isfinite(loss) and np.isfinite(gnorm)
     assert plain._t == guarded._t == 4
     for k in plain.params:
-        np.testing.assert_array_equal(np.asarray(plain.params[k]),
-                                      np.asarray(guarded.params[k]))
+        np.testing.assert_allclose(np.asarray(plain.params[k]),
+                                   np.asarray(guarded.params[k]),
+                                   rtol=2e-6, atol=1e-8)
 
 
 @pytest.mark.parametrize("spec", [

@@ -93,8 +93,9 @@ def test_sdpa_auto_routes_long_kernel():
         lambda qd, kd, vd: _sdpa_plain(qd, kd, vd, causal=True,
                                        impl="auto"))(
         q._data, k._data, v._data)
-    # The kernel announces itself via pallas_call's name_and_src_info;
-    # walker.name_inventory surfaces it without string-ifying the jaxpr.
+    # The kernel announces itself via its body's "fn at file:line"
+    # marker; walker.name_inventory surfaces it without string-ifying
+    # the jaxpr.
     names = walker.name_inventory(jaxpr)
     assert any("long_attention" in s for s in names), sorted(names)
     out_auto = F.scaled_dot_product_attention(q, k, v, is_causal=True)

@@ -142,11 +142,10 @@ def test_pjit_subjaxpr_recursion():
 
 
 def test_shard_map_subjaxpr_recursion():
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     mesh = Mesh(np.array(jax.devices()[:1]), ("x",))
-    f = shard_map(lambda a, b: a @ b, mesh=mesh,
+    f = jax.shard_map(lambda a, b: a @ b, mesh=mesh,
                   in_specs=(P(), P()), out_specs=P())
     rep = estimate_fn_cost(f, _sds(4, 8), _sds(8, 16))
     assert rep.flops == 1024
@@ -227,7 +226,11 @@ def test_peak_tables_substring_lookup():
     assert perf.peak_flops_per_chip("TPU v5p") == 459e12
     assert perf.peak_flops_per_chip("TPU v5 lite") == 197e12
     assert perf.peak_flops_per_chip("TPU v4") == 275e12
-    assert perf.peak_flops_per_chip("mystery-device") == 1e12  # fallback
+    # an unknown device is an error, never the table's last row
+    with pytest.raises(LookupError, match="no-such-device"):
+        perf.peak_flops_per_chip("no-such-device")
+    with pytest.raises(LookupError, match="no-such-device"):
+        perf.peak_hbm_bytes_s("no-such-device")
     assert perf.ridge_intensity("cpu") == 20.0
 
 
