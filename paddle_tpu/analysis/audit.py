@@ -87,21 +87,41 @@ class CountedJit:
                 "jit_dispatches_total",
                 "Jitted program dispatches per program",
                 labels=("program",)).labels(program=self.name).inc()
-        if self._exe:
-            from ..core import aot
+        # the one span every serving program's dispatch gets (only the
+        # serving executor builds CountedJits); ``traced``: this
+        # dispatch traced, and compiled or loaded, the program.  The
+        # call is made from THIS frame: one more Python frame between
+        # here and the jitted call made every trace a fifth slower on
+        # the chip's host (PERF.md section 6, PR 26)
+        traces = self.traces
+        with obs.span("jit.dispatch", cat="serve", program=self.name,
+                      traced=False) as sp:
+            try:
+                return (self._aot_executable(args, kwargs)
+                        or self._jit)(*args, **kwargs)
+            finally:
+                if self.traces > traces:
+                    sp.set(traced=True)
 
-            exe = self._exe.get(aot.signature(args, kwargs))
-            if exe is not None:
-                self.aot_hits += 1
-                return exe(*args, **kwargs)
-            self.aot_misses += 1
-            if self._sealed:
-                raise aot.AotMissError(
-                    f"[{self.name}] PT_AOT=strict: dispatch at an "
-                    f"un-warmed signature after seal() — the shape "
-                    f"ladder must cover every runtime shape "
-                    f"({aot.signature(args, kwargs)})")
-        return self._jit(*args, **kwargs)
+    def _aot_executable(self, args, kwargs):
+        """The executable installed for this signature, or None (the jit
+        path); a miss on a sealed program raises."""
+        if not self._exe:
+            return None
+        from ..core import aot
+
+        exe = self._exe.get(aot.signature(args, kwargs))
+        if exe is not None:
+            self.aot_hits += 1
+            return exe
+        self.aot_misses += 1
+        if self._sealed:
+            raise aot.AotMissError(
+                f"[{self.name}] PT_AOT=strict: dispatch at an "
+                f"un-warmed signature after seal() — the shape "
+                f"ladder must cover every runtime shape "
+                f"({aot.signature(args, kwargs)})")
+        return None
 
     def lower(self, *args, **kwargs):
         return self._jit.lower(*args, **kwargs)

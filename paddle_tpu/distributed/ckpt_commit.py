@@ -209,9 +209,7 @@ class CheckpointManager:
             self._inflight = handle
             return handle
         t0 = h.clock() if h is not None else None
-        sp = (h.tracer.span("ckpt.save", cat="train", step=int(step))
-              if h is not None else obs.NULL_SPAN)
-        with sp:
+        with obs.span("ckpt.save", cat="train", step=int(step)):
             _job()
         if h is not None:
             h.registry.histogram(

@@ -20,24 +20,18 @@ from ..nn.layers import Layer
 from .callbacks import Callback, CallbackList, ModelCheckpoint, ProgBarLogger
 
 
-def _timed_batches(loader, timer=None):
+def _timed_batches(loader, timer):
     """Iterate ``loader``, timing each ``next()`` under a
-    ``train.data_wait`` span when telemetry is on — input starvation
-    becomes visible as wide data-wait slices in the trace.  ``timer``
-    (an ``obs.perf.StepTimer``) additionally accumulates the wait into
-    the step's ``data_wait`` phase."""
+    ``train.data_wait`` span — input starvation becomes visible as wide
+    data-wait slices in the trace.  ``timer`` (an
+    ``obs.perf.StepTimer``) additionally accumulates the wait into the
+    step's ``data_wait`` phase."""
     it = iter(loader)
     while True:
-        h = obs.handle()
         try:
-            ph = (timer.phase("data_wait") if timer is not None
-                  else obs.NULL_SPAN)
-            with ph:
-                if h is not None:
-                    with h.tracer.span("train.data_wait", cat="train"):
-                        batch = next(it)
-                else:
-                    batch = next(it)
+            with timer.phase("data_wait"), \
+                    obs.span("train.data_wait", cat="train"):
+                batch = next(it)
         except StopIteration:
             return
         yield batch
@@ -308,11 +302,8 @@ class Model:
             for step, batch in enumerate(_timed_batches(loader, timer)):
                 cbk.on_train_batch_begin(step)
                 ins, labs = self._split_batch(batch)
-                h = obs.handle()
-                sp = (h.tracer.span("train.fit_step", cat="train",
-                                    epoch=epoch, step=step)
-                      if h is not None else obs.NULL_SPAN)
-                with sp:
+                with obs.span("train.fit_step", cat="train",
+                              epoch=epoch, step=step) as sp:
                     with timer.phase("compute"):
                         if guardian is not None:
                             loss, metrics = self._guarded_train_batch(

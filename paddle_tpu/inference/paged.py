@@ -21,6 +21,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from .. import obs as _obs
 from ..core.tensor import Tensor
 from ..ops import registry as _registry
 from ..testing import faults as _faults
@@ -427,8 +428,6 @@ class PagedKVCache:
         self.page_table[seq, slot] = new
         self.page_refs[old] -= 1
         self.cow_count += 1
-        from .. import obs as _obs
-
         h = _obs.handle()
         if h is not None:
             h.recorder.record("kv.cow", seq=seq, slot=slot,
@@ -542,17 +541,21 @@ class PagedKVCache:
             return
         k = jnp.asarray(k, self.k_pages.dtype)
         v = jnp.asarray(v, self.v_pages.dtype)
-        t = 0
-        while t < T:
-            pos = start + t
-            page, off = pos // ps, pos % ps
-            n = min(ps - off, T - t)  # span within this page
-            pid = int(self.page_table[seq, page])
-            self.k_pages = self.k_pages.at[:, :, pid, off:off + n].set(
-                k[:, :, t:t + n])
-            self.v_pages = self.v_pages.at[:, :, pid, off:off + n].set(
-                v[:, :, t:t + n])
-            t += n
+        t = pages = 0
+        with _obs.span("kv.write", cat="serve") as sp:
+            while t < T:
+                pos = start + t
+                page, off = pos // ps, pos % ps
+                n = min(ps - off, T - t)  # span within this page
+                pid = int(self.page_table[seq, page])
+                self.k_pages = self.k_pages.at[
+                    :, :, pid, off:off + n].set(k[:, :, t:t + n])
+                self.v_pages = self.v_pages.at[
+                    :, :, pid, off:off + n].set(v[:, :, t:t + n])
+                t += n
+                pages += 1
+            # eager device ops issued: one scatter into each pool a page
+            sp.set(pages=pages, dispatches=2 * pages)
         self.lengths[seq] = start + T
 
     def write_sharded(self, seq: int, k, v, start: int,
@@ -593,8 +596,6 @@ class PagedKVCache:
         mesh.  Returns the number of pages covered."""
         _faults.fire("sp.gather", "before")
         pages = -(-int(self.lengths[seq]) // self.page_size)
-        from .. import obs as _obs
-
         h = _obs.handle()
         if h is not None:
             h.registry.counter(

@@ -1142,7 +1142,8 @@ class PagedExecutor:
         self.prefill_events.append((sid, int(ids.shape[1])))
         logits, k, v = self._jit_prefill(self.layers, self.tops, ids)
         self.cache.prefill(sid, k, v)
-        tok = int(jnp.argmax(logits))
+        with obs.span("exec.fetch", cat="serve", what="prefill"):
+            tok = int(jnp.argmax(logits))
         self.last_token[sid] = tok
         return tok
 
@@ -1151,7 +1152,8 @@ class PagedExecutor:
         """One prefill chunk at position ``start``; attends the slot's
         already-written pages.  When ``final``, records and returns the
         prompt's first greedy token; else returns None."""
-        past_k, past_v = self.cache.gather_dense(sid, start)
+        with obs.span("kv.gather", cat="serve", past_tokens=start):
+            past_k, past_v = self.cache.gather_dense(sid, start)
         if self.aot_ladder is not None:
             # bucket the past cover so its shape comes from the finite
             # warmup set: pad to the next page bucket with zeros — the
@@ -1166,7 +1168,8 @@ class PagedExecutor:
                 pad = ((0, 0), (0, 0), (0, (b - pages) * ps), (0, 0))
                 past_k = jnp.pad(past_k, pad)
                 past_v = jnp.pad(past_v, pad)
-        ids = jnp.asarray(np.asarray(chunk_ids)[None], jnp.int32)
+        with obs.span("exec.prep", cat="serve", tokens=len(chunk_ids)):
+            ids = jnp.asarray(np.asarray(chunk_ids)[None], jnp.int32)
         self.prefill_events.append((sid, int(ids.shape[1])))
         # past_k/past_v are donated: gather_dense returns fresh dense
         # copies nothing else references, and when the past cover
@@ -1188,7 +1191,8 @@ class PagedExecutor:
             # transition even though the last (short) chunk ran dense
             self.cache.gather_shards(sid)
             self._sp_written.discard(sid)
-        tok = int(jnp.argmax(logits))
+        with obs.span("exec.fetch", cat="serve", what="prefill_chunk"):
+            tok = int(jnp.argmax(logits))
         self.last_token[sid] = tok
         return tok
 
@@ -1264,7 +1268,8 @@ class PagedExecutor:
             return None
         self.cache.gather_shards(sid)
         self._sp_written.discard(sid)
-        tok = int(jnp.argmax(logits))
+        with obs.span("exec.fetch", cat="serve", what="prefill_sp"):
+            tok = int(jnp.argmax(logits))
         self.last_token[sid] = tok
         return tok
 
@@ -1275,20 +1280,23 @@ class PagedExecutor:
         if not sids:
             return {}
         cache = self.cache
-        # batch-atomic page reservation BEFORE the jitted
-        # write-then-attend: a per-sequence loop would strand earlier
-        # sequences' fresh pages when a later one exhausts the pool
-        cache.reserve(sids, extra_tokens=1)
-        # the in-graph page write must never land on a shared page
-        for s in sids:
-            pos = int(cache.lengths[s])
-            cache.make_writable(s, pos, pos + 1)
-        ids = jnp.asarray([self.last_token[s] for s in sids], jnp.int32)
-        positions = jnp.asarray([int(cache.lengths[s]) for s in sids],
-                                jnp.int32)
-        tables = jnp.asarray(np.maximum(cache.page_table[sids], 0))
-        lengths = jnp.asarray(cache.lengths[sids])
-        kp, vp = self._pools()
+        with obs.span("exec.prep", cat="serve", batch=len(sids)):
+            # batch-atomic page reservation BEFORE the jitted
+            # write-then-attend: a per-sequence loop would strand
+            # earlier sequences' fresh pages when a later one exhausts
+            # the pool
+            cache.reserve(sids, extra_tokens=1)
+            # the in-graph page write must never land on a shared page
+            for s in sids:
+                pos = int(cache.lengths[s])
+                cache.make_writable(s, pos, pos + 1)
+            ids = jnp.asarray([self.last_token[s] for s in sids],
+                              jnp.int32)
+            positions = jnp.asarray(
+                [int(cache.lengths[s]) for s in sids], jnp.int32)
+            tables = jnp.asarray(np.maximum(cache.page_table[sids], 0))
+            lengths = jnp.asarray(cache.lengths[sids])
+            kp, vp = self._pools()
         logits, kps, vps = self._jit_decode(
             self.layers, self.tops, ids, positions, kp, vp, lengths,
             tables)
@@ -1296,7 +1304,8 @@ class PagedExecutor:
         for s in sids:
             cache.lengths[s] += 1
         # single batched argmax + ONE host transfer for the whole step
-        toks = np.asarray(jnp.argmax(logits, axis=-1))
+        with obs.span("exec.fetch", cat="serve", what="decode"):
+            toks = np.asarray(jnp.argmax(logits, axis=-1))
         out = {}
         for i, s in enumerate(sids):
             tok = int(toks[i])

@@ -47,7 +47,6 @@ from __future__ import annotations
 import numpy as np
 
 from ... import obs
-from ...profiler import RecordEvent
 from ...testing import faults
 from .request import Request, RequestState
 from .wal import stream_crc
@@ -101,9 +100,9 @@ class Scheduler:
         self.running: list = []      # hold a slot, decoding
         self.tick = 0                # logical clock (iterations)
         self._last_decode_batch = 0
-        # telemetry handle cached at construction: the off path is one
-        # None check per site, and tests reconfigure obs BEFORE
-        # building the engine under test
+        # operator-plane handle cached at construction: the off path is
+        # one None check per site, and tests reconfigure obs BEFORE
+        # building the engine under test (spans go to obs.span always)
         self._obs = obs.handle()
         # write-ahead request journal (None = off, bit-exact): the
         # scheduler owns the admit/token/finish records — every token
@@ -135,10 +134,8 @@ class Scheduler:
     def add(self, req: Request) -> None:
         self.requests[req.rid] = req
         self.metrics.on_submit(req, self.tick)
-        if self._obs is not None:
-            self._obs.tracer.instant(
-                "req.submit", cat="serve", trace_id=req.rid,
-                prompt_tokens=len(req.prompt_ids), tick=self.tick)
+        obs.instant("req.submit", cat="serve", trace_id=req.rid,
+                    prompt_tokens=len(req.prompt_ids), tick=self.tick)
         ex = self.executor
         budget_tokens = (ex.cache.max_pages_per_seq
                          * ex.cache.page_size)
@@ -160,17 +157,16 @@ class Scheduler:
         faults.fire("serve.step", "before")
         self.tick += 1
         emitted: dict = {}
-        h = self._obs
-        sp = (h.tracer.span("serve.step", cat="serve", tick=self.tick)
-              if h is not None else obs.NULL_SPAN)
-        with sp, RecordEvent("serve.step"):
+        with obs.span("serve.step", cat="serve", tick=self.tick):
             if self.async_mode:
                 self._step_async(emitted)
             else:
-                self._sweep_cancelled()
-                self._sweep_deadlines()
+                with obs.span("serve.sweep", cat="serve"):
+                    self._sweep_cancelled()
+                    self._sweep_deadlines()
                 self._decode(emitted)
-                self._admit()
+                with obs.span("serve.admit", cat="serve") as sp:
+                    sp.set(admitted=self._admit())
                 self._prefill(emitted)
         self.metrics.on_step(
             decode_batch=self._last_decode_batch,
@@ -238,23 +234,21 @@ class Scheduler:
             self._decode_spec(emitted)
             return
         self._last_decode_batch = 0
-        run = self._reserve_decode_batch(lambda sids, by_sid: 1)
-        if not run:
-            return
-        sids = sorted(r.sid for r in run)
-        by_sid = {r.sid: r for r in run}
-        faults.fire("serve.decode", "before")
-        h = self._obs
-        sp = (h.tracer.span("serve.decode", cat="serve",
-                            batch=len(sids), tick=self.tick)
-              if h is not None else obs.NULL_SPAN)
-        with sp, RecordEvent("serve.decode"):
+        with obs.span("serve.decode", cat="serve", batch=0,
+                      tick=self.tick) as sp:
+            run = self._reserve_decode_batch(lambda sids, by_sid: 1)
+            if not run:
+                return
+            sids = sorted(r.sid for r in run)
+            by_sid = {r.sid: r for r in run}
+            sp.set(batch=len(sids))
+            faults.fire("serve.decode", "before")
             toks = self.executor.decode(sids)
-        self._last_decode_batch = len(sids)
-        self.metrics.on_decode_tokens(len(sids))
-        for sid in sids:
-            self._on_token(by_sid[sid], toks[sid], emitted)
-        faults.fire("serve.decode", "after")
+            self._last_decode_batch = len(sids)
+            self.metrics.on_decode_tokens(len(sids))
+            for sid in sids:
+                self._on_token(by_sid[sid], toks[sid], emitted)
+            faults.fire("serve.decode", "after")
 
     # -- speculative decode (draft -> batched verify -> rollback) -------
 
@@ -309,12 +303,8 @@ class Scheduler:
         dr = [drafts[by_sid[s].rid][:lim - 1]
               for s, lim in zip(sids, lims)]
         faults.fire("spec.verify", "before")
-        h = self._obs
-        sp = (h.tracer.span("serve.verify", cat="serve",
-                            batch=len(sids), tick=self.tick,
-                            drafted=sum(len(v) for v in dr))
-              if h is not None else obs.NULL_SPAN)
-        with sp, RecordEvent("serve.decode"):
+        with obs.span("serve.verify", cat="serve", batch=len(sids),
+                      tick=self.tick, drafted=sum(len(v) for v in dr)):
             toks, accepted = ex.verify(sids, dr, lims, self.spec.k)
         self._last_decode_batch = len(sids)
         self.metrics.on_decode_step(
@@ -332,19 +322,22 @@ class Scheduler:
         faults.fire("spec.verify", "after")
         faults.fire("spec.rollback", "before")
         ex.rollback([r.sid for r in run if r.sid is not None])
-        if h is not None:
-            # per-request rollback journal: the rejected-draft tail of
-            # every verified window is trimmed here
-            for i, sid in enumerate(sids):
-                rejected = len(dr[i]) - accepted[sid]
-                if rejected > 0:
+        self._journal_rollbacks(sids, by_sid, dr, accepted)
+        faults.fire("spec.rollback", "after")
+
+    def _journal_rollbacks(self, sids, by_sid, dr, accepted):
+        """Per-request rollback journal: the rejected-draft tail of
+        every verified window has just been trimmed."""
+        h = self._obs
+        for i, sid in enumerate(sids):
+            rejected = len(dr[i]) - accepted[sid]
+            if rejected > 0:
+                obs.instant("req.spec_rollback", cat="serve",
+                            trace_id=by_sid[sid].rid, rejected=rejected)
+                if h is not None:
                     h.recorder.record("spec.rollback",
                                       rid=by_sid[sid].rid,
                                       rejected=rejected, tick=self.tick)
-                    h.tracer.instant("req.spec_rollback", cat="serve",
-                                     trace_id=by_sid[sid].rid,
-                                     rejected=rejected)
-        faults.fire("spec.rollback", "after")
 
     # -- double-buffered execution (PT_ASYNC_EXEC=on) -------------------
 
@@ -395,11 +388,8 @@ class Scheduler:
         ph["plan"] = t1 - t0
         if plan is None:
             return t1
-        h = self._obs
-        sp = (h.tracer.span("serve.decode_async", cat="serve",
-                            batch=len(plan.sids), tick=self.tick)
-              if h is not None else obs.NULL_SPAN)
-        with sp, RecordEvent("serve.decode"):
+        with obs.span("serve.decode_async", cat="serve",
+                      batch=len(plan.sids), tick=self.tick):
             pending = self.executor.decode_async(plan.sids)
             t2 = clk()
             ph["dispatch"] = t2 - t1
@@ -448,12 +438,8 @@ class Scheduler:
         plan = StepPlan(self.tick, sids, by_sid, kind="verify",
                         drafts=dr)
         faults.fire("spec.verify", "before")
-        h = self._obs
-        sp = (h.tracer.span("serve.verify", cat="serve",
-                            batch=len(sids), tick=self.tick,
-                            drafted=sum(len(v) for v in dr))
-              if h is not None else obs.NULL_SPAN)
-        with sp, RecordEvent("serve.decode"):
+        with obs.span("serve.verify", cat="serve", batch=len(sids),
+                      tick=self.tick, drafted=sum(len(v) for v in dr)):
             pending = ex.verify_async(sids, dr, lims, self.spec.k)
             t2 = clk()
             ph["dispatch"] = t2 - t1
@@ -475,11 +461,10 @@ class Scheduler:
         if plan is not None and not self._plan_valid(plan):
             faults.fire("async.replan", "before")
             self.replans += 1
+            obs.instant("async.replan", cat="serve", tick=self.tick)
             if self._obs is not None:
                 self._obs.recorder.record("async.replan",
                                           tick=self.tick)
-                self._obs.tracer.instant("async.replan", cat="serve",
-                                         tick=self.tick)
             faults.fire("async.replan", "after")
             plan = None
         if plan is None:
@@ -576,17 +561,7 @@ class Scheduler:
         faults.fire("spec.rollback", "before")
         self.executor.rollback(
             [r.sid for r in by_sid.values() if r.sid is not None])
-        h = self._obs
-        if h is not None:
-            for i, sid in enumerate(sids):
-                rejected = len(dr[i]) - accepted[sid]
-                if rejected > 0:
-                    h.recorder.record("spec.rollback",
-                                      rid=by_sid[sid].rid,
-                                      rejected=rejected, tick=self.tick)
-                    h.tracer.instant("req.spec_rollback", cat="serve",
-                                     trace_id=by_sid[sid].rid,
-                                     rejected=rejected)
+        self._journal_rollbacks(sids, by_sid, dr, accepted)
         faults.fire("spec.rollback", "after")
 
     def _publish_phases(self, ph):
@@ -636,8 +611,10 @@ class Scheduler:
         budget = ex.cache.max_pages_per_seq * ex.cache.page_size
         return min(prompt_tokens + lookahead, budget)
 
-    def _admit(self):
+    def _admit(self) -> int:
+        """Admits what fits; returns how many requests it admitted."""
         ex = self.executor
+        admitted = 0
         while self.queue:
             req = self._pick_next()
             hit_tokens, hit_pages = 0, []
@@ -676,12 +653,12 @@ class Scheduler:
             self.queue.remove(req)
             self.prefilling.append(req)
             self.metrics.on_sched(req, self.tick)
+            admitted += 1
+            obs.instant("req.admit", cat="serve", trace_id=req.rid,
+                        sid=req.sid, tick=self.tick,
+                        cached_tokens=int(hit_tokens),
+                        resume=int(req.preempt_count > 0))
             if self._obs is not None:
-                self._obs.tracer.instant(
-                    "req.admit", cat="serve", trace_id=req.rid,
-                    sid=req.sid, tick=self.tick,
-                    cached_tokens=int(hit_tokens),
-                    resume=int(req.preempt_count > 0))
                 self._obs.events.log(
                     "req.admit", rid=req.rid, tick=self.tick,
                     cached_tokens=int(hit_tokens),
@@ -690,6 +667,7 @@ class Scheduler:
                 self.wal.append({"t": "admit", "rid": req.rid,
                                  "tick": self.tick})
             faults.fire("serve.admit", "after")
+        return admitted
 
     def _pick_next(self):
         if self.policy == "priority":
@@ -725,37 +703,34 @@ class Scheduler:
             if ladder is not None:
                 chunk = ladder.floor(chunk)
             final = start + chunk == total
-            try:
-                # page work FIRST, outside the per-request bracket: a
-                # pool-exhausted raise preempts (not fails) the request,
-                # and an injected prefix.cow fault escapes step() with
-                # the pool consistent — the next step() retries cleanly
-                self.executor.prepare_write(req.sid, start, chunk)
-            except RuntimeError as e:
-                if _POOL_EXHAUSTED not in str(e):
-                    raise
-                self._preempt(req)
-                continue
-            try:
-                faults.fire("serve.request", "before")
-                h = self._obs
-                sp = (h.tracer.span("req.prefill", cat="serve",
-                                    trace_id=req.rid, start=start,
-                                    tokens=chunk, final=final,
-                                    tick=self.tick)
-                      if h is not None else obs.NULL_SPAN)
-                # long prompts plan sequence-parallel: above the
-                # (rung-quantized) length threshold, and only when the
-                # chunk stripes evenly with >= 2 rows per rank —
-                # everything else is the bit-exact single-device path,
-                # so PT_SP_PREFILL=off changes nothing at all
-                spn = getattr(self.executor, "sp_degree", 1)
-                use_sp = (
-                    spn > 1
-                    and total >=
-                    self.executor.sp_min_tokens_effective()
-                    and chunk % spn == 0 and chunk >= 2 * spn)
-                with sp, RecordEvent("serve.prefill"):
+            with obs.span("req.prefill", cat="serve", trace_id=req.rid,
+                          start=start, tokens=chunk, final=final,
+                          tick=self.tick):
+                try:
+                    # page work FIRST, outside the per-request bracket:
+                    # a pool-exhausted raise preempts (not fails) the
+                    # request, and an injected prefix.cow fault escapes
+                    # step() with the pool consistent — the next step()
+                    # retries cleanly
+                    self.executor.prepare_write(req.sid, start, chunk)
+                except RuntimeError as e:
+                    if _POOL_EXHAUSTED not in str(e):
+                        raise
+                    self._preempt(req)
+                    continue
+                try:
+                    faults.fire("serve.request", "before")
+                    # long prompts plan sequence-parallel: above the
+                    # (rung-quantized) length threshold, and only when
+                    # the chunk stripes evenly with >= 2 rows per rank —
+                    # everything else is the bit-exact single-device
+                    # path, so PT_SP_PREFILL=off changes nothing at all
+                    spn = getattr(self.executor, "sp_degree", 1)
+                    use_sp = (
+                        spn > 1
+                        and total >=
+                        self.executor.sp_min_tokens_effective()
+                        and chunk % spn == 0 and chunk >= 2 * spn)
                     if (start == 0 and final and ladder is None
                             and not use_sp):
                         tok = self.executor.prefill(req.sid, ids)
@@ -767,18 +742,19 @@ class Scheduler:
                         tok = self.executor.prefill_chunk(
                             req.sid, ids[start:start + chunk], start,
                             final)
-                faults.fire("serve.request", "after")
-            except RuntimeError as e:
-                if _POOL_EXHAUSTED in str(e):
-                    # decodes ate the pages between admission and this
-                    # chunk: give the slot back and retry via the queue
-                    self._preempt(req)
+                    faults.fire("serve.request", "after")
+                except RuntimeError as e:
+                    if _POOL_EXHAUSTED in str(e):
+                        # decodes ate the pages between admission and
+                        # this chunk: give the slot back and retry via
+                        # the queue
+                        self._preempt(req)
+                        continue
+                    self._fail(req, e)
                     continue
-                self._fail(req, e)
-                continue
-            except Exception as e:  # poisoned request fails ALONE
-                self._fail(req, e)
-                continue
+                except Exception as e:  # poisoned request fails ALONE
+                    self._fail(req, e)
+                    continue
             req.prefill_done = start + chunk
             self.metrics.on_prefill_tokens(chunk)
             if final:
@@ -813,10 +789,8 @@ class Scheduler:
                              "i": len(req.generated) - 1})
         if req.first_token_step is None:
             self.metrics.on_first_token(req, self.tick)
-            if self._obs is not None:
-                self._obs.tracer.instant(
-                    "req.first_token", cat="serve", trace_id=req.rid,
-                    tick=self.tick)
+            obs.instant("req.first_token", cat="serve", trace_id=req.rid,
+                        tick=self.tick)
         if (self.eos_token_id is not None
                 and int(tok) == int(self.eos_token_id)):
             self._finish(req, RequestState.FINISHED, "eos")
@@ -835,14 +809,13 @@ class Scheduler:
         prefilled again and decoding resumes where it left off."""
         self.metrics.on_preempt(req)
         req.preempt_count += 1
+        obs.instant("req.preempt", cat="serve", trace_id=req.rid,
+                    tick=self.tick, preempt_count=req.preempt_count)
         if self._obs is not None:
             self._obs.recorder.record(
                 "serve.preempt", rid=req.rid, tick=self.tick,
                 preempt_count=req.preempt_count,
                 generated=len(req.generated))
-            self._obs.tracer.instant(
-                "req.preempt", cat="serve", trace_id=req.rid,
-                tick=self.tick, preempt_count=req.preempt_count)
         self._release(req)
         if req.preempt_count > self.max_preemptions:
             self._finish(req, RequestState.EVICTED, "preempt_budget")
@@ -884,11 +857,10 @@ class Scheduler:
                 "t": "finish", "rid": req.rid, "state": state.value,
                 "reason": reason, "n": len(req.generated),
                 "crc": stream_crc(req.generated)})
+        obs.instant("req.finish", cat="serve", trace_id=req.rid,
+                    tick=self.tick, state=state.value, reason=reason,
+                    tokens=len(req.generated))
         if self._obs is not None:
-            self._obs.tracer.instant(
-                "req.finish", cat="serve", trace_id=req.rid,
-                tick=self.tick, state=state.value, reason=reason,
-                tokens=len(req.generated))
             self._obs.events.log(
                 "req.finish", rid=req.rid, tick=self.tick,
                 state=state.value, reason=reason,

@@ -579,12 +579,13 @@ class CompiledTrainStep:
         # keeps weak-typed ints int32 (XLA-friendly) and lets the pallas
         # flash-attention kernel lower (its mosaic pipeline chokes on the
         # int64 indices that global x64 mode would introduce).
-        with jax.enable_x64(False), self._kernel_mesh():
-            batch = [self._place_batch(b) for b in batch]
-            self._capture_lint_batch(batch)
-            sp = (h.tracer.span("train.step", cat="train", t=self._t)
-                  if h is not None else obs.NULL_SPAN)
-            with sp:
+        with obs.span("train.step", cat="train", t=self._t), \
+                jax.enable_x64(False), self._kernel_mesh():
+            with obs.span("train.place", cat="train"):
+                batch = [self._place_batch(b) for b in batch]
+                self._capture_lint_batch(batch)
+            with obs.span("jit.dispatch", cat="train",
+                          program="train.step"):
                 (self.params, self._master, self._m, self._v, loss) = \
                     self._step(self.params, self._master, self._m,
                                self._v, jnp.asarray(self._t, jnp.float32),
@@ -643,14 +644,15 @@ class CompiledTrainStep:
         else:
             lr_val = float(self.lr)
         batch = [b._data if isinstance(b, Tensor) else b for b in batch]
-        with jax.enable_x64(False), self._kernel_mesh():
-            batch = [self._place_batch(b) for b in batch]
-            self._capture_lint_batch(batch)
+        with obs.span("train.guarded_step", cat="train",
+                      t=self._t) as sp, \
+                jax.enable_x64(False), self._kernel_mesh():
+            with obs.span("train.place", cat="train"):
+                batch = [self._place_batch(b) for b in batch]
+                self._capture_lint_batch(batch)
             gate = jnp.asarray([threshold, l_inj, g_inj], jnp.float32)
-            sp = (h.tracer.span("train.guarded_step", cat="train",
-                                t=self._t)
-                  if h is not None else obs.NULL_SPAN)
-            with sp:
+            with obs.span("jit.dispatch", cat="train",
+                          program="train.guarded_step"):
                 (self.params, self._master, self._m, self._v, loss,
                  gnorm, ok) = self._guarded(
                     self.params, self._master, self._m, self._v,
@@ -658,8 +660,8 @@ class CompiledTrainStep:
                     *batch)
         faults.fire("train.step", "after")
         loss_f, gnorm_f, ok_b = float(loss), float(gnorm), bool(ok)
+        sp.set(loss=loss_f, ok=ok_b)
         if h is not None:
-            sp.set(loss=loss_f, ok=ok_b)
             wall = h.clock() - t0
             h.registry.counter(
                 "train_steps_total", "Optimizer steps dispatched").inc()

@@ -1,27 +1,37 @@
-"""Unified telemetry plane: metric registry + trace spans + flight
-recorder, gated by ``PT_OBS={off,on}``.
+"""Unified telemetry plane: trace spans, always on, and the operator
+plane (metric registry, event log, flight recorder, SLO engine, httpd,
+``obs.perf``) gated by ``PT_OBS={off,on}``.
 
-One process-wide bundle (:func:`handle`) holds the three surfaces; the
-whole layer is OFF by default and the off path is one cached ``None``
-check per producer site — no allocation, no clock read, bit-identical
-behavior (asserted by tests/test_obs.py's parity test).
+**Spans.**  One process-wide :class:`Tracer` (:func:`tracer`) exists
+whatever ``PT_OBS`` says; :func:`span` and :func:`instant` always
+record into its bounded in-memory ring, on ``time.perf_counter``, with
+the id of the span that was open when they began (``parent``).  A span
+is two clock reads, one small object and one ``deque.append``; the
+budget is a handful per ``ServingEngine.step()`` / train step and none
+per token, page or eager op.  ``obs.tracer().export_chrome(path)``
+writes them out.
+
+**Operator plane.**  One process-wide bundle (:func:`handle`) holds the
+other surfaces; it is OFF by default and the off path is one cached
+``None`` check per producer site — bit-identical behavior (asserted by
+tests/test_obs.py's parity test).
 
 Producer idiom (hot paths cache the handle)::
 
     from paddle_tpu import obs
+
+    with obs.span("train.step", cat="train"):   # always recorded
+        ...
 
     h = obs.handle()
     if h is not None:
         h.recorder.record("serve.preempt", rid=req.rid)
         h.registry.counter("serve_preemptions_total").inc()
 
-    with obs.span("train.step", cat="train"):   # null ctx when off
-        ...
-
 Export surfaces:
 
+- ``obs.tracer().export_chrome(path)`` — Perfetto-viewable
 - ``obs.handle().registry.prometheus_text()`` / ``.snapshot()``
-- ``obs.handle().tracer.export_chrome(path)`` — Perfetto-viewable
 - ``obs.dump(path)`` — flight-recorder JSON lines; crash paths
   (``GuardianAbort``, request failure) call :func:`auto_dump`, which
   also writes a file per dump under ``$PT_OBS_DUMP_DIR`` when set.
@@ -34,6 +44,7 @@ from __future__ import annotations
 
 import os
 import threading
+import time
 
 from .events import EventLog
 from .flight import FlightRecorder
@@ -44,31 +55,37 @@ __all__ = [
     "EventLog", "FlightRecorder", "LogicalClock", "MetricRegistry",
     "Span", "Tracer", "auto_dump", "beat", "configure", "dump",
     "enabled", "event", "handle", "instant", "perf", "reset", "span",
+    "tracer",
 ]
 
 _MODES = ("off", "on")
 
 _lock = threading.Lock()
-_handle = None        # _Obs | None (None = telemetry off)
+_handle = None        # _Obs | None (None = operator plane off)
 _initialized = False  # PT_OBS read yet?
+_tracer = Tracer()    # the one tracer: records with PT_OBS off too
+_tracer.listen_for_compiles()
+
+
+def tracer():
+    """The process-wide :class:`Tracer` every span and instant goes to,
+    with ``PT_OBS`` off too."""
+    return _tracer
 
 
 class _Obs:
-    """The live telemetry bundle: one clock feeding one registry, one
-    tracer, one flight recorder, and one structured event log (the
-    flight ring tees into the log), plus the health-plane state
-    (heartbeats, SLO engines, ``/statusz`` providers, HTTP server)."""
+    """The live operator plane: one clock feeding one registry, one
+    flight recorder, and one structured event log (the flight ring tees
+    into the log), plus the health-plane state (heartbeats, SLO
+    engines, ``/statusz`` providers, HTTP server).  ``tracer`` is the
+    process-wide one, on the same clock."""
 
-    def __init__(self, clock=None, flight_capacity=512,
-                 trace_capacity=65536, annotate=True, events_path=None,
+    def __init__(self, clock=None, flight_capacity=512, events_path=None,
                  events_max_bytes=262144, events_max_files=3,
                  events_capacity=4096):
-        import time
-
         self.clock = clock if clock is not None else time.perf_counter
         self.registry = MetricRegistry()
-        self.tracer = Tracer(clock=self.clock, capacity=trace_capacity,
-                             annotate=annotate)
+        self.tracer = _tracer
         if events_path is None:
             events_path = os.environ.get("PT_OBS_EVENT_LOG") or None
         self.events = EventLog(clock=self.clock, path=events_path,
@@ -103,8 +120,9 @@ def _env_mode():
 
 
 def handle():
-    """The live :class:`_Obs` bundle, or ``None`` when telemetry is
-    off — the single branch every producer pays on the off path."""
+    """The live :class:`_Obs` bundle, or ``None`` when the operator
+    plane is off — the single branch every producer pays on the off
+    path."""
     global _handle, _initialized
     if not _initialized:
         with _lock:
@@ -123,7 +141,8 @@ def configure(mode="on", clock=None, flight_capacity=512,
               events_max_bytes=262144, events_max_files=3,
               events_capacity=4096):
     """Programmatic gate (tests / bench A/B): rebuild the bundle
-    regardless of ``PT_OBS``.  Returns the new handle (None for
+    regardless of ``PT_OBS``, and put the tracer on ``clock`` with an
+    empty ring in either mode.  Returns the new handle (None for
     ``mode="off"``).  Producers that cached a handle at construction
     (EngineMetrics, Scheduler) keep the old one — reconfigure BEFORE
     building the objects under test."""
@@ -132,8 +151,9 @@ def configure(mode="on", clock=None, flight_capacity=512,
         raise ValueError(f"obs.configure mode={mode!r}: expected off|on")
     with _lock:
         old = _handle
+        _tracer.configure(clock=clock or time.perf_counter,
+                          capacity=trace_capacity, annotate=annotate)
         _handle = (_Obs(clock=clock, flight_capacity=flight_capacity,
-                        trace_capacity=trace_capacity, annotate=annotate,
                         events_path=events_path,
                         events_max_bytes=events_max_bytes,
                         events_max_files=events_max_files,
@@ -146,49 +166,23 @@ def configure(mode="on", clock=None, flight_capacity=512,
 
 
 def reset():
-    """Drop all telemetry state; the next :func:`handle` re-reads
-    ``PT_OBS``."""
+    """Drop all telemetry state (the tracer's ring too, back on the
+    wall clock); the next :func:`handle` re-reads ``PT_OBS``."""
     global _handle, _initialized
     with _lock:
         old = _handle
         _handle = None
         _initialized = False
+        _tracer.configure()
     if old is not None:
         old.close()
     perf.reset()
 
 
-# -- thin producer helpers (no-ops when off) ----------------------------
+# -- thin producer helpers: spans always; the rest no-ops when off ------
 
-class _NullSpan:
-    """Stands in for a live span when telemetry is off."""
-
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-    def set(self, **kv):
-        return self
-
-
-NULL_SPAN = _NullSpan()
-
-
-def span(name, cat="host", trace_id=None, **args):
-    h = handle()
-    if h is None:
-        return NULL_SPAN
-    return h.tracer.span(name, cat=cat, trace_id=trace_id, **args)
-
-
-def instant(name, cat="host", trace_id=None, **args):
-    h = handle()
-    if h is not None:
-        h.tracer.instant(name, cat=cat, trace_id=trace_id, **args)
+span = _tracer.span
+instant = _tracer.instant
 
 
 def event(kind, **fields):

@@ -341,13 +341,18 @@ def test_off_path_is_bit_identical(model):
 
 
 def test_off_handle_costs_nothing():
+    """PT_OBS off: no operator plane is built, by recording either; the
+    span still lands in the one tracer (tests/test_obs_spans.py holds
+    its cost to a count per step)."""
     obs.configure(mode="off")
     assert obs.handle() is None
     assert not obs.enabled()
     assert obs.dump() is None
-    assert obs.span("x") is obs.NULL_SPAN
     with obs.span("x") as sp:
-        sp.set(a=1)                               # null span absorbs
+        sp.set(a=1)
+    (s,) = obs.tracer().spans
+    assert (s.name, s.args, s.parent) == ("x", {"a": 1}, None)
+    assert obs.handle() is None and obs.tracer() is obs.tracer()
 
 
 def test_env_gate_rejects_bogus(monkeypatch):

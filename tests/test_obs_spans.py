@@ -1,0 +1,318 @@
+"""The one tracer, on the default path: spans with parents and request ids
+through ``ServingEngine.step()`` and ``CompiledTrainStep.step()`` with no
+``PT_*`` variable set, what a span costs as a count per step, the ring's
+bound, the ``pt:`` annotation prefix and the compile listener."""
+import json
+import threading
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import paddle_tpu as paddle
+from paddle_tpu import obs
+from paddle_tpu.inference.server import ServingEngine
+from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
+from paddle_tpu.models.training import CompiledTrainStep
+from paddle_tpu.obs.trace import ANNOTATION_PREFIX, LogicalClock, Tracer
+
+
+@pytest.fixture(scope="module")
+def model():
+    paddle.seed(11)
+    cfg = LlamaConfig(vocab_size=256, hidden_size=64,
+                      intermediate_size=128, num_hidden_layers=2,
+                      num_attention_heads=4, num_key_value_heads=2,
+                      max_position_embeddings=128)
+    return LlamaForCausalLM(cfg)
+
+
+@pytest.fixture(autouse=True)
+def _clean(monkeypatch):
+    monkeypatch.delenv("PT_OBS", raising=False)
+    obs.reset()
+    yield
+    obs.reset()
+
+
+def family(spans):
+    """id -> span, and ancestors(span) -> names from its parent upward."""
+    by_id = {s.id: s for s in spans}
+
+    def ancestors(s):
+        names = []
+        while s.parent is not None:
+            s = by_id[s.parent]
+            names.append(s.name)
+        return names
+    return by_id, ancestors
+
+
+def self_time(spans, span):
+    """A span's duration less what its children cover."""
+    return span.dur - sum(c.dur for c in spans
+                          if c.parent == span.id and c.dur is not None)
+
+
+# -- the tracer itself ---------------------------------------------------------
+
+def test_parents_and_self_time_on_the_logical_clock():
+    obs.configure(mode="off", clock=LogicalClock(tick=1.0))
+    with obs.span("outer") as outer:
+        with obs.span("a"):
+            obs.instant("mark", trace_id="r1")
+            with obs.span("leaf"):
+                pass
+        with obs.span("b"):
+            pass
+    spans = list(obs.tracer().spans)
+    by_name = {s.name: s for s in spans}
+    assert [s.name for s in spans] == ["mark", "leaf", "a", "b", "outer"]
+    assert by_name["outer"].parent is None
+    assert by_name["a"].parent == by_name["b"].parent == outer.id
+    assert by_name["leaf"].parent == by_name["mark"].parent \
+        == by_name["a"].id
+    assert by_name["mark"].args == {"trace_id": "r1"}
+    # reads: outer 1, a 2, mark 3, leaf 4-5, a ends 6, b 7-8, outer ends 9
+    assert (by_name["outer"].ts, by_name["outer"].dur) == (1.0, 8.0)
+    assert (by_name["a"].ts, by_name["a"].dur) == (2.0, 4.0)
+    assert self_time(spans, by_name["outer"]) == 8.0 - 4.0 - 1.0
+    assert self_time(spans, by_name["a"]) == 4.0 - 1.0
+    assert len({s.id for s in spans}) == len(spans)
+
+
+def test_spans_are_recorded_with_the_operator_plane_off():
+    assert obs.handle() is None
+    with obs.span("x", cat="train", k=1):
+        obs.instant("y")
+    assert [s.name for s in obs.tracer().spans] == ["y", "x"]
+    assert obs.handle() is None and not obs.enabled()
+    # the plane, when on, holds the same tracer
+    h = obs.configure(mode="on")
+    assert h.tracer is obs.tracer()
+
+
+def test_ring_is_bounded_and_counts_what_it_dropped():
+    obs.configure(mode="off", trace_capacity=4)
+    for i in range(7):
+        obs.instant("i", n=i)
+    tr = obs.tracer()
+    assert len(tr.spans) == 4 and tr.dropped == 3
+    assert [s.args["n"] for s in tr.spans] == [3, 4, 5, 6]
+    obs.reset()
+    assert obs.tracer().dropped == 0 and obs.tracer().capacity == 65536
+
+
+def test_each_thread_has_its_own_open_spans():
+    seen = {}
+
+    def other():
+        with obs.span("there") as sp:
+            seen["parent"] = sp.parent
+
+    with obs.span("here"):
+        t = threading.Thread(target=other)
+        t.start()
+        t.join(timeout=30)
+        assert not t.is_alive()
+    assert seen["parent"] is None
+
+
+def test_every_span_enters_an_annotation_with_the_prefix(monkeypatch):
+    names = []
+
+    class Annotation:
+        def __init__(self, name):
+            names.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+    with obs.span("serve.step"):
+        with obs.span("exec.fetch"):
+            pass
+    assert ANNOTATION_PREFIX == "pt:"
+    assert names == ["pt:serve.step", "pt:exec.fetch"]
+
+
+def test_chrome_export_carries_id_and_parent(tmp_path):
+    with obs.span("outer", cat="serve"):
+        obs.instant("req.admit", cat="serve", trace_id="r")
+    path = obs.tracer().export_chrome(str(tmp_path / "t.json"))
+    with open(path) as f:
+        events = [e for e in json.load(f)["traceEvents"] if e["ph"] != "M"]
+    inst, outer = events
+    assert outer["ph"] == "X" and outer["args"]["parent"] is None
+    assert inst["ph"] == "i" and inst["args"]["parent"] == outer["args"]["id"]
+    assert inst["args"]["trace_id"] == "r"
+
+
+def test_compile_listener_records_a_fresh_jit():
+    def fresh_program_for_the_listener(x):
+        return jnp.sin(x) * 3 + 1
+
+    jax.jit(fresh_program_for_the_listener)(jnp.ones((3, 5, 7))).block_until_ready()
+    mine = [s for s in obs.tracer().spans
+            if "fresh_program_for_the_listener" in s.args.get("fun_name", "")]
+    assert [(s.name, s.args["fun_name"]) for s in mine] == [
+        ("jit.trace", "fresh_program_for_the_listener"),
+        ("jit.lower", "jit(fresh_program_for_the_listener)"),
+        ("jit.compile", "jit(fresh_program_for_the_listener)")]
+    assert all(s.dur > 0 and s.cat == "jit" for s in mine)
+    # the inner jits' traces lie inside their caller's: a reader takes
+    # the union of the intervals
+    trace = mine[0]
+    inner = [s for s in obs.tracer().spans if s.name == "jit.trace"
+             and s.args["fun_name"] in ("sin", "multiply", "add")]
+    assert inner and all(
+        trace.ts - 1e-3 <= s.ts and s.ts + s.dur <= trace.ts + trace.dur
+        for s in inner)
+    # a second call compiles nothing
+    n = len(obs.tracer().spans)
+    jax.jit(fresh_program_for_the_listener)(jnp.ones((3, 5, 7)))
+    assert not [s for s in list(obs.tracer().spans)[n:]
+                if s.name == "jit.compile"]
+
+
+def test_compile_spans_stay_off_an_injected_clock():
+    clock = LogicalClock()
+    obs.configure(mode="off", clock=clock)
+    jax.jit(lambda x: jnp.cos(x) - 2)(jnp.ones((2, 7))).block_until_ready()
+    assert clock.reads == 0 and not obs.tracer().spans
+
+
+def test_a_tracer_of_ones_own():
+    """The class still stands alone (tools build their own)."""
+    tr = Tracer(clock=LogicalClock(), capacity=8, annotate=False)
+    with tr.span("a"):
+        tr.complete("timed", 0.25, fun_name="f")
+    timed, a = tr.spans
+    assert timed.parent == a.id and timed.dur == 0.25
+    assert not obs.tracer().spans
+
+
+# -- the serving engine, nothing set -------------------------------------------
+
+ENGINE_KW = dict(max_seqs=4, page_size=4, max_len=64, prefill_chunk=8)
+
+
+def serve(model, lens=(5, 19), new=4):
+    eng = ServingEngine(model, **ENGINE_KW)
+    rng = np.random.RandomState(0)
+    handles = [eng.submit(rng.randint(1, 256, (n,)).astype(np.int32),
+                          max_new_tokens=new) for n in lens]
+    eng.run()
+    assert all(len(h.tokens) == new for h in handles)
+    return eng, handles, [s for s in obs.tracer().spans if s.cat != "jit"]
+
+
+def test_serving_span_tree(model):
+    eng, handles, spans = serve(model)
+    assert obs.handle() is None
+    by_id, ancestors = family(spans)
+    steps = [s for s in spans if s.name == "serve.step"]
+    assert [s.args["tick"] for s in steps] == list(range(1, eng.tick + 1))
+    assert all(s.parent is None for s in steps)
+    for s in spans:
+        if s.name in ("exec.fetch", "jit.dispatch", "kv.write", "kv.gather",
+                      "exec.prep", "serve.sweep", "serve.decode",
+                      "serve.admit", "req.prefill"):
+            assert ancestors(s)[-1] == "serve.step", s
+        if s.name in ("kv.write", "kv.gather"):
+            assert ancestors(s)[0] == "req.prefill"
+        if s.name == "exec.fetch":
+            assert ancestors(s)[0] == {"decode": "serve.decode"}.get(
+                s.args["what"], "req.prefill")
+    # the control plane's children, in the order of a step
+    kids = [s.name for s in spans
+            if s.parent == steps[1].id and s.dur is not None]
+    assert kids[:3] == ["serve.sweep", "serve.decode", "serve.admit"]
+    assert set(kids[3:]) <= {"req.prefill"}
+    # every request-scoped record carries its rid, in lifecycle order
+    rids = {h.rid for h in handles}
+    for s in spans:
+        if s.name.startswith("req."):
+            assert s.args["trace_id"] in rids, s
+    for rid in rids:
+        names = [s.name for s in spans if s.args.get("trace_id") == rid]
+        assert names[0] == "req.submit" and names[1] == "req.admit"
+        assert names[-1] == "req.finish" and "req.first_token" in names
+        assert names.index("req.prefill") < names.index("req.first_token")
+    admits = [s for s in spans if s.name == "serve.admit"]
+    assert sum(s.args["admitted"] for s in admits) == 2
+    # the 19-token prompt: chunks of 8, 8, 3 over pages of 4 tokens
+    writes = [by_id[s.id].args for s in spans if s.name == "kv.write"
+              and by_id[s.parent].args["trace_id"] == handles[1].rid]
+    assert writes == [{"pages": 2, "dispatches": 4}] * 2 \
+        + [{"pages": 1, "dispatches": 2}]
+
+
+def test_traced_is_true_exactly_on_first_shapes(model):
+    eng, _, spans = serve(model, lens=(5, 19), new=6)
+    seen, want = set(), []
+    events = iter(eng.executor.prefill_events)
+    for s in spans:
+        if s.name != "jit.dispatch":
+            continue
+        # a shape is new when its program has not run at this batch size
+        # (decode), chunk length and past cover (prefill)
+        parent = next(p for p in spans if p.id == s.parent)
+        if s.args["program"] == "serve.decode":
+            shape = ("decode", parent.args["batch"])
+        else:
+            shape = (s.args["program"], next(events)[1],
+                     -(-parent.args["start"] // 4))
+        want.append(shape not in seen)
+        seen.add(shape)
+    got = [s.args["traced"] for s in spans if s.name == "jit.dispatch"]
+    assert got == want and True in got and False in got
+    progs = eng.executor.programs.values()
+    assert sum(got) == sum(p.traces for p in progs)
+    assert len(got) == sum(p.dispatches for p in progs)
+
+
+def test_span_budget_of_a_step(model):
+    """A decode-only step records at most 8 spans, a prefill chunk at
+    most 6 more, none per token or page; instants are per request."""
+    eng, _, spans = serve(model, lens=(5, 19, 30), new=12)
+    timed = [s for s in spans if s.dur is not None]
+    steps = [s for s in timed if s.name == "serve.step"]
+    _, ancestors = family(spans)
+    decode_only = 0
+    for step in steps:
+        inside = [s for s in timed if s is step
+                  or (s.ts >= step.ts and s.ts + s.dur <= step.ts + step.dur
+                      and "serve.step" in ancestors(s))]
+        chunks = sum(s.name == "req.prefill" for s in inside)
+        assert len(inside) <= 8 + 6 * chunks, [s.name for s in inside]
+        decode_only += not chunks
+    assert decode_only >= 5
+    instants = [s for s in spans if s.dur is None]
+    assert len(instants) == 4 * 3       # submit, admit, first_token, finish
+
+
+# -- the train step, nothing set -----------------------------------------------
+
+def test_train_step_spans(model):
+    step = CompiledTrainStep(model, lr=1e-3)
+    ids = np.random.RandomState(0).randint(0, 256, (2, 17)).astype(np.int32)
+    n0 = len(obs.tracer().spans)
+    for _ in range(3):
+        loss = step.step(ids[:, :-1], ids[:, 1:])
+    assert np.isfinite(float(loss))
+    assert obs.handle() is None
+    spans = [s for s in list(obs.tracer().spans)[n0:] if s.cat != "jit"]
+    assert len(spans) <= 4 * 3
+    tops = [s for s in spans if s.name == "train.step"]
+    assert [s.args["t"] for s in tops] == [1, 2, 3]
+    for top in tops:
+        assert top.parent is None
+        kids = [s for s in spans if s.parent == top.id]
+        assert [s.name for s in kids] == ["train.place", "jit.dispatch"]
+        assert kids[1].args == {"program": "train.step"}
+        assert self_time(spans, top) >= 0
