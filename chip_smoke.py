@@ -894,7 +894,7 @@ def leg_serve(ctx):
     decode_sites = kernel_sites(
         jax, ex._decode_fwd, ex.layers, ex.tops,
         jnp.zeros((1,), jnp.int32), jnp.zeros((1,), jnp.int32),
-        *ex._pools(), jnp.ones((1,), jnp.int32),
+        *ex.cache.pools(), jnp.ones((1,), jnp.int32),
         jnp.zeros((1, ex.cache.max_pages_per_seq), jnp.int32))
     # every handle holds the engine: let go of all of them, or the
     # pools and stacked weights stay in HBM under the reference

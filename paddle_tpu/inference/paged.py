@@ -22,7 +22,9 @@ import jax
 import jax.numpy as jnp
 
 from .. import obs as _obs
+from ..analysis import CountedJit
 from ..core.tensor import Tensor
+from ..ops import quant as _quant
 from ..ops import registry as _registry
 from ..testing import faults as _faults
 
@@ -242,6 +244,42 @@ def paged_decode_attention(q, k_pages, v_pages, lengths, page_indices,
     return Tensor(out) if wrap else out
 
 
+# -- the pool's device-side writer ---------------------------------------
+
+
+def _write_span(kp, vp, k, v, pids, offs):
+    """Write a token span's K and V, ``[L, KV, T, D]``, into both pools
+    in ONE program.  The pools arrive donated (see
+    :attr:`PagedKVCache.writer`), so the pages are patched in place and
+    nothing else of the pool moves; the shape is keyed on ``T`` alone,
+    never on the page ids or the position.
+
+    An int8 pool is ``(pages, scales)`` and takes the page id and
+    in-page slot of every token, int32 ``[T]``
+    (``ops.quant.kv_write``).  A plain pool takes ``pids`` = the ids of
+    the pages the span touches, in order, padded with ``num_pages``
+    (dropped) to the ``(T - 1) // page_size + 2`` a span of ``T`` can
+    touch, and ``offs`` = the first token's in-page slot: the touched
+    pages are read, the span laid over them at ``offs``, and whole
+    ``(page_size, head_dim)`` tiles written back.  A row per token is a
+    sub-tile write, for which the TPU compiler re-lays the whole pool
+    before and after the scatter (PERF.md section 6, PR 27)."""
+    if isinstance(kp, tuple):
+        return (_quant.kv_write(*kp, pids, offs, k),
+                _quant.kv_write(*vp, pids, offs, v))
+
+    def put(pool, x):
+        old = pool.at[:, :, pids].get(mode="clip")  # [L, KV, n, ps, D]
+        L, KV, n, ps, D = old.shape
+        span = jax.lax.dynamic_update_slice_in_dim(
+            old.reshape(L, KV, n * ps, D), x.astype(pool.dtype), offs,
+            axis=2)
+        return pool.at[:, :, pids].set(span.reshape(old.shape),
+                                       mode="drop")
+
+    return put(kp, k), put(vp, v)
+
+
 # -- block-table cache manager ------------------------------------------
 
 
@@ -264,13 +302,19 @@ class PagedKVCache:
     returning pages to the pool, so shared prefix pages survive the
     sequences that used them.  With no prefix cache attached every
     refcount is 0/1 and the behavior is bit-identical to the r10 code.
+
+    The pools have ONE owner, this object's attributes.  Every program
+    that writes pages — :attr:`writer` for a prefill span, the
+    executor's decode and verify programs for their own tokens — takes
+    them DONATED (:meth:`pools`) and its outputs replace them at once
+    (:meth:`set_pools`), so a write patches pages in place instead of
+    copying a pool.  A donated array is deleted: read ``k_pages`` /
+    ``v_pages`` afresh for every use and keep none across a write.
     """
 
     def __init__(self, n_layers, n_kv_heads, head_dim, num_pages,
                  page_size=16, max_seqs=8, dtype=jnp.bfloat16,
                  max_pages_per_seq=None, quant=None):
-        from ..ops import quant as _quant
-
         self.n_layers = n_layers
         self.page_size = page_size
         self.num_pages = num_pages
@@ -315,6 +359,28 @@ class PagedKVCache:
         # (the prefix cache's LRU eviction); consulted before any
         # "pool exhausted" raise
         self.reclaimer = None
+        #: the device-side writer (``_write_span``), pools donated
+        self.writer = CountedJit(_write_span, name="serve.kv_write",
+                                 donate_argnums=(0, 1))
+
+    def pools(self):
+        """The jit-argument form of the KV pools: the bare page arrays
+        in the plain mode, or ``(pages, scales)`` tuples on an int8
+        pool — jit flattens the tuple, donation covers every leaf, and
+        the programs branch on the pytree form at trace time."""
+        if self.quant == "int8":
+            return ((self.k_pages, self.k_scales),
+                    (self.v_pages, self.v_scales))
+        return self.k_pages, self.v_pages
+
+    def set_pools(self, kps, vps) -> None:
+        """Store a program's updated pool outputs (the form
+        :meth:`pools` gave it) back on the cache."""
+        if self.quant == "int8":
+            (self.k_pages, self.k_scales), \
+                (self.v_pages, self.v_scales) = kps, vps
+        else:
+            self.k_pages, self.v_pages = kps, vps
 
     # -- control plane (host) ------------------------------------------
 
@@ -511,7 +577,8 @@ class PagedKVCache:
         """Write a token span's KV at position ``start`` (chunked
         prefill): k/v [L, KV, T, D] covering positions
         ``start..start+T-1``.  Pages are allocated as needed; the
-        sequence length becomes ``start + T``.  On an int8 pool the
+        sequence length becomes ``start + T``.  One dispatch of
+        :attr:`writer` whatever the span's length; on an int8 pool the
         span is quantized on write (``ops.quant.kv_write``:
         scatter-max the touched pages' scales, requantize residents,
         write the new cells)."""
@@ -521,41 +588,24 @@ class PagedKVCache:
         # first (no-op when nothing is shared, i.e. no prefix cache)
         self.make_writable(seq, start, start + T)
         ps = self.page_size
-        if self.quant == "int8":
-            from ..ops import quant as _quant
-
-            row = self.page_table[seq]
-            pids = jnp.asarray([int(row[(start + t) // ps])
-                                for t in range(T)], jnp.int32)
-            offs = jnp.asarray([(start + t) % ps for t in range(T)],
-                               jnp.int32)
-            _faults.fire("quant.kv_write", "before")
-            self.k_pages, self.k_scales = _quant.kv_write(
-                self.k_pages, self.k_scales, pids, offs,
-                jnp.asarray(k))
-            self.v_pages, self.v_scales = _quant.kv_write(
-                self.v_pages, self.v_scales, pids, offs,
-                jnp.asarray(v))
-            _faults.fire("quant.kv_write", "after")
-            self.lengths[seq] = start + T
-            return
-        k = jnp.asarray(k, self.k_pages.dtype)
-        v = jnp.asarray(v, self.v_pages.dtype)
-        t = pages = 0
-        with _obs.span("kv.write", cat="serve") as sp:
-            while t < T:
-                pos = start + t
-                page, off = pos // ps, pos % ps
-                n = min(ps - off, T - t)  # span within this page
-                pid = int(self.page_table[seq, page])
-                self.k_pages = self.k_pages.at[
-                    :, :, pid, off:off + n].set(k[:, :, t:t + n])
-                self.v_pages = self.v_pages.at[
-                    :, :, pid, off:off + n].set(v[:, :, t:t + n])
-                t += n
-                pages += 1
-            # eager device ops issued: one scatter into each pool a page
-            sp.set(pages=pages, dispatches=2 * pages)
+        first, last = start // ps, -(-(start + T) // ps)
+        row = self.page_table[seq]
+        with _obs.span("kv.write", cat="serve", pages=last - first,
+                       dispatches=1):
+            if self.quant == "int8":
+                pos = start + np.arange(T)
+                pids, offs = row[pos // ps], (pos % ps).astype(np.int32)
+                _faults.fire("quant.kv_write", "before")
+            else:
+                pids = np.full(((T - 1) // ps + 2,), self.num_pages,
+                               np.int32)
+                pids[:last - first] = row[first:last]
+                offs = np.int32(start % ps)
+            # the donated call comes last of what can fail, and its
+            # outputs replace the deleted pools in the same statement
+            self.set_pools(*self.writer(*self.pools(), k, v, pids, offs))
+            if self.quant == "int8":
+                _faults.fire("quant.kv_write", "after")
         self.lengths[seq] = start + T
 
     def write_sharded(self, seq: int, k, v, start: int,
@@ -627,8 +677,6 @@ class PagedKVCache:
         k = self.k_pages[:, :, pids]          # [L, KV, n, ps, D]
         v = self.v_pages[:, :, pids]
         if self.quant == "int8":
-            from ..ops import quant as _quant
-
             _faults.fire("quant.dequant", "before")
             k = _quant.kv_dequant(k, self.k_scales[:, :, pids],
                                   self.compute_dtype)
@@ -668,8 +716,6 @@ class PagedKVCache:
         pids = jnp.asarray(pids)
         offs = jnp.asarray(offs)
         if self.quant == "int8":
-            from ..ops import quant as _quant
-
             _faults.fire("quant.kv_write", "before")
             self.k_pages, self.k_scales = _quant.kv_write(
                 self.k_pages, self.k_scales, pids, offs, jnp.asarray(k))

@@ -218,11 +218,13 @@ class PagedExecutor:
         # fn doubles as the lint registration target below
         self._jit_prefill = CountedJit(self._prefill_fwd,
                                        name="serve.prefill")
-        # donate the pools (and the chunk's dense past-KV gather, which
-        # is a fresh copy the caller never reuses): the call sites
-        # immediately replace them with the outputs, so XLA updates in
-        # place instead of copying GBs of KV — the donation-miss lint
-        # check flagged the chunk program's past_k/past_v
+        # the chunk program never sees the pools: it donates the dense
+        # past-KV gather (a fresh copy the caller never reuses — the
+        # donation-miss lint check flagged it) and hands its K/V to the
+        # cache's own donated writer (serve.kv_write).  Decode and
+        # verify take the pools themselves donated, and the call sites
+        # replace them with the outputs at once, so every page write is
+        # in place instead of a copy of GBs of KV
         self._jit_chunk = CountedJit(self._chunk_fwd,
                                      name="serve.prefill_chunk",
                                      donate_argnums=(4, 5))
@@ -367,28 +369,9 @@ class PagedExecutor:
     def verify_dispatches(self) -> int:
         return self._jit_verify.dispatches
 
-    def _pools(self):
-        """The jit-argument form of the KV pools: the bare page arrays
-        in the plain mode (byte-identical signatures to r18), or
-        ``(pages, scales)`` tuples on an int8 pool — jit flattens the
-        tuple, donation covers every leaf, and the forwards branch on
-        the pytree form at trace time."""
-        c = self.cache
-        if self.quant == "int8":
-            return (c.k_pages, c.k_scales), (c.v_pages, c.v_scales)
-        return c.k_pages, c.v_pages
-
-    def _set_pools(self, kps, vps):
-        """Store a program's updated pool outputs back on the cache."""
-        c = self.cache
-        if self.quant == "int8":
-            (c.k_pages, c.k_scales), (c.v_pages, c.v_scales) = kps, vps
-        else:
-            c.k_pages, c.v_pages = kps, vps
-
     def _pool_sds(self):
-        """ShapeDtypeStruct mirror of :meth:`_pools` for contracts and
-        AOT warmup."""
+        """ShapeDtypeStruct mirror of ``cache.pools()`` for contracts
+        and AOT warmup."""
         c = self.cache
         kp = jax.ShapeDtypeStruct(jnp.shape(c.k_pages),
                                   c.k_pages.dtype)
@@ -467,6 +450,15 @@ class PagedExecutor:
                 **{**common,
                    "expected_collectives": {"ppermute": 2 * (nsp - 1),
                                             "all_gather": 1}}))
+        # the cache's span writer at one page of tokens (int8: page id
+        # and slot per token; plain: the two pages a mid-page start
+        # touches, and the first slot)
+        span = ((i32(ps), i32(ps)) if self.quant == "int8"
+                else (i32(2), i32()))
+        register_program(ProgramContract(
+            name="serve.kv_write" + sfx, fn=cache.writer.fn,
+            args=(kp, kp, past, past) + span,
+            donate_argnums=cache.writer.donate_argnums, **common))
         register_program(ProgramContract(
             name="serve.decode" + sfx, fn=self._decode_fwd,
             args=(layers, tops, i32(B), i32(B), kp, kp, i32(B),
@@ -1296,11 +1288,11 @@ class PagedExecutor:
                 [int(cache.lengths[s]) for s in sids], jnp.int32)
             tables = jnp.asarray(np.maximum(cache.page_table[sids], 0))
             lengths = jnp.asarray(cache.lengths[sids])
-            kp, vp = self._pools()
+            kp, vp = self.cache.pools()
         logits, kps, vps = self._jit_decode(
             self.layers, self.tops, ids, positions, kp, vp, lengths,
             tables)
-        self._set_pools(kps, vps)
+        self.cache.set_pools(kps, vps)
         for s in sids:
             cache.lengths[s] += 1
         # single batched argmax + ONE host transfer for the whole step
@@ -1333,11 +1325,11 @@ class PagedExecutor:
                                 jnp.int32)
         tables = jnp.asarray(np.maximum(cache.page_table[sids], 0))
         lengths = jnp.asarray(cache.lengths[sids])
-        kp, vp = self._pools()
+        kp, vp = self.cache.pools()
         toks, kps, vps = self._jit_decode_async(
             self.layers, self.tops, ids, positions, kp, vp, lengths,
             tables)
-        self._set_pools(kps, vps)
+        self.cache.set_pools(kps, vps)
         for s in sids:
             cache.lengths[s] += 1
         return _PendingDecode(self, sids, toks)
@@ -1373,11 +1365,11 @@ class PagedExecutor:
             ids[i, 1:1 + len(dr)] = dr
         tables = jnp.asarray(np.maximum(cache.page_table[sids], 0))
         lengths = jnp.asarray(cache.lengths[sids])
-        kp, vp = self._pools()
+        kp, vp = self.cache.pools()
         packed, emit_n, kps, vps = self._jit_verify(
             self.layers, self.tops, jnp.asarray(ids), kp, vp, lengths,
             tables, jnp.asarray(limits, jnp.int32))
-        self._set_pools(kps, vps)
+        self.cache.set_pools(kps, vps)
         # ONE host transfer: the sort-packed token block + counts;
         # splitting it is per-SEQUENCE host work, never per-token-cell
         packed = np.asarray(packed)
@@ -1418,11 +1410,11 @@ class PagedExecutor:
             ids[i, 1:1 + len(dr)] = dr
         tables = jnp.asarray(np.maximum(cache.page_table[sids], 0))
         lengths = jnp.asarray(cache.lengths[sids])
-        kp, vp = self._pools()
+        kp, vp = self.cache.pools()
         packed, emit_n, kps, vps = self._jit_verify(
             self.layers, self.tops, jnp.asarray(ids), kp, vp, lengths,
             tables, jnp.asarray(limits, jnp.int32))
-        self._set_pools(kps, vps)
+        self.cache.set_pools(kps, vps)
         return _PendingVerify(self, sids, packed, emit_n)
 
     def rollback(self, sids) -> int:
@@ -1451,11 +1443,11 @@ class PagedExecutor:
                                 jnp.int32)
         tables = jnp.asarray(np.maximum(cache.page_table[sids], 0))
         lengths = jnp.asarray(cache.lengths[sids])
-        kp, vp = self._pools()
+        kp, vp = self.cache.pools()
         toks, kps, vps = self._jit_decode_n(
             self.layers, self.tops, ids, positions, kp, vp, lengths,
             tables, n=int(n))
-        self._set_pools(kps, vps)
+        self.cache.set_pools(kps, vps)
         toks = np.asarray(toks)                     # [n, B]
         out = {}
         for i, s in enumerate(sids):

@@ -248,8 +248,12 @@ def test_serving_span_tree(model):
     # the 19-token prompt: chunks of 8, 8, 3 over pages of 4 tokens
     writes = [by_id[s.id].args for s in spans if s.name == "kv.write"
               and by_id[s.parent].args["trace_id"] == handles[1].rid]
-    assert writes == [{"pages": 2, "dispatches": 4}] * 2 \
-        + [{"pages": 1, "dispatches": 2}]
+    assert writes == [{"pages": 2, "dispatches": 1}] * 2 \
+        + [{"pages": 1, "dispatches": 1}]
+    # ... and `dispatches` is what ran under the span: the one writer
+    for w in (s for s in spans if s.name == "kv.write"):
+        assert [(k.name, k.args["program"]) for k in spans
+                if k.parent == w.id] == [("jit.dispatch", "serve.kv_write")]
 
 
 def test_traced_is_true_exactly_on_first_shapes(model):
@@ -260,10 +264,14 @@ def test_traced_is_true_exactly_on_first_shapes(model):
         if s.name != "jit.dispatch":
             continue
         # a shape is new when its program has not run at this batch size
-        # (decode), chunk length and past cover (prefill)
+        # (decode), chunk length and past cover (prefill), span length
+        # alone (the page writer, under kv.write under req.prefill)
         parent = next(p for p in spans if p.id == s.parent)
         if s.args["program"] == "serve.decode":
             shape = ("decode", parent.args["batch"])
+        elif s.args["program"] == "serve.kv_write":
+            chunk = next(p for p in spans if p.id == parent.parent)
+            shape = ("kv_write", chunk.args["tokens"])
         else:
             shape = (s.args["program"], next(events)[1],
                      -(-parent.args["start"] // 4))
@@ -271,14 +279,14 @@ def test_traced_is_true_exactly_on_first_shapes(model):
         seen.add(shape)
     got = [s.args["traced"] for s in spans if s.name == "jit.dispatch"]
     assert got == want and True in got and False in got
-    progs = eng.executor.programs.values()
+    progs = [*eng.executor.programs.values(), eng.executor.cache.writer]
     assert sum(got) == sum(p.traces for p in progs)
     assert len(got) == sum(p.dispatches for p in progs)
 
 
 def test_span_budget_of_a_step(model):
     """A decode-only step records at most 8 spans, a prefill chunk at
-    most 6 more, none per token or page; instants are per request."""
+    most 7 more, none per token or page; instants are per request."""
     eng, _, spans = serve(model, lens=(5, 19, 30), new=12)
     timed = [s for s in spans if s.dur is not None]
     steps = [s for s in timed if s.name == "serve.step"]
@@ -289,7 +297,7 @@ def test_span_budget_of_a_step(model):
                   or (s.ts >= step.ts and s.ts + s.dur <= step.ts + step.dur
                       and "serve.step" in ancestors(s))]
         chunks = sum(s.name == "req.prefill" for s in inside)
-        assert len(inside) <= 8 + 6 * chunks, [s.name for s in inside]
+        assert len(inside) <= 8 + 7 * chunks, [s.name for s in inside]
         decode_only += not chunks
     assert decode_only >= 5
     instants = [s for s in spans if s.dur is None]
