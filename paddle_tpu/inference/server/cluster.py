@@ -633,6 +633,12 @@ class ServingCluster:
             raise ValueError(
                 "disaggregated mode needs >= 2 replicas "
                 "(at least one prefill and one decode role)")
+        if "mamba" in getattr(model.config, "layer_types", ()):
+            # a hand-off moves a request's pages, or replays it from the
+            # journal; a recurrent state row is neither
+            raise NotImplementedError(
+                "ServingCluster: cluster hand-off not supported for a "
+                "model with recurrent (state-space) layers")
         self.model = model
         self.disaggregated = bool(disaggregated)
         self._engine_kwargs = dict(engine_kwargs)

@@ -18,13 +18,14 @@ import os
 
 import jax.numpy as jnp
 
-from .executor import PagedExecutor
+from .executor import PagedExecutor, _sp_prefill_enabled
+from .hybrid_executor import HybridExecutor
 from .metrics import EngineMetrics
 from .prefix_cache import PrefixCache
 from .request import Request, RequestHandle, RequestState
 from .scheduler import Scheduler
 from .spec_decode import SpecDecode, spec_mode
-from .wal import resolve_wal
+from .wal import resolve_wal, wal_enabled
 
 
 def _prefix_cache_enabled() -> bool:
@@ -41,6 +42,20 @@ def _async_exec_enabled() -> bool:
         raise ValueError(
             f"PT_ASYNC_EXEC={mode!r}: expected off|on")
     return mode == "on"
+
+
+def _refuse_for_recurrent(wanted: dict) -> None:
+    """A model with recurrent (state-space) layers keeps per-sequence
+    state that is not pages: every feature that assumes "state = pages"
+    is refused when the engine is built, by name, never run wrong."""
+    asked = [name for name, on in wanted.items() if on]
+    if asked:
+        raise NotImplementedError(
+            f"ServingEngine: {', '.join(asked)} not supported for a model "
+            f"with recurrent (state-space) layers: its per-sequence state "
+            f"is one row of the recurrent-state cache, which cannot be "
+            f"attached by reference, rolled back, handed over or "
+            f"quantised like KV pages")
 
 
 class ServingEngine:
@@ -61,11 +76,41 @@ class ServingEngine:
         # or above sp_min_tokens (PT_SP_PREFILL_MIN_TOKENS) prefill
         # sequence-parallel over sp_mesh's sp axis (default: a 1-D
         # mesh over every local device).
-        self.executor = PagedExecutor(
-            model, max_seqs=max_seqs, page_size=page_size,
-            max_len=max_len, dtype=dtype, num_pages=num_pages,
-            quant=quant, sp_mesh=sp_mesh, sp_prefill=sp_prefill,
-            sp_min_tokens=sp_min_tokens, sp_axis=sp_axis)
+        # the programs follow the model's layer kinds: a model with
+        # state-space layers gets the hybrid executor (a recurrent-state
+        # cache beside the paged KV pool) behind the same slot interface
+        recurrent = "mamba" in getattr(model.config, "layer_types", ())
+        if recurrent:
+            from paddle_tpu.core import aot as _aot
+            from paddle_tpu.ops import quant as _quant
+
+            _refuse_for_recurrent({
+                "prefix cache": (_prefix_cache_enabled()
+                                 if prefix_cache is None else prefix_cache),
+                "speculative decoding": (
+                    spec_mode() == "ngram" if spec_decode is None
+                    else spec_decode not in (False, "off")),
+                "async execution": (_async_exec_enabled()
+                                    if async_exec is None else async_exec),
+                "decode_n": bool(decode_n_steps),
+                "sequence-parallel prefill": (
+                    _sp_prefill_enabled() if sp_prefill is None
+                    else sp_prefill),
+                "int8 quantisation": _quant.quant_mode(quant) != "none",
+                "AOT warm-up": (_aot.mode() if aot is None
+                                else aot) != "off",
+                "write-ahead log": (wal_enabled() if wal is None
+                                    else wal is not False),
+            })
+            self.executor = HybridExecutor(
+                model, max_seqs=max_seqs, page_size=page_size,
+                max_len=max_len, dtype=dtype, num_pages=num_pages)
+        else:
+            self.executor = PagedExecutor(
+                model, max_seqs=max_seqs, page_size=page_size,
+                max_len=max_len, dtype=dtype, num_pages=num_pages,
+                quant=quant, sp_mesh=sp_mesh, sp_prefill=sp_prefill,
+                sp_min_tokens=sp_min_tokens, sp_axis=sp_axis)
         # clock: injectable wall-clock source for the SLO metrics and
         # per-request timestamps (default time.perf_counter; seeded
         # tests pass obs.LogicalClock() for exact ms percentiles)

@@ -324,3 +324,81 @@ def test_train_step_spans(model):
         assert [s.name for s in kids] == ["train.place", "jit.dispatch"]
         assert kids[1].args == {"program": "train.step"}
         assert self_time(spans, top) >= 0
+
+
+# -- the hybrid engine (state-space layers beside attention) --------------------
+
+@pytest.fixture(scope="module")
+def hybrid():
+    from paddle_tpu.models.granite_hybrid import (GraniteHybridConfig,
+                                                  GraniteHybridForCausalLM)
+
+    paddle.seed(12)
+    return GraniteHybridForCausalLM(GraniteHybridConfig.tiny())
+
+
+#: the contract's spans (PERF.md section 3) every serving path emits
+CONTRACT = {"serve.step", "serve.sweep", "serve.decode", "serve.admit",
+            "req.prefill", "exec.prep", "kv.gather", "kv.write",
+            "jit.dispatch", "exec.fetch", "req.submit", "req.admit",
+            "req.first_token", "req.finish"}
+
+
+def test_hybrid_spans_are_the_contracts_and_the_states(hybrid):
+    eng, handles, spans = serve(hybrid, lens=(5, 19), new=6)
+    names = {s.name for s in spans}
+    assert CONTRACT <= names
+    assert {"state.read", "state.write", "state.alloc",
+            "state.free"} <= names
+    by_id, ancestors = family(spans)
+    for s in spans:
+        if s.name in ("state.read", "state.write", "kv.write", "kv.gather"):
+            assert ancestors(s)[0] == "req.prefill", s
+            assert ancestors(s)[-1] == "serve.step"
+    # one read and one write a chunk, the write one donated dispatch
+    chunks = [s for s in spans if s.name == "req.prefill"]
+    assert len(chunks) == 1 + 3         # 5 whole; 19 = 8 + 8 + 3
+    for c in chunks:
+        kids = [s for s in spans if s.parent == c.id]
+        assert [k.name for k in kids].count("state.read") == 1
+        (write,) = [k for k in kids if k.name == "state.write"]
+        assert write.args["tokens"] == c.args["tokens"]
+        assert write.args["dispatches"] == 1
+        assert [(k.name, k.args["program"]) for k in spans
+                if k.parent == write.id] == [("jit.dispatch",
+                                              "serve.state_write")]
+        read = next(k for k in kids if k.name == "state.read")
+        assert read.args["start"] == c.args["start"]
+        # the past is gathered only where there is one
+        assert [k.name for k in kids].count("kv.gather") == \
+            (c.args["start"] > 0)
+    # a slot's alloc and free, one each a request
+    for name in ("state.alloc", "state.free"):
+        assert len([s for s in spans if s.name == name]) == len(handles)
+    # ONE decode program whatever the batch, one chunk program a shape
+    progs = {s.args["program"] for s in spans if s.name == "jit.dispatch"}
+    assert progs == {"serve.hybrid_chunk", "serve.hybrid_decode",
+                     "serve.kv_write", "serve.state_write"}
+    assert eng.executor.programs["hybrid_decode"].traces == 1
+
+
+def test_hybrid_span_budget_of_a_step(hybrid):
+    """A decode-only step records at most 8 spans, a prefill chunk at most
+    11 more (its own, the gather, the prep, the state's read, the
+    program, two writes with their dispatches, the fetch); none per
+    token, page or layer."""
+    eng, _, spans = serve(hybrid, lens=(5, 19, 30), new=12)
+    timed = [s for s in spans if s.dur is not None]
+    steps = [s for s in timed if s.name == "serve.step"]
+    _, ancestors = family(spans)
+    decode_only = 0
+    for step in steps:
+        inside = [s for s in timed if s is step
+                  or (s.ts >= step.ts and s.ts + s.dur <= step.ts + step.dur
+                      and "serve.step" in ancestors(s))]
+        chunks = sum(s.name == "req.prefill" for s in inside)
+        assert len(inside) <= 8 + 11 * chunks, [s.name for s in inside]
+        decode_only += not chunks
+    assert decode_only >= 5
+    instants = [s for s in spans if s.dur is None]
+    assert len(instants) == 6 * 3       # + state.alloc, state.free
