@@ -6,13 +6,16 @@ Reference: ``paddle/phi/kernels/fusion/gpu/block_multi_head_attention_kernel.cu`
 length-masked cache), the two kernels behind the reference Predictor's
 continuous-batching serving path.
 
-TPU-native: the page pool is a static [n_kv, num_pages, page_size, d]
-array per layer (XLA-friendly fixed shape — page capacity plays the
-role of the reference's pre-allocated block pool), the block table is a
-host-side free-list (allocation is control plane, not compute), decode
-attention runs the Pallas ``paged_attention`` TPU kernel over the page
-pool (dense gather fallback off-TPU), and prefill writes whole pages
-with one scatter.
+TPU-native: the page pool is one static [layers, n_kv, num_pages,
+page_size, d] array for keys and one for values (XLA-friendly fixed
+shape — page capacity plays the role of the reference's pre-allocated
+block pool), the block table is a host-side free-list (allocation is
+control plane, not compute), decode attention runs a Pallas TPU kernel
+over the page pool, addressed by layer in place (dense gather fallback
+off-TPU), and every write — a prefill span, a decode step's token —
+patches whole pages of the donated pool by row of its flat view
+(:func:`_write_span`, :func:`_put_token`), so no pool and no layer of
+one is ever copied.
 """
 from __future__ import annotations
 
@@ -72,21 +75,12 @@ def masked_multihead_attention(q, k_cache, v_cache, lengths, name=None):
     return out if wrap else out._data
 
 
-def _dense_paged_attention(q, k_pages, v_pages, lengths, page_indices):
-    """Reference semantics of the Pallas kernel, in plain XLA ops —
-    the off-TPU fallback and the parity oracle for tests.
-
-    q [B, H, D]; k/v_pages [KV, P, ps, D]; page_indices [B, pages_per_seq].
-    """
+def _window_attention(q, kc, vc, lengths):
+    """Decode attention over gathered windows, float32: q [B, H, D];
+    kc/vc [B, KV, T, D], each sequence's window laid dense; lengths [B]
+    keys each query reads."""
     B, H, D = q.shape
-    KV, _, ps, _ = k_pages.shape
-    pages_per_seq = page_indices.shape[1]
-    T = pages_per_seq * ps
-    # gather each sequence's pages -> dense [B, KV, T, D]
-    kc = jnp.swapaxes(k_pages[:, page_indices], 0, 1)  # [B, KV, pps, ps, D]
-    vc = jnp.swapaxes(v_pages[:, page_indices], 0, 1)
-    kc = kc.reshape(B, KV, T, D)
-    vc = vc.reshape(B, KV, T, D)
+    KV, T = kc.shape[1], kc.shape[2]
     g = H // KV
     qg = q.reshape(B, KV, g, D)
     logits = jnp.einsum("bkgd,bktd->bkgt", qg.astype(jnp.float32),
@@ -97,6 +91,36 @@ def _dense_paged_attention(q, k_pages, v_pages, lengths, page_indices):
     p = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bkgt,bktd->bkgd", p, vc.astype(jnp.float32))
     return out.reshape(B, H, D).astype(q.dtype)
+
+
+def _dense_paged_attention(q, k_pages, v_pages, lengths, page_indices):
+    """Reference semantics of the Pallas kernel, in plain XLA ops —
+    the off-TPU fallback and the parity oracle for tests.
+
+    q [B, H, D]; k/v_pages [KV, P, ps, D]; page_indices [B, pages_per_seq].
+    """
+    B, _, D = q.shape
+    KV, _, ps, _ = k_pages.shape
+    T = page_indices.shape[1] * ps
+    # gather each sequence's pages -> dense [B, KV, T, D]
+    kc = jnp.swapaxes(k_pages[:, page_indices], 0, 1)  # [B, KV, pps, ps, D]
+    vc = jnp.swapaxes(v_pages[:, page_indices], 0, 1)
+    return _window_attention(q, kc.reshape(B, KV, T, D),
+                             vc.reshape(B, KV, T, D), lengths)
+
+
+def _dense_pool_attention(q, k_pool, v_pool, lengths, page_indices, layer):
+    """:func:`_dense_paged_attention` over layer ``layer`` (an int32
+    scalar, traced or not) of the whole pools ``[L, KV, P, ps, D]``: each
+    sequence's window is gathered by row from the pool viewed flat, so no
+    layer is sliced out of it (a copy of that layer on the TPU)."""
+    B, _, D = q.shape
+    KV, ps = k_pool.shape[1], k_pool.shape[3]
+    T = page_indices.shape[1] * ps
+    rows = _rows(k_pool.shape, layer, page_indices)       # [B, KV, pps]
+    return _window_attention(q, _flat(k_pool)[rows].reshape(B, KV, T, D),
+                             _flat(v_pool)[rows].reshape(B, KV, T, D),
+                             lengths)
 
 
 def _dense_paged_attention_q(q, k_pages, v_pages, lengths, page_indices,
@@ -115,18 +139,8 @@ def _dense_paged_attention_q(q, k_pages, v_pages, lengths, page_indices,
     vsc = jnp.swapaxes(v_scales[:, page_indices], 0, 1)
     kc = kc.astype(jnp.float32) * ksc[..., None, None]
     vc = vc.astype(jnp.float32) * vsc[..., None, None]
-    kc = kc.reshape(B, KV, T, D)
-    vc = vc.reshape(B, KV, T, D)
-    g = H // KV
-    qg = q.reshape(B, KV, g, D)
-    logits = jnp.einsum("bkgd,bktd->bkgt", qg.astype(jnp.float32),
-                        kc) / np.sqrt(D)
-    mask = jnp.arange(T)[None, None, None, :] < \
-        lengths[:, None, None, None]
-    logits = jnp.where(mask, logits, -1e30)
-    p = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bkgt,bktd->bkgd", p, vc)
-    return out.reshape(B, H, D).astype(q.dtype)
+    return _window_attention(q, kc.reshape(B, KV, T, D),
+                             vc.reshape(B, KV, T, D), lengths)
 
 
 def _select_impl(head_dim, page_size):
@@ -165,7 +179,7 @@ def _select_impl(head_dim, page_size):
 
 def paged_decode_attention(q, k_pages, v_pages, lengths, page_indices,
                            pages_per_compute_block=4,
-                           k_scales=None, v_scales=None):
+                           k_scales=None, v_scales=None, layer=None):
     """Decode attention over the page pool.  On TPU this is the
     self-authored fused kernel (``ops/pallas_kernels/paged_decode.py``:
     per-sequence DMA page gather + whole decode attention in VMEM) or
@@ -173,6 +187,15 @@ def paged_decode_attention(q, k_pages, v_pages, lengths, page_indices,
     dense-gather fallback jit-cached through the op registry.  Routing
     is overridable via ``PT_PAGED_IMPL`` (see ``_select_impl``).
     Returns a Tensor iff ``q`` is a Tensor.
+
+    ``k_pages`` / ``v_pages`` are one layer's pool ``[KV, P, ps, D]``,
+    or, with ``layer`` (an int32 scalar, traced inside a scan over
+    layers), the WHOLE pools ``[L, KV, P, ps, D]``, addressed in place:
+    the fused kernel reads ``pool[layer, kv, page]`` and the dense path
+    gathers the windows by row of the flat pool, so neither slices a
+    layer out.  Only the stock kernel, which takes one layer's pool,
+    gets a ``dynamic_index_in_dim`` slice — a copy of that layer on the
+    TPU, the price of ``PT_PAGED_IMPL=stock`` (PERF.md section 7).
 
     ``k_scales``/``v_scales`` [KV, P] select the int8-page path
     (``PT_QUANT=int8``): the fused quant kernel when its (stricter)
@@ -187,6 +210,8 @@ def paged_decode_attention(q, k_pages, v_pages, lengths, page_indices,
     if k_scales is not None:
         from ..ops.pallas_kernels import paged_decode as _fused
 
+        if layer is not None:
+            raise ValueError("the int8 pool is attended a layer at a time")
         impl = _select_impl(q.shape[-1], k_pages.shape[2])
         if impl == "pallas" and (
                 _fused.supported_quant(q.shape[-1], k_pages.shape[2],
@@ -208,25 +233,33 @@ def paged_decode_attention(q, k_pages, v_pages, lengths, page_indices,
                       Tensor(jnp.asarray(v_scales, jnp.float32)))
         return out if wrap else out._data
 
-    impl = _select_impl(q.shape[-1], k_pages.shape[2])
+    impl = _select_impl(q.shape[-1], k_pages.shape[-2])
+    args = [Tensor(q), Tensor(jnp.asarray(k_pages)),
+            Tensor(jnp.asarray(v_pages)), Tensor(lengths),
+            Tensor(page_indices)]
+    if layer is not None:
+        layer = jnp.asarray(layer, jnp.int32)
+        args.append(Tensor(layer))
 
     if impl == "pallas":
         from ..ops.pallas_kernels import paged_decode as _fused
 
-        out = _fused.handle()(
-            Tensor(q), Tensor(jnp.asarray(k_pages)),
-            Tensor(jnp.asarray(v_pages)), Tensor(lengths),
-            Tensor(page_indices))
+        out = _fused.handle()(*args)
         return out if wrap else out._data
     if impl == "dense":
-        out = _op("paged_decode_attention", _dense_paged_attention,
-                  Tensor(q), Tensor(jnp.asarray(k_pages)),
-                  Tensor(jnp.asarray(v_pages)), Tensor(lengths),
-                  Tensor(page_indices))
+        name, fn = (("paged_decode_attention", _dense_paged_attention)
+                    if layer is None else
+                    ("paged_decode_attention_pool", _dense_pool_attention))
+        out = _op(name, fn, *args)
         return out if wrap else out._data
     from jax.experimental.pallas.ops.tpu.paged_attention import (
         paged_attention,
     )
+
+    if layer is not None:
+        k_pages, v_pages = (
+            jax.lax.dynamic_index_in_dim(p, layer, 0, keepdims=False)
+            for p in (k_pages, v_pages))
 
     blk = min(pages_per_compute_block, page_indices.shape[1])
     while page_indices.shape[1] % blk:
@@ -244,7 +277,42 @@ def paged_decode_attention(q, k_pages, v_pages, lengths, page_indices,
     return Tensor(out) if wrap else out
 
 
-# -- the pool's device-side writer ---------------------------------------
+# -- the pool's device-side writers --------------------------------------
+
+
+def _flat(pool):
+    """A page pool ``[A, KV, pages, page_size, W]`` as rows of whole
+    pages ``[A * KV * pages, page_size, W]`` (a bitcast), so that one
+    gather or scatter by row reaches any layer and head: slicing a layer
+    out first is a copy of that layer's pool on the TPU."""
+    return pool.reshape(-1, *pool.shape[3:])
+
+
+def _rows(pool_shape, layer, pids):
+    """Row of page ``pids[...]`` of every KV head of ``layer`` in the
+    flat pool: ``[..., KV]`` -> inserted as axis 1."""
+    _, KV, pages = pool_shape[:3]
+    base = (layer * KV + jnp.arange(KV, dtype=pids.dtype)) * pages
+    return base.reshape((1, KV) + (1,) * (pids.ndim - 1)) + pids[:, None]
+
+
+def _put_token(flat, pool_shape, layer, pids, offs, x):
+    """Write one token per sequence into layer ``layer`` of the flat
+    pool: x [S, KV, W] goes to slot ``offs[s]`` of page ``pids[s]`` (a
+    page id of ``pages`` or more is dropped).  Whole pages are read,
+    patched and written back: a row per token is a sub-tile write, for
+    which the TPU compiler re-lays the whole pool (PERF.md section 6,
+    PR 27).  No two sequences may name the same page: each would write
+    back its own patch of it, and one would win."""
+    ps = pool_shape[3]
+    rows = jnp.where((pids < pool_shape[2])[:, None],
+                     _rows(pool_shape, layer, pids), flat.shape[0])
+    old = flat.at[rows].get(mode="clip")                  # [S, KV, ps, W]
+    here = jnp.arange(ps, dtype=offs.dtype)[None, :] == offs[:, None]
+    new = jnp.where(here[:, None, :, None],
+                    x[:, :, None, :].astype(flat.dtype), old)
+    return flat.at[rows].set(new, mode="drop")
+
 
 
 def _write_span(kp, vp, k, v, pids, offs):

@@ -32,7 +32,9 @@ from ... import obs
 from ...analysis import CountedJit, ProgramContract, register_program
 from ...ops import quant as _quant
 from ...ops.nn_ops import _rms_norm_plain, _rope_plain
-from ..paged import PagedKVCache, paged_decode_attention
+from ..paged import (
+    PagedKVCache, _flat, _put_token, paged_decode_attention,
+)
 
 
 def _sp_prefill_enabled() -> bool:
@@ -872,70 +874,117 @@ class PagedExecutor:
         last = jax.lax.all_gather(x[:, -1], axis)     # [n, B, h]
         return self._head(last[n - 1], tops)[0], ks[:, 0], vs[:, 0]
 
-    def _decode_fwd(self, layers, tops, ids, positions, k_pages, v_pages,
-                    lengths, page_tables):
-        """One token per active sequence: ids [B], positions [B] (the
-        token's position).  Each layer writes the new token's KV into
-        its page (write-then-attend, so the paged attention over
-        lengths+1 includes the self term), then attends over the pool.
-        Returns (logits [B, V], k_pages', v_pages')."""
+    def _paged_layers(self, layers, tops, x, pos, k_pages, v_pages, pids,
+                      offs, lens, tables):
+        """The layer stack of the decode and verify programs: x [B, W, h]
+        holds W new tokens a sequence at positions ``pos`` [B, W].  Each
+        layer writes their K/V into slot ``offs`` of page ``pids`` (both
+        [B, W]; a page id of ``num_pages`` is dropped) and then attends
+        (write-then-attend, so the self term is in the pool): row (b, w)
+        reads ``lens[b * W + w]`` keys through ``tables`` [B * W, pps].
+        Returns (x, k_pages', v_pages').
+
+        Plain pools ``[L, KV, P, ps, D]`` are a CARRY of the scan, never
+        sliced, stacked or copied: a token goes in by whole pages of the
+        pool viewed flat (:func:`~..paged._put_token`, once per window
+        position, since a window's tokens share pages) and the attention
+        addresses the layer in place (``paged_decode_attention(..,
+        layer=layer)``).  Pools that are scanned inputs and stacked
+        outputs are copied by the TPU compiler, layer by layer and then
+        whole (PERF.md section 6, PR 29).  An int8 pool is a ``(pages,
+        scales)`` tuple and is scanned that way still: its write grows a
+        page's scale (``ops.quant.kv_write``) and its kernel takes one
+        layer's pool."""
         cfg = self.config
         nh, nkv, d = (cfg.num_attention_heads, cfg.num_key_value_heads,
                       cfg.head_dim)
-        ps = self.cache.page_size
-        B = ids.shape[0]
-        x = tops["embed"][ids][:, None]           # [B, 1, h]
-        pos = positions[:, None]
-        pids = page_tables[jnp.arange(B), positions // ps]  # [B]
-        offs = positions % ps
+        B, W = pos.shape
 
-        def block(x, lp_kv):
-            lp, kp, vp = lp_kv
+        def qkv(x, lp):
             h = _rms_norm_plain(x, lp["input_layernorm.weight"],
                                 epsilon=cfg.rms_norm_eps)
             q = _mm(h, lp["self_attn.q_proj.weight"]) \
-                .reshape(B, 1, nh, d)
+                .reshape(B, W, nh, d)
             k = _mm(h, lp["self_attn.k_proj.weight"]) \
-                .reshape(B, 1, nkv, d)
+                .reshape(B, W, nkv, d)
             v = _mm(h, lp["self_attn.v_proj.weight"]) \
-                .reshape(B, 1, nkv, d)
+                .reshape(B, W, nkv, d)
             q, k = _rope_plain(q, k, tops["cos"], tops["sin"],
                                position_ids=pos)
-            kh = jnp.swapaxes(k, 1, 2)[:, :, 0]   # [B, nkv, d]
-            vh = jnp.swapaxes(v, 1, 2)[:, :, 0]
-            if isinstance(kp, tuple):
-                # int8 pool slice (pages, per-page scales): quantize
-                # the new token on write (scale grow + resident
-                # requant), attend with the scales threaded through
-                kp = _quant.kv_write(kp[0], kp[1], pids, offs,
-                                     jnp.swapaxes(kh, 0, 1))
-                vp = _quant.kv_write(vp[0], vp[1], pids, offs,
-                                     jnp.swapaxes(vh, 0, 1))
-                o = paged_decode_attention(
-                    jnp.swapaxes(q, 1, 2)[:, :, 0], kp[0], vp[0],
-                    lengths + 1, page_tables,
-                    k_scales=kp[1], v_scales=vp[1])
-            else:
-                kp = kp.at[:, pids, offs].set(
-                    jnp.swapaxes(kh, 0, 1).astype(kp.dtype))
-                vp = vp.at[:, pids, offs].set(
-                    jnp.swapaxes(vh, 0, 1).astype(vp.dtype))
-                o = paged_decode_attention(
-                    jnp.swapaxes(q, 1, 2)[:, :, 0], kp, vp,
-                    lengths + 1, page_tables)     # [B, nh, d]
-            o = o.reshape(B, 1, nh * d).astype(x.dtype)
+            return q.reshape(B * W, nh, d), k, v
+
+        def rest(x, o, lp):
+            o = o.reshape(B, W, nh * d).astype(x.dtype)
             x = x + _mm(o, lp["self_attn.o_proj.weight"])
             h2 = _rms_norm_plain(x, lp["post_attention_layernorm.weight"],
                                  epsilon=cfg.rms_norm_eps)
             gate = _mm(h2, lp["mlp.gate_proj.weight"])
             up = _mm(h2, lp["mlp.up_proj.weight"])
-            x = x + _mm(jax.nn.silu(gate) * up,
-                        lp["mlp.down_proj.weight"])
-            return x, (kp, vp)
+            return x + _mm(jax.nn.silu(gate) * up,
+                           lp["mlp.down_proj.weight"])
 
-        x, (kps, vps) = jax.lax.scan(
-            block, x, (layers, k_pages, v_pages))
-        x = _rms_norm_plain(x, tops["norm_w"], epsilon=cfg.rms_norm_eps)
+        if isinstance(k_pages, tuple):
+            pids_f, offs_f = pids.reshape(-1), offs.reshape(-1)
+
+            def block_q(x, lp_kv):
+                lp, kp, vp = lp_kv
+                q, k, v = qkv(x, lp)
+                # quantize the new tokens on write (scale grow +
+                # resident requant; kv_write drops the sentinel page id
+                # like the plain patch), attend with the scales
+                kp = _quant.kv_write(
+                    *kp, pids_f, offs_f,
+                    jnp.swapaxes(k.reshape(B * W, nkv, d), 0, 1))
+                vp = _quant.kv_write(
+                    *vp, pids_f, offs_f,
+                    jnp.swapaxes(v.reshape(B * W, nkv, d), 0, 1))
+                o = paged_decode_attention(
+                    q, kp[0], vp[0], lens, tables,
+                    k_scales=kp[1], v_scales=vp[1])
+                return rest(x, o, lp), (kp, vp)
+
+            x, (kps, vps) = jax.lax.scan(
+                block_q, x, (layers, k_pages, v_pages))
+            return x, kps, vps
+
+        shape = k_pages.shape
+
+        def block(carry, lp_layer):
+            x, kf, vf = carry
+            lp, layer = lp_layer
+            q, k, v = qkv(x, lp)
+            for w in range(W):
+                kf = _put_token(kf, shape, layer, pids[:, w], offs[:, w],
+                                k[:, w])
+                vf = _put_token(vf, shape, layer, pids[:, w], offs[:, w],
+                                v[:, w])
+            o = paged_decode_attention(
+                q, kf.reshape(shape), vf.reshape(shape), lens, tables,
+                layer=layer)                          # [B * W, nh, d]
+            return (rest(x, o, lp), kf, vf), None
+
+        (x, kf, vf), _ = jax.lax.scan(
+            block, (x, _flat(k_pages), _flat(v_pages)),
+            (layers, jnp.arange(shape[0], dtype=jnp.int32)))
+        return x, kf.reshape(shape), vf.reshape(shape)
+
+    def _decode_fwd(self, layers, tops, ids, positions, k_pages, v_pages,
+                    lengths, page_tables):
+        """One token per active sequence: ids [B], positions [B] (the
+        token's position).  Each layer writes the new token's KV into
+        its page, then attends over lengths+1 keys of the pool
+        (:meth:`_paged_layers`, the window of one token); the donated
+        pools come back updated in place.
+        Returns (logits [B, V], k_pages', v_pages')."""
+        ps = self.cache.page_size
+        B = ids.shape[0]
+        pids = page_tables[jnp.arange(B), positions // ps]  # [B]
+        x, kps, vps = self._paged_layers(
+            layers, tops, tops["embed"][ids][:, None], positions[:, None],
+            k_pages, v_pages, pids[:, None], (positions % ps)[:, None],
+            lengths + 1, page_tables)
+        x = _rms_norm_plain(x, tops["norm_w"],
+                            epsilon=self.config.rms_norm_eps)
         return self._head(x[:, 0], tops), kps, vps
 
     def _decode_tok_fwd(self, layers, tops, ids, positions, k_pages,
@@ -959,13 +1008,13 @@ class PagedExecutor:
         many window tokens each sequence may commit (page budget /
         length cap / actual draft length), 1 <= limit <= W.
 
-        Write-then-attend like _decode_fwd, widened to the window: each
-        layer scatters all valid window KV into the pages (positions
-        past a sequence's limit are pushed out of bounds and dropped),
-        then attends with B*W query rows through the SAME
-        paged_decode_attention — row (b, w) masked to lengths[b]+w+1
-        keys, so causality inside the window comes from the length
-        mask, not a new kernel.
+        Write-then-attend like _decode_fwd, widened to the window
+        (:meth:`_paged_layers`): each layer patches all valid window KV
+        into the pages (positions past a sequence's limit get the page
+        id ``num_pages`` and are dropped), then attends with B*W query
+        rows through the SAME paged_decode_attention — row (b, w) masked
+        to lengths[b]+w+1 keys, so causality inside the window comes
+        from the length mask, not a new kernel.
 
         Greedy acceptance in-graph: with t = argmax(logits) per window
         position, draft token w+1 is accepted iff every earlier draft
@@ -979,73 +1028,27 @@ class PagedExecutor:
 
         Returns (packed_tokens [B*W], emit_n [B], k_pages', v_pages').
         """
-        cfg = self.config
-        nh, nkv, d = (cfg.num_attention_heads, cfg.num_key_value_heads,
-                      cfg.head_dim)
         ps = self.cache.page_size
         B, W = ids.shape
         pps = page_tables.shape[1]
         num_pages = (k_pages[0] if isinstance(k_pages, tuple)
                      else k_pages).shape[2]
-        x = tops["embed"][ids]                         # [B, W, h]
-        pos = lengths[:, None] + jnp.arange(W)[None]   # [B, W]
+        w = jnp.arange(W, dtype=jnp.int32)[None]
+        pos = lengths[:, None] + w                     # [B, W]
         slot = pos // ps
         pids = jnp.take_along_axis(page_tables,
                                    jnp.minimum(slot, pps - 1), axis=1)
         # invalid window cells (past the commit limit, or past the
-        # per-seq page budget) write out of bounds -> mode='drop'
-        valid_w = ((jnp.arange(W)[None] < limits[:, None])
-                   & (slot < pps))
-        pids = jnp.where(valid_w, pids, num_pages).reshape(-1)
-        offs = (pos % ps).reshape(-1)
+        # per-seq page budget) get the page id that every write drops
+        valid_w = (w < limits[:, None]) & (slot < pps)
         # one attention row per window cell; the +w+1 length mask is
         # the in-window causal mask
-        lens_f = (lengths[:, None] + jnp.arange(W)[None] + 1).reshape(-1)
-        tables_f = jnp.repeat(page_tables, W, axis=0)  # [B*W, pps]
-
-        def block(x, lp_kv):
-            lp, kp, vp = lp_kv
-            h = _rms_norm_plain(x, lp["input_layernorm.weight"],
-                                epsilon=cfg.rms_norm_eps)
-            q = _mm(h, lp["self_attn.q_proj.weight"]) \
-                .reshape(B, W, nh, d)
-            k = _mm(h, lp["self_attn.k_proj.weight"]) \
-                .reshape(B, W, nkv, d)
-            v = _mm(h, lp["self_attn.v_proj.weight"]) \
-                .reshape(B, W, nkv, d)
-            q, k = _rope_plain(q, k, tops["cos"], tops["sin"],
-                               position_ids=pos)
-            kf = jnp.swapaxes(k.reshape(B * W, nkv, d), 0, 1)
-            vf = jnp.swapaxes(v.reshape(B * W, nkv, d), 0, 1)
-            if isinstance(kp, tuple):
-                # kv_write scatters with mode='drop' throughout, so the
-                # num_pages sentinel pid of invalid window cells is
-                # dropped exactly like the plain path's scatter
-                kp = _quant.kv_write(kp[0], kp[1], pids, offs, kf)
-                vp = _quant.kv_write(vp[0], vp[1], pids, offs, vf)
-                o = paged_decode_attention(
-                    q.reshape(B * W, nh, d), kp[0], vp[0], lens_f,
-                    tables_f, k_scales=kp[1], v_scales=vp[1])
-            else:
-                kp = kp.at[:, pids, offs].set(kf.astype(kp.dtype),
-                                              mode="drop")
-                vp = vp.at[:, pids, offs].set(vf.astype(vp.dtype),
-                                              mode="drop")
-                o = paged_decode_attention(
-                    q.reshape(B * W, nh, d), kp, vp, lens_f, tables_f)
-            o = o.reshape(B, W, nh * d).astype(x.dtype)
-            x = x + _mm(o, lp["self_attn.o_proj.weight"])
-            h2 = _rms_norm_plain(x, lp["post_attention_layernorm.weight"],
-                                 epsilon=cfg.rms_norm_eps)
-            gate = _mm(h2, lp["mlp.gate_proj.weight"])
-            up = _mm(h2, lp["mlp.up_proj.weight"])
-            x = x + _mm(jax.nn.silu(gate) * up,
-                        lp["mlp.down_proj.weight"])
-            return x, (kp, vp)
-
-        x, (kps, vps) = jax.lax.scan(
-            block, x, (layers, k_pages, v_pages))
-        x = _rms_norm_plain(x, tops["norm_w"], epsilon=cfg.rms_norm_eps)
+        x, kps, vps = self._paged_layers(
+            layers, tops, tops["embed"][ids], pos, k_pages, v_pages,
+            jnp.where(valid_w, pids, num_pages), pos % ps,
+            (pos + 1).reshape(-1), jnp.repeat(page_tables, W, axis=0))
+        x = _rms_norm_plain(x, tops["norm_w"],
+                            epsilon=self.config.rms_norm_eps)
         t = jnp.argmax(self._head(x, tops), -1).astype(jnp.int32)
         # accepted = longest prefix of drafts matching the model's own
         # greedy choices; always commit 1 + accepted (the model's next

@@ -78,7 +78,7 @@ from ... import obs
 from ...analysis import CountedJit
 from ...models import granite_hybrid as gh
 from ...ops.pallas_kernels import ssm_decode as _ssm
-from ..paged import PagedKVCache
+from ..paged import PagedKVCache, _flat, _put_token, _rows
 from ..state_cache import RecurrentStateCache
 
 _F32 = jnp.float32
@@ -95,39 +95,6 @@ def _free_device_bytes():
     if not stats or "bytes_limit" not in stats:
         return None
     return int(stats["bytes_limit"]) - int(stats["bytes_in_use"])
-
-
-def _flat(pool):
-    """A page pool ``[A, KV, pages, page_size, W]`` as rows of whole
-    pages ``[A * KV * pages, page_size, W]`` (a bitcast), so that one
-    gather or scatter by row reaches any layer and head: slicing a layer
-    out first is a copy of that layer's pool on the TPU."""
-    return pool.reshape(-1, *pool.shape[3:])
-
-
-def _rows(pool_shape, layer, pids):
-    """Row of page ``pids[...]`` of every KV head of ``layer`` in the
-    flat pool: ``[..., KV]`` -> inserted as axis 1."""
-    _, KV, pages = pool_shape[:3]
-    base = (layer * KV + jnp.arange(KV, dtype=pids.dtype)) * pages
-    return base.reshape((1, KV) + (1,) * (pids.ndim - 1)) + pids[:, None]
-
-
-def _put_token(flat, pool_shape, layer, pids, offs, x):
-    """Write one token per sequence into layer ``layer`` of the flat
-    pool: x [S, KV, W] goes to slot ``offs[s]`` of page ``pids[s]`` (a
-    page id of ``pages`` or more is dropped).  Whole pages are read,
-    patched and written back: a row per token is a sub-tile write, for
-    which the TPU compiler re-lays the whole pool (PERF.md section 6,
-    PR 27)."""
-    ps = pool_shape[3]
-    rows = jnp.where((pids < pool_shape[2])[:, None],
-                     _rows(pool_shape, layer, pids), flat.shape[0])
-    old = flat.at[rows].get(mode="clip")                  # [S, KV, ps, W]
-    here = jnp.arange(ps, dtype=offs.dtype)[None, :] == offs[:, None]
-    new = jnp.where(here[:, None, :, None],
-                    x[:, :, None, :].astype(flat.dtype), old)
-    return flat.at[rows].set(new, mode="drop")
 
 
 def _fold_factor(n_kv_heads, head_dim, lanes=128):

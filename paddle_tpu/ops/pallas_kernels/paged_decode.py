@@ -7,11 +7,11 @@ reference's continuous-batching serving path.  The role, not the
 design.
 
 TPU design: one program per (sequence, kv-head).  The program DMAs the
-sequence's block-table window — ``pages_per_seq`` pages of
-``[page_size, head_dim]`` K and V — from the HBM page pool into VMEM
-scratch (all copies started before any is waited on, so the gather is
-one pipelined burst), then computes the whole decode attention for that
-head group in VMEM:
+pages of the sequence's block-table window that hold its tokens —
+``[page_size, head_dim]`` K and V each — from the HBM page pool into
+VMEM scratch (all copies started before any is waited on, so the gather
+is one pipelined burst; a loop over pages, :func:`_gather_window`), then
+computes the whole decode attention for that head group in VMEM:
 
     scores = q_group @ K_window^T * scale      [group, S_window]
     p      = softmax(scores  masked to length)
@@ -32,11 +32,22 @@ traffic is the theoretical floor (read each page once, write [B, H, D]
 once).
 
 Layout contract (matches PagedKVCache):
-  q            [B, KV, G, D]   (G = H // KV query heads per KV head)
-  k/v_pages    [KV, P, ps, D]  (the pool; P = total pages)
-  lengths      [B]   int32     valid tokens per sequence
-  page_indices [B, pps] int32  each sequence's block-table window
+  q            [B, KV, G, D]      (G = H // KV query heads per KV head)
+  k/v_pages    [L, KV, P, ps, D]  (the WHOLE pool, every layer; P = pages
+                                   of a layer)
+  layer        int32 scalar       the layer this call attends over
+  lengths      [B]   int32        valid tokens per sequence
+  page_indices [B, pps] int32     each sequence's block-table window
 returns        [B, KV, G, D]
+
+The layer is a prefetched scalar beside the lengths and the page table,
+and a page's DMA reads ``pool[layer, kv, page]``: the pool stays where it
+is in HBM, is only read, and nothing the size of a layer is ever sliced
+out of it (on the TPU such a slice is a copy of 134 MB at the benchmark's
+size).  Inside a ``lax.scan`` over layers the pool is the carry.  A pool
+of one layer, ``[KV, P, ps, D]``, is the same pool with ``L = 1`` and
+``layer = 0`` (:func:`paged_decode` reshapes it, a bitcast).  The int8
+kernel (:func:`paged_decode_quant`) still takes one layer's pool.
 
 TPU constraints (callers gate, inference/paged.py): D % 128 == 0 (lane
 tiling), page_size % 8 == 0 (sublane tiling of the DMA'd page; 32 for
@@ -54,46 +65,62 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _kernel(len_ref, tbl_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf,
-            sem, *, page_size, pages_per_seq, scale):
+def _gather_window(page_of, npages, pools, bufs, sem, *, page_size,
+                   pages_per_seq):
+    """DMA pages ``0 .. npages`` of a sequence's window from each HBM
+    pool (``page_of(pool, i)`` is page ``i``'s ``[page_size, D]`` slice
+    of it) into its VMEM buffer, and zero the window's tail.
+
+    EVERY needed copy is started before any is waited on (the DMA engine
+    pipelines them).  The tail is zeroed because VMEM scratch holds
+    garbage from the previous program, and a NaN bit pattern in V would
+    poison p @ V even at p == 0.
+
+    Three loops over pages, not an unroll: unrolled over a window of 128
+    pages the kernel's body is ~400 conditionals, traced again for every
+    decode batch size, and that trace was most of a serving engine's
+    set-up (PERF.md section 6, PR 29)."""
+    def rows(i):
+        return pl.ds(pl.multiple_of(i * page_size, page_size), page_size)
+
+    def copies(i):
+        return [pltpu.make_async_copy(page_of(pool, i), buf.at[rows(i)], sem)
+                for pool, buf in zip(pools, bufs)]
+
+    def start(i, carry):
+        for dma in copies(i):
+            dma.start()
+        return carry
+
+    def zero(i, carry):
+        for buf in bufs:
+            buf[rows(i)] = jnp.zeros((page_size, buf.shape[-1]), buf.dtype)
+        return carry
+
+    def wait(i, carry):
+        for dma in copies(i):
+            dma.wait()
+        return carry
+
+    jax.lax.fori_loop(0, npages, start, 0)
+    jax.lax.fori_loop(npages, jnp.int32(pages_per_seq), zero, 0)
+    jax.lax.fori_loop(0, npages, wait, 0)
+
+
+def _kernel(len_ref, tbl_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf,
+            v_buf, sem, *, page_size, pages_per_seq, scale):
     b = pl.program_id(0)
     kv = pl.program_id(1)
+    layer = layer_ref[0]
     # Keep every scalar explicitly i32: the repo's global x64 mode turns
     # weak Python-int constants into i64 at lowering, and a mixed
     # i32/i64 divide fails StableHLO verification (interpret mode) and
     # Mosaic (compiled).
     length = len_ref[b]
-    npages = pl.cdiv(length, jnp.int32(page_size))
-
-    def page_dma(i, pool, buf):
-        """HBM pool page -> VMEM window row block, one async copy."""
-        return pltpu.make_async_copy(
-            pool.at[kv, tbl_ref[b, i]],
-            buf.at[pl.ds(i * page_size, page_size)],
-            sem)
-
-    # Start EVERY needed page copy before waiting on any (the DMA engine
-    # pipelines them); zero the window tail instead — VMEM scratch holds
-    # garbage from the previous program, and a NaN bit pattern in V
-    # would poison p @ V even at p == 0.
-    for i in range(pages_per_seq):
-        @pl.when(i < npages)
-        def _start():
-            page_dma(i, k_hbm, k_buf).start()
-            page_dma(i, v_hbm, v_buf).start()
-
-        @pl.when(i >= npages)
-        def _zero():
-            k_buf[pl.ds(i * page_size, page_size)] = jnp.zeros(
-                (page_size, k_buf.shape[-1]), k_buf.dtype)
-            v_buf[pl.ds(i * page_size, page_size)] = jnp.zeros(
-                (page_size, v_buf.shape[-1]), v_buf.dtype)
-
-    for i in range(pages_per_seq):
-        @pl.when(i < npages)
-        def _wait():
-            page_dma(i, k_hbm, k_buf).wait()
-            page_dma(i, v_hbm, v_buf).wait()
+    _gather_window(lambda pool, i: pool.at[layer, kv, tbl_ref[b, i]],
+                   pl.cdiv(length, jnp.int32(page_size)),
+                   (k_hbm, v_hbm), (k_buf, v_buf), sem,
+                   page_size=page_size, pages_per_seq=pages_per_seq)
 
     q = q_ref[0, 0].astype(jnp.float32) * jnp.float32(scale)  # [G, D]
     k = k_buf[...].astype(jnp.float32)               # [S_window, D]
@@ -116,23 +143,27 @@ def _interpret():
 
 
 @functools.partial(jax.jit, static_argnames=("scale",))
-def _call(q, k_pages, v_pages, lengths, page_indices, scale):
+def _call(q, k_pages, v_pages, lengths, page_indices, layer, scale):
+    """The jitted wrapper: the device trace names the kernel's event
+    ``_call [tpu_custom_call]`` after it (the benchmark's
+    ``paged_decode_roofline`` matches that name)."""
     B, KV, G, D = q.shape
-    ps = k_pages.shape[2]
+    ps = k_pages.shape[3]
     pps = page_indices.shape[1]
     kernel = functools.partial(_kernel, page_size=ps, pages_per_seq=pps,
                                scale=scale)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,          # lengths + page table
+        num_scalar_prefetch=3,          # lengths + page table + layer
         grid=(B, KV),
         in_specs=[
             pl.BlockSpec((1, 1, G, D),
-                         lambda b, kv, lens, tbl: (b, kv, 0, 0)),
+                         lambda b, kv, lens, tbl, layer: (b, kv, 0, 0)),
             pl.BlockSpec(memory_space=pl.ANY),   # K pool stays in HBM
             pl.BlockSpec(memory_space=pl.ANY),   # V pool stays in HBM
         ],
         out_specs=pl.BlockSpec((1, 1, G, D),
-                               lambda b, kv, lens, tbl: (b, kv, 0, 0)),
+                               lambda b, kv, lens, tbl, layer:
+                               (b, kv, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((pps * ps, D), k_pages.dtype),
             pltpu.VMEM((pps * ps, D), v_pages.dtype),
@@ -148,24 +179,35 @@ def _call(q, k_pages, v_pages, lengths, page_indices, scale):
             out_shape=jax.ShapeDtypeStruct((B, KV, G, D), q.dtype),
             interpret=_interpret(),
         )(jnp.asarray(lengths, jnp.int32),
-          jnp.asarray(page_indices, jnp.int32), q, k_pages, v_pages)
+          jnp.asarray(page_indices, jnp.int32),
+          jnp.asarray(layer, jnp.int32).reshape(1), q, k_pages, v_pages)
 
 
-def paged_decode(q, k_pages, v_pages, lengths, page_indices, scale=None):
+def paged_decode(q, k_pages, v_pages, lengths, page_indices, layer=None,
+                 scale=None):
     """Fused paged-decode attention over the page pool.
 
-    q [B, H, D] (H % KV == 0); k/v_pages [KV, P, ps, D]; lengths [B];
-    page_indices [B, pps].  Returns [B, H, D].  Pure function of its
-    arguments (no custom VJP: decode is inference-only).
+    q [B, H, D] (H % KV == 0); lengths [B]; page_indices [B, pps];
+    k/v_pages either the whole pool ``[L, KV, P, ps, D]`` with ``layer``
+    (an int32 scalar, traced or not) naming the layer to attend over, or
+    one layer's pool ``[KV, P, ps, D]`` with no ``layer``.  Returns
+    [B, H, D].  Pure function of its arguments (no custom VJP: decode is
+    inference-only).
     """
     B, H, D = q.shape
-    KV = k_pages.shape[0]
+    if k_pages.ndim == 4:
+        if layer is not None:
+            raise ValueError("a layer was named for a pool of one layer")
+        k_pages, v_pages, layer = k_pages[None], v_pages[None], 0
+    elif layer is None:
+        raise ValueError("a pool [L, KV, P, ps, D] needs its layer named")
+    KV = k_pages.shape[1]
     if H % KV:
         raise ValueError(f"q heads {H} not a multiple of kv heads {KV}")
     if scale is None:
         scale = 1.0 / (D ** 0.5)
     qg = q.reshape(B, KV, H // KV, D)
-    out = _call(qg, k_pages, v_pages, lengths, page_indices,
+    out = _call(qg, k_pages, v_pages, lengths, page_indices, layer,
                 float(scale))
     return out.reshape(B, H, D)
 
@@ -181,32 +223,10 @@ def _kernel_quant(len_ref, tbl_ref, ks_ref, vs_ref, q_ref, k_hbm, v_hbm,
     b = pl.program_id(0)
     kv = pl.program_id(1)
     length = len_ref[b]
-    npages = pl.cdiv(length, jnp.int32(page_size))
-
-    def page_dma(i, pool, buf):
-        return pltpu.make_async_copy(
-            pool.at[kv, tbl_ref[b, i]],
-            buf.at[pl.ds(i * page_size, page_size)],
-            sem)
-
-    for i in range(pages_per_seq):
-        @pl.when(i < npages)
-        def _start():
-            page_dma(i, k_hbm, k_buf).start()
-            page_dma(i, v_hbm, v_buf).start()
-
-        @pl.when(i >= npages)
-        def _zero():
-            k_buf[pl.ds(i * page_size, page_size)] = jnp.zeros(
-                (page_size, k_buf.shape[-1]), k_buf.dtype)
-            v_buf[pl.ds(i * page_size, page_size)] = jnp.zeros(
-                (page_size, v_buf.shape[-1]), v_buf.dtype)
-
-    for i in range(pages_per_seq):
-        @pl.when(i < npages)
-        def _wait():
-            page_dma(i, k_hbm, k_buf).wait()
-            page_dma(i, v_hbm, v_buf).wait()
+    _gather_window(lambda pool, i: pool.at[kv, tbl_ref[b, i]],
+                   pl.cdiv(length, jnp.int32(page_size)),
+                   (k_hbm, v_hbm), (k_buf, v_buf), sem,
+                   page_size=page_size, pages_per_seq=pages_per_seq)
 
     # Per-row dequant scale for the window: row r belongs to window page
     # r // page_size, whose pool page id is tbl[b, i] — a static unroll
@@ -319,7 +339,7 @@ def supported_quant(head_dim, page_size, on_tpu):
 
 
 def paged_decode_spmd_rule(mesh, q_spec, k_spec, v_spec, len_spec,
-                           tbl_spec):
+                           tbl_spec, layer_spec=None):
     """SPMD rule: shard the batch dim (grid axis 0 — programs are
     independent per sequence) and/or the head dim (grid axis 1 — the
     pools' KV axis must carry the same sharding); D and the page axes

@@ -2,21 +2,40 @@
 size, here, with no chip attached: what the chip's compiler makes of them
 costs no chip time to see.  Every test that describes the topology lives in
 this one file (one process at a time may load the TPU's library), and the
-description is made inside a fixture, never while a module is imported."""
+description is made inside a fixture, never while a module is imported.
+
+Pinned so far, each with the pools aliased from input to output and no copy
+of a pool or of a layer of one: mistral7b-serve's page writer
+(``serve.kv_write``) and its whole decode step (``serve.decode``, the pools
+carried through the layer scan into the Pallas kernel); granite4h-micro's
+state kernel inside a scan, its state writer, and the page patch and window
+gather on its folded KV pool.  Each has a control beside it or in it that
+shows the compiler's copies when the program is written the other way, so a
+serving program can be checked for pool copies before any chip time."""
 import os
 import re
+import types
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from paddle_tpu.inference.paged import _write_span
+from paddle_tpu.inference.paged import (
+    _flat, _put_token, _rows, _write_span,
+)
 
 # mistral7b-serve's pools: 8 layers x 8 KV heads x 4,096 pages x 16 x 128
 POOL = (8, 8, 4096, 16, 128)
+# a whole pool (1.07 GB) copied or re-laid: what a row-per-token scatter
+# costs the page writer
 POOL_SIZED_COPY = re.compile(
     r"= bf16\[8,8,4096,16,128\]\S* (copy|transpose)\(")
+# a pool or ONE LAYER of it (134 MB) produced by a copy, a transpose or a
+# fusion (a layer sliced out of the pool, re-laid, or stacked back): what a
+# decode step made 8 + 2 times before the pools were a carry of its scan
+POOL_OR_LAYER_MOVED = re.compile(
+    r"= bf16\[(8,|1,)?8,4096,16,128\]\S* (copy|transpose|fusion)\(")
 
 
 @pytest.fixture(scope="module")
@@ -32,10 +51,15 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-def compile_writer(fn, one_chip, T, index):
-    def sds(shape, dtype):
+@pytest.fixture(scope="module")
+def sds(one_chip):
+    """A shape on the described chip (bf16 unless told)."""
+    def sds(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    return sds
 
+
+def compile_writer(fn, sds, T, index):
     pool = sds(POOL, jnp.bfloat16)
     kv = sds((POOL[0], POOL[1], T, POOL[4]), jnp.bfloat16)
     return jax.jit(fn, donate_argnums=(0, 1)).lower(
@@ -43,10 +67,10 @@ def compile_writer(fn, one_chip, T, index):
 
 
 @pytest.mark.parametrize("T", [256, 8])
-def test_the_page_writer_moves_no_pool(one_chip, T):
+def test_the_page_writer_moves_no_pool(sds, T):
     """serve.kv_write at a chunk of the cell: both pools aliased to the
     outputs, no temporary worth the name, and no copy of a pool's size."""
-    exe = compile_writer(_write_span, one_chip, T,
+    exe = compile_writer(_write_span, sds, T,
                          [((T - 1) // 16 + 2,), ()])
     text, mem = exe.as_text(), exe.memory_analysis()
     assert "input_output_alias={ {0}: (0, {}, may-alias), " \
@@ -56,7 +80,7 @@ def test_the_page_writer_moves_no_pool(one_chip, T):
     assert not POOL_SIZED_COPY.search(text)
 
 
-def test_a_row_per_token_re_lays_the_pool(one_chip):
+def test_a_row_per_token_re_lays_the_pool(sds):
     """Why the writer moves whole pages: the same span as one scatter of
     token rows makes the compiler copy each pool into another layout and
     back (the control that shows the test above can see such a copy)."""
@@ -64,9 +88,90 @@ def test_a_row_per_token_re_lays_the_pool(one_chip):
         return (kp.at[:, :, pids, offs].set(k),
                 vp.at[:, :, pids, offs].set(v))
 
-    exe = compile_writer(rows, one_chip, 256, [(256,), (256,)])
+    exe = compile_writer(rows, sds, 256, [(256,), (256,)])
     assert exe.memory_analysis().temp_size_in_bytes > 1 << 30
     assert len(POOL_SIZED_COPY.findall(exe.as_text())) == 4
+
+
+# -- the decode step (mistral7b-serve.closed32) ------------------------------
+
+
+@pytest.fixture
+def compiled_kernel(monkeypatch):
+    """Steer the program as the chip would: the fused kernel, compiled
+    (here the backend is the CPU, which takes the dense path and the
+    interpreter)."""
+    from paddle_tpu.ops.pallas_kernels import paged_decode
+
+    monkeypatch.setenv("PT_PAGED_IMPL", "pallas")
+    monkeypatch.setattr(paged_decode, "_interpret", lambda: False)
+
+
+@pytest.mark.parametrize("batch", [32, 17])
+def test_the_decode_step_moves_no_pool(sds, compiled_kernel, batch):
+    """serve.decode at the cell's size — Mistral-7B widths, 8 layers, a
+    table of 128 pages a sequence: both pools aliased to the outputs, the
+    kernel in the program, and neither a pool nor a layer of one copied,
+    re-laid or stacked; the temporaries stay under one layer's 134 MB."""
+    from paddle_tpu.inference.server.executor import PagedExecutor
+
+    ex = object.__new__(PagedExecutor)
+    ex.config = types.SimpleNamespace(
+        num_attention_heads=32, num_key_value_heads=8, head_dim=128,
+        rms_norm_eps=1e-5)
+    ex.cache = types.SimpleNamespace(page_size=POOL[3])
+    ex._tied = False
+    L, H, F, V = POOL[0], 4096, 14336, 32768
+    layers = {"input_layernorm.weight": sds((L, H)),
+              "post_attention_layernorm.weight": sds((L, H)),
+              "self_attn.q_proj.weight": sds((L, H, H)),
+              "self_attn.k_proj.weight": sds((L, H, 1024)),
+              "self_attn.v_proj.weight": sds((L, H, 1024)),
+              "self_attn.o_proj.weight": sds((L, H, H)),
+              "mlp.gate_proj.weight": sds((L, H, F)),
+              "mlp.up_proj.weight": sds((L, H, F)),
+              "mlp.down_proj.weight": sds((L, F, H))}
+    tops = {"embed": sds((V, H)), "norm_w": sds((H,)),
+            "head_w": sds((H, V)), "cos": sds((V, 128), jnp.float32),
+            "sin": sds((V, 128), jnp.float32)}
+    i32 = jnp.int32
+    exe = jax.jit(ex._decode_fwd, donate_argnums=(4, 5)).lower(
+        layers, tops, sds((batch,), i32), sds((batch,), i32), sds(POOL),
+        sds(POOL), sds((batch,), i32), sds((batch, 128), i32)).compile()
+    text, mem = exe.as_text(), exe.memory_analysis()
+    assert re.search(r"input_output_alias=\{ \{1\}: \(\d+, \{\}, may-alias\), "
+                     r"\{2\}: \(\d+, \{\}, may-alias\) \}",
+                     text[:text.index("\n")])
+    assert mem.alias_size_in_bytes == 2 * 2 * 8 * 8 * 4096 * 16 * 128
+    assert mem.temp_size_in_bytes < 128 << 20
+    assert "tpu_custom_call" in text
+    assert not POOL_OR_LAYER_MOVED.search(text)
+
+
+def test_pools_scanned_layer_by_layer_are_moved(sds, compiled_kernel):
+    """The control: the decode step's pool handling as it was before — the
+    pools as scanned inputs, a row per token scattered into the layer's
+    slice, the kernel on that slice, the slices stacked back as outputs.
+    The compiler slices every layer out, re-lays it and copies it back."""
+    from paddle_tpu.ops.pallas_kernels.paged_decode import paged_decode
+
+
+    def step(q, k_pages, v_pages, kv, pids, offs, lengths, tables):
+        def block(q, pools):
+            kp, vp = pools
+            kp = kp.at[:, pids, offs].set(kv)
+            vp = vp.at[:, pids, offs].set(kv)
+            return q + paged_decode(q, kp, vp, lengths, tables), (kp, vp)
+
+        return jax.lax.scan(block, q, (k_pages, v_pages))
+
+    i32 = jnp.int32
+    exe = jax.jit(step, donate_argnums=(1, 2)).lower(
+        sds((32, 32, 128)), sds(POOL), sds(POOL), sds((8, 32, 128)),
+        sds((32,), i32), sds((32,), i32), sds((32,), i32),
+        sds((32, 128), i32)).compile()
+    assert exe.memory_analysis().temp_size_in_bytes > 128 << 20
+    assert len(POOL_OR_LAYER_MOVED.findall(exe.as_text())) >= 4
 
 
 # -- the hybrid executor's state path (granite4h-micro-serve) -------------------
@@ -77,7 +182,7 @@ STATE_SIZED_COPY = re.compile(
     r"= f32\[9,64,32,128,128\]\S* (copy|transpose)\(")
 
 
-def test_the_state_kernel_updates_the_carried_pool_in_place(one_chip):
+def test_the_state_kernel_updates_the_carried_pool_in_place(sds):
     """ops/pallas_kernels/ssm_decode.py at the cell's size, inside a scan
     over the run's layers with the pool as the carry: Mosaic takes the
     kernel, the pool is aliased to the output, and no copy of its size
@@ -85,9 +190,6 @@ def test_the_state_kernel_updates_the_carried_pool_in_place(one_chip):
     from paddle_tpu.ops.pallas_kernels import ssm_decode
 
     L, S, G, N, KP = STATE
-
-    def sds(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     def step(pool, decay, xdt, B, C, live):
         def layer(carry, i):
@@ -114,13 +216,10 @@ def test_the_state_kernel_updates_the_carried_pool_in_place(one_chip):
     assert not STATE_SIZED_COPY.search(text)
 
 
-def test_the_state_writer_moves_no_pool(one_chip):
+def test_the_state_writer_moves_no_pool(sds):
     """serve.state_write: one slot's rows of every layer of a run into the
     donated pools, nothing else of them moved."""
     from paddle_tpu.inference.state_cache import _write_slot
-
-    def sds(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     ssm, conv = sds(STATE, jnp.float32), sds((9, 3, 64, 4352), jnp.bfloat16)
     exe = jax.jit(_write_slot, donate_argnums=(0, 1)).lower(
@@ -133,21 +232,18 @@ def test_the_state_writer_moves_no_pool(one_chip):
     assert not STATE_SIZED_COPY.search(text)
 
 
-def test_a_token_into_the_folded_kv_pool_moves_no_pool(one_chip):
+def test_a_token_into_the_folded_kv_pool_moves_no_pool(sds):
     """The hybrid decode's page patch and window gather on the pool of 4
     attention layers x 4 folded KV heads x 8,192 pages x 16 x 128: the
     pool is aliased, and neither a layer (134 MB) nor the pool is copied
     — only each sequence's window is gathered."""
     from paddle_tpu.inference.server import hybrid_executor as hx
 
+    assert (hx._flat, hx._rows, hx._put_token) == (_flat, _rows, _put_token)
     shape = (4, 4, 8192, 16, 128)
 
-    def sds(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
     def step(pool, pids, offs, x, q, lengths, tables):
-        flat = hx._flat(pool)
-        flat = hx._put_token(flat, shape, 2, pids, offs, x)
+        flat = _put_token(_flat(pool), shape, 2, pids, offs, x)
         o = hx._pool_attention(q, flat, flat, shape, 2, lengths, tables, 2)
         return flat.reshape(shape), o
 

@@ -126,3 +126,21 @@ def test_supported_gate():
     assert not supported(head_dim=64, page_size=16, on_tpu=True)
     assert not supported(head_dim=128, page_size=6, on_tpu=True)
     assert not supported(head_dim=128, page_size=16, on_tpu=False)
+
+
+def test_the_kernel_body_does_not_grow_with_the_window():
+    """The page DMAs are loops, not an unroll: the traced program is as
+    long at 64 pages a sequence as at 4.  (Unrolled, the kernel was
+    re-traced for every decode batch size at ~400 conditionals a trace,
+    most of a serving engine's set-up.)"""
+    import jax
+
+    def eqns(pps):
+        rng = np.random.RandomState(0)
+        q, kp, vp, table = _mk(rng, B=2, H=4, KV=2, D=16, P=2 * pps, ps=4,
+                               pps=pps)
+        text = str(jax.make_jaxpr(paged_decode)(
+            q, kp, vp, np.array([3, 4 * pps], np.int32), table))
+        return text.count("\n")
+
+    assert eqns(64) == eqns(4)
