@@ -1,15 +1,14 @@
-# Developer entry points.  Every target but `bench` runs on the CPU
-# (JAX_PLATFORMS=cpu, which jax honours).  `bench` is the chip path: it
-# exits non-zero without a TPU, so run it — like `python chip_smoke.py`
-# — through the chip tool.  `bench-cpu-counts` is the explicit CPU
-# count lane that feeds `perf-check`.
+# Developer entry points.  Every target runs on the CPU
+# (JAX_PLATFORMS=cpu, which jax honours).  How fast the system is comes
+# from the chip alone: `python3 chipbench/run.py` (BENCHMARK.json,
+# recorded by the driver in PERF_LEDGER.jsonl) and `python chip_smoke.py`
+# exit non-zero without a TPU, so run them through the chip tool.
 
 PY ?= python
 
 .PHONY: smoke test test-fast verify-fast lint-graph obs-check \
 	health-check aot-check cluster-check chaos-check \
-	durability-check sp-check perf-report perf-check bench \
-	bench-cpu-counts
+	durability-check sp-check
 
 # <3 min sanity gate: import + one eager op, one jitted llama forward
 # step (the driver's entry()), and a 2-virtual-device multichip train
@@ -129,25 +128,8 @@ durability-check:
 sp-check:
 	JAX_PLATFORMS=cpu $(PY) tools/sp_prefill_check.py
 
-# Per-program roofline table: analytical cost (FLOPs / HBM bytes /
-# intensity from the jaxpr cost model) vs achieved wall time for every
-# registered hot program, built live on CPU like lint-graph.
-perf-report:
-	JAX_PLATFORMS=cpu $(PY) tools/perf_report.py
-
-# Bench regression gate: newest usable BENCH_r*.json vs the previous
-# one, per-metric tolerances; fails on any regressed metric.
-perf-check:
-	$(PY) tools/check_perf.py
-
 # Fast lane + regression gate: fails ONLY on failures not recorded in
 # tools/fastlane_baseline.txt, so a dirty-but-known lane never blocks
 # unrelated work while any NEW breakage does.
-verify-fast: lint-graph perf-check
+verify-fast: lint-graph
 	$(PY) tools/check_fastlane.py
-
-bench:
-	$(PY) bench.py
-
-bench-cpu-counts:
-	JAX_PLATFORMS=cpu $(PY) bench.py --cpu-counts

@@ -21,12 +21,11 @@ Byte accounting is the roofline numerator: program inputs + outputs
 (every train/serve step streams its operands through HBM once) plus the
 largest intermediate as a working-set estimate — all via the walker's
 ``_aval_nbytes``.  ``shard_map`` bodies carry per-shard shapes, so every
-figure is per chip, matching the per-chip MFU convention in bench.py.
+figure is per chip.
 
 ``transformer_flops_per_token`` hosts the closed-form 6N + attention
-estimate that bench.py and the hapi models previously re-derived inline;
-keeping one copy here is what lets tests assert bench-vs-cost-model
-agreement to the digit.
+estimate the models' ``flops_per_token`` share; keeping one copy here
+is what lets tests assert model-vs-cost-model agreement to the digit.
 """
 from __future__ import annotations
 
@@ -56,9 +55,9 @@ def transformer_flops_per_token(num_params, num_layers, hidden_size,
                                 seq_len):
     """Megatron-style fwd+bwd FLOPs per token: ``6·N`` for the parameter
     GEMMs plus ``12·L·H·S`` for attention score/value matmuls.  This is
-    the single home of the estimate bench.py's MFU legs and the hapi
-    models' ``flops_per_token`` share (remat's extra forward is hardware
-    overhead, deliberately not counted as useful FLOPs)."""
+    the single home of the estimate the models' ``flops_per_token``
+    share (remat's extra forward is hardware overhead, deliberately not
+    counted as useful FLOPs)."""
     return (6 * int(num_params)
             + 12 * int(num_layers) * int(hidden_size) * int(seq_len))
 

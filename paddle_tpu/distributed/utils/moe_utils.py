@@ -210,7 +210,7 @@ def ep_moe_local(tokens, wg, w1, b1, w2, b2, *, axis_name, n, num_experts,
                  top_k, capacity, activation, gate_kind, impl=None):
     """Per-device EP MoE body (runs inside shard_map over ``axis_name``;
     ``axis_name=None`` runs the same body single-device — the dense
-    MoELayer path and the bench harness use it that way).
+    MoELayer path uses it that way).
 
     tokens: [T_local, H]; wg: [H, E] replicated gate; w1/b1/w2/b2: this
     device's expert slice ([E_local, H, F] etc).  Returns (out [T_local, H],

@@ -493,7 +493,6 @@ def convert_to_mixed_precision(model_file, params_file=None,
 from .paged import (  # noqa: F401,E402
     PagedKVCache, masked_multihead_attention, paged_decode_attention,
 )
-from .serving import PagedLlamaEngine  # noqa: F401,E402
 from .server import (  # noqa: F401,E402
     PagedExecutor, RequestHandle, RequestState, ServingEngine,
 )

@@ -147,9 +147,10 @@ def _select_impl(head_dim, page_size):
     """Resolve the decode-attention implementation.
 
     ``PT_PAGED_IMPL`` ∈ {auto, pallas, stock, dense} forces a path
-    (the A/B lever bench.py uses); ``auto`` prefers the self-authored
-    fused kernel when its shape gate passes, then the stock flash-style
-    kernel, then the dense jnp gather.  The gate is load-bearing: a
+    (how the CPU tests force the Pallas kernel in interpret mode);
+    ``auto`` prefers the self-authored fused kernel when its shape gate
+    passes, then the stock flash-style kernel, then the dense jnp
+    gather.  The gate is load-bearing: a
     shape Mosaic refuses raises at compile time INSIDE a serving step,
     which the scheduler turns into one FAILED request after another —
     so ``auto`` must never pick a kernel for a shape it cannot

@@ -129,8 +129,8 @@ class Router:
     tree), tie-broken by sequence-parallel fit (a long prompt prefers
     a mesh-backed replica that can stripe its prefill), then lowest
     queue depth, then most free pages, then lowest replica index —
-    fully deterministic.  ``random``: seeded uniform pick, the bench
-    A/B control arm.
+    fully deterministic.  ``random``: seeded uniform pick, the tests'
+    control arm.
     """
 
     POLICIES = ("affinity", "random")
@@ -609,7 +609,7 @@ class ServingCluster:
 
     ``cluster``: None = follow ``PT_CLUSTER`` (default off — the
     cluster collapses to one replica, bit-exact single-engine);
-    True/False force it (tests / bench A/B).  Engine keyword arguments
+    True/False force it (tests).  Engine keyword arguments
     (``max_seqs``, ``page_size``, ``prefix_cache``, ``aot``, ...)
     apply to every replica.
     """
@@ -1025,7 +1025,7 @@ class ServingCluster:
     # -- elastic scale ---------------------------------------------------
 
     def fail(self, name, reason="operator") -> Replica:
-        """Force one replica FAILED (ops hook and the bench's kill
+        """Force one replica FAILED (ops hook and the tests' kill
         switch): in-flight requests fail over immediately, the
         supervisor owns the restart/breaker follow-up."""
         rep = self.replica(name) if not isinstance(name, Replica) \
@@ -1356,7 +1356,7 @@ class ServingCluster:
         """Aggregate fleet stats plus each replica's full engine
         stats.  ``agg_tok_per_step`` is the fleet-level throughput on
         the LOGICAL clock — decode tokens per cluster tick — the
-        scaling metric the bench gates (wall time cannot scale when N
+        scaling count the tests read (wall time cannot scale when N
         simulated replicas share one CPU)."""
         per = {rep.name: rep.engine.stats() for rep in self.replicas}
         reqs: dict = {}

@@ -69,7 +69,7 @@ class ServingEngine:
                  wal=None, sp_mesh=None, sp_prefill=None,
                  sp_min_tokens=None, sp_axis=None):
         # quant: None = follow PT_QUANT (default none, bit-exact legacy
-        # path); "none"/"int8" force it (bench A/B).  int8 = per-channel
+        # path); "none"/"int8" force it (tests).  int8 = per-channel
         # int8 projection weights + per-page int8 KV pools.
         # sp_prefill: None = follow PT_SP_PREFILL (default off,
         # bit-exact legacy path); True/False force it.  On, prompts at
@@ -118,7 +118,7 @@ class ServingEngine:
             max_seqs=max_seqs, num_pages=self.executor.cache.num_pages,
             clock=clock)
         # prefix_cache: None = follow PT_PREFIX_CACHE (default off,
-        # bit-exact legacy path); True/False force it (bench A/B)
+        # bit-exact legacy path); True/False force it (tests)
         if prefix_cache is None:
             prefix_cache = _prefix_cache_enabled()
         self.prefix = None
@@ -132,7 +132,7 @@ class ServingEngine:
             self.executor.cache.reclaimer = self.prefix.evict
         # spec_decode: None = follow PT_SPEC_DECODE (default off,
         # bit-exact legacy path); "off"/"ngram" or False/True force it
-        # (bench A/B).  "ngram" drafts from each request's own
+        # (tests).  "ngram" drafts from each request's own
         # prompt+generated history — no second model.
         if spec_decode is None:
             spec_decode = spec_mode() == "ngram"
@@ -143,7 +143,7 @@ class ServingEngine:
             spec_decode = spec_decode == "ngram"
         self.spec = SpecDecode() if spec_decode else None
         # async_exec: None = follow PT_ASYNC_EXEC (default off,
-        # bit-exact legacy path); True/False force it (bench A/B).
+        # bit-exact legacy path); True/False force it (tests).
         # On = double-buffered steps: unrealized dispatch, next-step
         # planning overlapped behind the device, commit at the fence.
         if async_exec is None:
@@ -151,7 +151,7 @@ class ServingEngine:
         # wal: None = follow PT_WAL (default off, bit-exact legacy
         # path); False forces off (a cluster passes its own shared
         # journal or False so engines never double-resolve the env);
-        # a path/WriteAheadLog forces on (bench A/B, recovery).
+        # a path/WriteAheadLog forces on (tests, recovery).
         self.wal = resolve_wal(wal)
         self.dedup_hits = 0
         self.scheduler = Scheduler(
@@ -161,7 +161,7 @@ class ServingEngine:
             spec=self.spec, async_exec=async_exec, wal=self.wal)
         self._next_rid = 0
         # aot: None = follow PT_AOT (default off, bit-exact legacy
-        # path); "off"/"warm"/"strict" force it (bench A/B).  warm =
+        # path); "off"/"warm"/"strict" force it (tests).  warm =
         # AOT-compile every (program x shape-rung) pair at build via
         # the persistent compile cache; strict additionally seals the
         # programs so a post-warmup miss raises instead of compiling
@@ -320,21 +320,11 @@ class ServingEngine:
         return self.scheduler.requests.get(rid)
 
     def stats(self) -> dict:
-        out = self.metrics.stats()
-        from paddle_tpu import obs
-
-        if obs.handle() is not None:
-            # Pull-model roofline join over the scheduler's spans —
-            # stats() time only, never on the per-step hot path.  The
-            # scheduler's span names differ from the executor's program
-            # names where one span covers several programs.
-            out["roofline"] = obs.perf.attribute_from_tracer(
-                mapping={"req.prefill": "serve.prefill_chunk"})
-        return out
+        return self.metrics.stats()
 
     def _statusz(self) -> dict:
-        """/statusz provider: live pool/occupancy plus the roofline
-        rows and request-state counts from stats()."""
+        """/statusz provider: live pool/occupancy plus the
+        request-state counts from stats()."""
         cache = self.executor.cache
         s = self.scheduler
         return {

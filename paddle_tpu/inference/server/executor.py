@@ -12,10 +12,6 @@ NOTHING about queues, priorities or deadlines — those live in
   * ``decode(sids)``               one greedy token for an explicit
                                    batch of slots
   * ``decode_n(sids, n)``          n greedy tokens, feedback on device
-
-The legacy :class:`~paddle_tpu.inference.serving.PagedLlamaEngine`
-manual API is a thin shim over this class, so the hand-driven and the
-scheduled paths execute byte-identical programs.
 """
 from __future__ import annotations
 

@@ -1,8 +1,8 @@
 """SLO observability for the serving engine.
 
 Two clocks run side by side: the LOGICAL clock (scheduler iterations —
-what deterministic tests assert on) and the wall clock (what the bench
-reports as ms percentiles).  Per-request TTFT/TPOT/queue-wait are
+what deterministic tests assert on) and the wall clock (ms
+percentiles in ``stats()``).  Per-request TTFT/TPOT/queue-wait are
 recorded in both; engine-level occupancy and page utilization are
 step-averaged over the window where any request was in flight, so idle
 tails don't dilute them.

@@ -112,7 +112,7 @@ class Scheduler:
         # double-buffered execution state (PT_ASYNC_EXEC=on): the plan
         # built while the previous step was in flight, a commit a
         # fault interrupted mid-step, the replan audit counter, and
-        # the host-overlap accounting the statusz/bench surfaces read
+        # the host-overlap accounting the statusz surface reads
         self.async_mode = bool(async_exec)
         self._pending = None     # StepPlan parked for the next step
         self._inflight = None    # (StepPlan, pending) awaiting commit

@@ -77,7 +77,7 @@ def default_wal():
 
 def resolve_wal(wal):
     """None = follow PT_WAL; False forces off; a path string or a
-    WriteAheadLog force on (bench A/B and cluster-owned journals)."""
+    WriteAheadLog force on (tests and cluster-owned journals)."""
     if wal is None:
         return default_wal()
     if wal is False:
@@ -123,8 +123,7 @@ class WriteAheadLog:
         self.compactions = 0
         self.errors = 0
         # wall seconds spent inside append/fsync: the journal's true
-        # serving-path cost, measured within-run so host drift between
-        # bench legs can't fake (or hide) a tax
+        # serving-path cost, measured within the run
         self.write_s = 0.0
         self.last_fsync_at = 0      # `appended` watermark at last fsync
         self._since_fsync = 0

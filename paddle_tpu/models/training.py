@@ -597,7 +597,7 @@ class CompiledTrainStep:
             h.registry.histogram(
                 "train_step_wall_s",
                 "Host wall time of one train step").observe(wall)
-            obs.perf.on_program("train.step", wall)
+            obs.perf.sample_hbm("train.step", h)
         faults.fire("train.step", "after")
         return loss
 
@@ -668,7 +668,7 @@ class CompiledTrainStep:
             h.registry.histogram(
                 "train_step_wall_s",
                 "Host wall time of one train step").observe(wall)
-            obs.perf.on_program("train.guarded_step", wall)
+            obs.perf.sample_hbm("train.guarded_step", h)
         if not ok_b:
             # The gate kept the old state; the Adam step counter must
             # not advance either (found_inf semantics).

@@ -140,7 +140,7 @@ def configure(mode="on", clock=None, flight_capacity=512,
               trace_capacity=65536, annotate=True, events_path=None,
               events_max_bytes=262144, events_max_files=3,
               events_capacity=4096):
-    """Programmatic gate (tests / bench A/B): rebuild the bundle
+    """Programmatic gate (tests): rebuild the bundle
     regardless of ``PT_OBS``, and put the tracer on ``clock`` with an
     empty ring in either mode.  Returns the new handle (None for
     ``mode="off"``).  Producers that cached a handle at construction

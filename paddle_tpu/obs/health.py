@@ -355,7 +355,7 @@ def healthz_payload(handle, stale_after_s=None):
 def statusz_payload(handle):
     """The ``/statusz`` JSON: build info, heartbeats, the SLO table
     from every live :class:`SLOEngine`, and per-component provider
-    payloads (pool/occupancy/roofline from the serving engine, step
+    payloads (pool/occupancy from the serving engine, step
     phases from training)."""
     slos = []
     for eng in handle.slo_engines:

@@ -5,8 +5,8 @@ serving three read-only endpoints off the live obs bundle:
 
 - ``/metrics``  — Prometheus text exposition from the metric registry
 - ``/healthz``  — liveness + last-step staleness (200 ok / 503 stale)
-- ``/statusz``  — JSON: build info, SLO table, roofline rows,
-  pool/occupancy providers, heartbeats, event-log position
+- ``/statusz``  — JSON: build info, SLO table, pool/occupancy
+  providers, heartbeats, event-log position
 
 Gated by ``PT_OBS_HTTP=<port>`` (auto-started when the telemetry
 bundle is built with that set); tests start one explicitly on an

@@ -10,7 +10,7 @@ surfaces:
   ``_count`` triplets for histograms), deterministically ordered so
   seeded tests can assert on the exact string.
 - :meth:`MetricRegistry.snapshot` — the same data as a plain JSON-able
-  dict for programmatic consumers (``tools/obs_dump.py``, bench).
+  dict for programmatic consumers (``tools/obs_dump.py``).
 
 No background threads, no atomics beyond the GIL: producers are the
 single-threaded scheduler / train loop, and the registry is swapped

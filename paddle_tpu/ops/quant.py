@@ -226,7 +226,7 @@ def kv_dequant(pages, scales, dtype=None):
 
 def kv_pool_bytes_per_page(cache):
     """Bytes one page costs in ``cache`` (k+v pools plus any scale
-    rows) — the capacity-math denominator for the bench A/B."""
+    rows) — the capacity-math denominator."""
     per = (cache.k_pages.nbytes + cache.v_pages.nbytes)
     ks = getattr(cache, "k_scales", None)
     if ks is not None:

@@ -1,7 +1,7 @@
 """Bring-up contracts (ISSUE 21): the compile cache can be placed from
 outside and is never moved by building an engine; nothing hides the
-device (unknown peaks raise, the chip entry points fail without a
-chip); one process per chip (importing the package starts no backend).
+device (the chip entry point fails without a chip); one process per
+chip (importing the package starts no backend).
 All CPU and cheap — the chip side is ``python chip_smoke.py``."""
 import importlib.util
 import json
@@ -259,22 +259,6 @@ def test_walker_names_pallas_kernels_and_how_they_run():
     assert kernel in walker.name_inventory(jaxpr)
 
 
-def test_bench_counts_failed_legs_at_any_depth():
-    spec = importlib.util.spec_from_file_location(
-        "bench", os.path.join(REPO, "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    before = jax.config.jax_compilation_cache_dir
-    spec.loader.exec_module(bench)
-    # importing the bench moves no cache
-    assert jax.config.jax_compilation_cache_dir == before
-    doc = {"value": 1.0, "moe": {"error": "boom"},
-           "serving": {"value": 2.0, "spec": {"error": "x"},
-                       "quant": {"tok_s_ratio": 0.9}},
-           "large": {"skipped": "budget"}}
-    assert bench._failed_legs(doc) == ["moe", "serving.spec"]
-    assert bench._failed_legs({"value": 1.0}) == []
-
-
 def _run(args, **env):
     return subprocess.run(
         [sys.executable] + args, cwd=REPO, capture_output=True, text=True,
@@ -309,18 +293,9 @@ def test_chip_smoke_last_line_is_the_verdict_and_nothing_else():
             > src.rindex("print(json.dumps(report)"))   # printed last
 
 
-def test_bench_chip_path_without_a_chip_fails():
-    # default invocation, cold-start leg included: the parent must not
-    # print a result and must not exit 0
-    p = _run(["bench.py"])
-    assert p.returncode == 2, p.stderr[-400:]
-    assert "no TPU" in p.stderr
-    assert p.stdout.strip() == ""
-
-
 def test_importing_the_package_starts_no_backend():
-    # a parent that has touched jax holds the chip; launchers and bench
-    # parents rely on the import alone being free
+    # a parent that has touched jax holds the chip; launchers rely on
+    # the import alone being free
     p = _run(["-c", "import paddle_tpu, paddle_tpu.distributed.launch.main\n"
                     "from jax._src import xla_bridge\n"
                     "assert not xla_bridge._backends, xla_bridge._backends"])

@@ -148,7 +148,7 @@ def test_wal_fsync_batching(tmp_path):
     assert wal.statusz()["lag_records"] == 2
     wal.fsync()
     assert wal.fsyncs == 3 and wal.statusz()["lag_records"] == 0
-    # the journal accounts its own serving-path cost (bench gate input)
+    # the journal accounts its own serving-path cost
     assert 0 < wal.statusz()["write_s"] < 1.0
 
 

@@ -3,7 +3,8 @@
 Both expert matmuls for all experts in one Pallas kernel over
 sort-dispatched [E, C, H] buckets (MegaBlocks-style).  On CPU the
 kernel runs in interpreter mode — numerics, routing, and the custom
-VJP are validated here; speed is the TPU bench's job (bench.py `moe`).
+VJP are validated here; no cell of the benchmark runs it, so it has
+no chip number.
 """
 import numpy as np
 import pytest

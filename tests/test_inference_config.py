@@ -102,7 +102,7 @@ def test_convert_to_mixed_precision_weights_only(tmp_path):
         assert str(v.dtype) == "bfloat16", (k, v.dtype)
 
 
-def test_convert_to_mixed_precision_program_needs_builder(tmp_path):
+def test_convert_program_to_mixed_precision_needs_builder(tmp_path):
     import paddle_tpu.nn as nn
 
     layer, prefix = _save_model(tmp_path, with_program=True)

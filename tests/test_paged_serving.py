@@ -1,5 +1,5 @@
-"""Continuous-batching paged serving engine vs the dense KV-cache
-decoder: greedy tokens must match exactly, including staggered
+"""The paged executor's slot interface, driven by hand, vs the dense
+KV-cache decoder: greedy tokens must match exactly, including staggered
 admission and freeing (reference: the Predictor's
 block_multi_head_attention serving loop).
 """
@@ -7,7 +7,7 @@ import numpy as np
 import pytest
 
 import paddle_tpu as paddle
-from paddle_tpu.inference.serving import PagedLlamaEngine
+from paddle_tpu.inference.server import PagedExecutor
 from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
 from paddle_tpu.models.generation import LlamaDecoder
 
@@ -22,6 +22,23 @@ def model():
     return LlamaForCausalLM(cfg)
 
 
+def _executor(model, max_seqs=2, max_len=64):
+    return PagedExecutor(model, max_seqs=max_seqs, page_size=4,
+                         max_len=max_len)
+
+
+def _admit(ex, prompt):
+    """Prefill one prompt into a fresh slot; returns the slot id."""
+    sid = ex.alloc_slot()
+    ex.prefill(sid, np.asarray(prompt))
+    return sid
+
+
+def _step(ex):
+    """One greedy decode step over every active slot."""
+    return ex.decode(sorted(ex.last_token))
+
+
 def _dense_tokens(model, prompt, n):
     dec = LlamaDecoder(model)
     out = dec.generate(np.asarray(prompt)[None], max_new_tokens=n)
@@ -34,11 +51,11 @@ def test_paged_engine_matches_dense_decoder(model):
     n = 6
     want = _dense_tokens(model, prompt, n)
 
-    eng = PagedLlamaEngine(model, max_seqs=2, page_size=4, max_len=64)
-    sid = eng.add_request(prompt)
-    got = [eng._last_token[sid]]
+    ex = _executor(model)
+    sid = _admit(ex, prompt)
+    got = [ex.last_token[sid]]
     for _ in range(n - 1):
-        got.append(eng.step()[sid])
+        got.append(_step(ex)[sid])
     assert got == [int(t) for t in want], (got, want)
 
 
@@ -51,59 +68,59 @@ def test_paged_engine_continuous_batching(model):
     want1 = _dense_tokens(model, p1, 5)
     want2 = _dense_tokens(model, p2, 3)
 
-    eng = PagedLlamaEngine(model, max_seqs=2, page_size=4, max_len=64)
-    s1 = eng.add_request(p1)
-    got1 = [eng._last_token[s1]]
-    got1.append(eng.step()[s1])          # s1 decodes alone
-    s2 = eng.add_request(p2)             # s2 joins mid-flight
-    got2 = [eng._last_token[s2]]
+    ex = _executor(model)
+    s1 = _admit(ex, p1)
+    got1 = [ex.last_token[s1]]
+    got1.append(_step(ex)[s1])           # s1 decodes alone
+    s2 = _admit(ex, p2)                  # s2 joins mid-flight
+    got2 = [ex.last_token[s2]]
     for _ in range(2):
-        out = eng.step()                 # both decode in one batch
+        out = _step(ex)                  # both decode in one batch
         got1.append(out[s1])
         got2.append(out[s2])
-    out = eng.step()
+    out = _step(ex)
     got1.append(out[s1])
-    eng.finish(s1)                       # s1 leaves; s2 continues
+    ex.free_slot(s1)                     # s1 leaves; s2 continues
     assert got1 == [int(t) for t in want1], (got1, want1)
     assert got2 == [int(t) for t in want2], (got2, want2)
-    assert s1 not in eng._last_token
+    assert s1 not in ex.last_token
 
 
 def test_paged_engine_slot_reuse(model):
     """Freed pages/slots are reused by later requests."""
     rng = np.random.RandomState(2)
-    eng = PagedLlamaEngine(model, max_seqs=1, page_size=4, max_len=32)
+    ex = _executor(model, max_seqs=1, max_len=32)
     p = rng.randint(0, 256, (6,)).astype(np.int32)
-    s = eng.add_request(p)
-    eng.step()
-    eng.finish(s)
-    s2 = eng.add_request(p)              # slot comes back
+    s = _admit(ex, p)
+    _step(ex)
+    ex.free_slot(s)
+    s2 = _admit(ex, p)                   # slot comes back
     assert s2 == s
-    assert eng.step()[s2] is not None
+    assert _step(ex)[s2] is not None
 
 
 def test_decode_n_matches_per_step(model):
-    """r5: n greedy tokens in one dispatch == n sequential step()s
+    """n greedy tokens in one dispatch == n sequential decode steps
     (the device-resident feedback loop must be bit-identical)."""
     rng = np.random.RandomState(3)
     prompts = [rng.randint(0, 256, (5,)).astype(np.int32),
                rng.randint(0, 256, (9,)).astype(np.int32)]
     n = 6
 
-    a = PagedLlamaEngine(model, max_seqs=2, page_size=4, max_len=64)
-    sids_a = [a.add_request(p) for p in prompts]
+    a = _executor(model)
+    sids_a = [_admit(a, p) for p in prompts]
     per_step = {s: [] for s in sids_a}
     for _ in range(n):
-        out = a.step()
+        out = _step(a)
         for s, t in out.items():
             per_step[s].append(t)
 
-    b = PagedLlamaEngine(model, max_seqs=2, page_size=4, max_len=64)
-    sids_b = [b.add_request(p) for p in prompts]
-    fused = b.decode_n(n)
+    b = _executor(model)
+    sids_b = [_admit(b, p) for p in prompts]
+    fused = b.decode_n(sids_b, n)
     for sa, sb in zip(sids_a, sids_b):
         assert fused[sb] == per_step[sa], (fused[sb], per_step[sa])
-    # engine state advanced consistently: another plain step agrees
-    nxt_a, nxt_b = a.step(), b.step()
+    # executor state advanced consistently: another plain step agrees
+    nxt_a, nxt_b = _step(a), _step(b)
     for sa, sb in zip(sids_a, sids_b):
         assert nxt_a[sa] == nxt_b[sb]

@@ -1,15 +1,14 @@
 """Performance introspection plane: analytical jaxpr cost model with
-exact FLOP/byte counts, bench-vs-cost-model FLOP agreement, roofline
-joins and peak tables, StepTimer phase breakdown on the logical clock,
-Perfetto counter tracks, the bench regression gate, and the PT_OBS=off
-bit-parity contract with the perf layer wired.
+exact FLOP/byte counts, model-vs-cost-model FLOP agreement, StepTimer
+phase breakdown on the logical clock, the throttled HBM watermark
+sample, Perfetto counter tracks, and the PT_OBS=off bit-parity contract
+with the perf layer wired.
 
 Same conventions as test_obs.py: everything runs on
 :class:`obs.LogicalClock`, and producers cache ``obs.handle()`` at
 construction so every on-path test configures the plane BEFORE building
 the engine / train step under test.
 """
-import importlib.util
 import json
 import os
 import tempfile
@@ -32,8 +31,6 @@ from paddle_tpu.obs import perf
 from paddle_tpu.obs.trace import LogicalClock
 from paddle_tpu.testing import faults
 from paddle_tpu.testing.load import LoadSpec, generate_load, run_load
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 f32 = jnp.float32
 
@@ -164,15 +161,15 @@ def test_report_asdict_carries_derived_fields():
     assert "CostReport" in str(rep)
 
 
-# -- bench-vs-cost-model agreement --------------------------------------------
+# -- model-vs-cost-model agreement --------------------------------------------
 
 def test_transformer_flops_closed_form():
     assert transformer_flops_per_token(10, 2, 4, 8) == 6 * 10 + 12 * 2 * 4 * 8
 
 
 def test_llama_flops_per_token_matches_cost_model_home(model):
-    # bench.py's MFU legs use model.flops_per_token; it must agree with
-    # the single formula home in analysis.cost to the digit.
+    # model.flops_per_token must agree with the single formula home in
+    # analysis.cost to the digit.
     cfg = model.config
     n = model.num_params()
     for seq in (16, 512):
@@ -207,33 +204,6 @@ def test_program_cost_unknown_is_none():
     assert perf.program_cost("no.such.program") is None
 
 
-# -- roofline join + peak tables ----------------------------------------------
-
-def test_roofline_join_math_and_classification():
-    compute = CostReport(flops=1000, matmul_flops=1000, bytes_in=10)
-    rl = perf.roofline(compute, 0.5, device_kind="cpu")
-    assert rl["mfu"] == 1000 / 0.5 / perf.peak_flops_per_chip("cpu")
-    assert rl["hbm_gbps"] == 10 / 0.5 / 1e9
-    assert rl["bound"] == "compute"          # 100 FLOP/B >= ridge 20
-    bw = CostReport(flops=10, elementwise_flops=10, bytes_in=10)
-    assert perf.roofline(bw, 0.5, device_kind="cpu")["bound"] == "bandwidth"
-    assert perf.roofline(None, 0.5) is None
-    assert perf.roofline(compute, 0.0) is None
-    assert perf.roofline(compute, None) is None
-
-
-def test_peak_tables_substring_lookup():
-    assert perf.peak_flops_per_chip("TPU v5p") == 459e12
-    assert perf.peak_flops_per_chip("TPU v5 lite") == 197e12
-    assert perf.peak_flops_per_chip("TPU v4") == 275e12
-    # an unknown device is an error, never the table's last row
-    with pytest.raises(LookupError, match="no-such-device"):
-        perf.peak_flops_per_chip("no-such-device")
-    with pytest.raises(LookupError, match="no-such-device"):
-        perf.peak_hbm_bytes_s("no-such-device")
-    assert perf.ridge_intensity("cpu") == 20.0
-
-
 # -- StepTimer on the logical clock -------------------------------------------
 
 def test_steptimer_phase_breakdown_exact():
@@ -261,9 +231,9 @@ def test_steptimer_is_noop_when_obs_off():
     assert t.end_step() == {}
 
 
-# -- on_program: producer publishes roofline gauges + counters ---------------
+# -- HBM watermarks: the one device reading the plane takes ------------------
 
-def test_train_step_publishes_roofline_gauges(model):
+def test_train_step_samples_hbm_watermarks_and_no_rate(model):
     h = _on()
     step = CompiledTrainStep(model, lr=1e-3)
     ids = np.random.RandomState(0).randint(
@@ -271,28 +241,31 @@ def test_train_step_publishes_roofline_gauges(model):
     for _ in range(2):
         step.step(ids, ids)
     prom = h.registry.prometheus_text()
-    assert 'program_mfu{program="train.step"}' in prom
-    assert 'program_hbm_gbps{program="train.step"}' in prom
-    assert 'program_flops{program="train.step"}' in prom
-    assert 'roofline_bound{bound="compute",program="train.step"}' in prom
-    assert 'roofline_bound{bound="bandwidth",program="train.step"}' in prom
-    assert "hbm_peak_bytes" in prom
-    assert any(s.ph == "C" and s.name.startswith("perf.")
-               for s in h.tracer.spans)
+    for fam in ("hbm_peak_bytes", "hbm_bytes_in_use", "hbm_bytes_limit"):
+        assert fam in prom
+    # no rate or share from a dispatch's host wall time
+    assert "program_mfu" not in prom and "roofline_bound" not in prom
+    tracks = [s for s in h.tracer.spans
+              if s.ph == "C" and s.name == "perf.hbm_bytes"]
+    assert len(tracks) == 1                 # step 1 sampled, step 2 not
+    # the throttle is per program: every HBM_SAMPLE_EVERY-th call
+    took = [perf.sample_hbm("demo.step") is not None
+            for _ in range(perf.HBM_SAMPLE_EVERY + 1)]
+    assert took == [True] + [False] * (perf.HBM_SAMPLE_EVERY - 1) + [True]
 
 
 # -- chrome trace: counter tracks + thread metadata ---------------------------
 
 def test_chrome_export_counter_tracks_and_thread_names():
     h = _on()
-    h.tracer.counter("perf.mfu", cat="perf", demo=0.5)
+    h.tracer.counter("perf.step_phases", cat="perf", demo=0.5)
     with tempfile.TemporaryDirectory() as d:
         path = os.path.join(d, "trace.json")
         h.tracer.export_chrome(path)
         doc = json.loads(open(path).read())
     evs = doc["traceEvents"]
     counters = [e for e in evs if e.get("ph") == "C"]
-    assert counters and counters[0]["name"] == "perf.mfu"
+    assert counters and counters[0]["name"] == "perf.step_phases"
     assert counters[0]["args"] == {"demo": 0.5}
     threads = {e["args"]["name"] for e in evs
                if e.get("ph") == "M" and e["name"] == "thread_name"}
@@ -320,123 +293,9 @@ def _seeded_load(model):
 
 def test_off_path_is_bit_identical_with_perf_wired(model):
     toks_off, stats_off, raw_off = _seeded_load(model)
-    assert "roofline" not in raw_off        # off path: no perf join
     _on()
     toks_on, stats_on, raw_on = _seeded_load(model)
     assert toks_on == toks_off
     assert stats_on == stats_off
-    rl = raw_on.get("roofline", {})
-    assert "serve.decode" in rl and rl["serve.decode"]["mfu"] > 0
-    assert rl["serve.decode"]["bound"] in ("compute", "bandwidth")
-
-
-# -- bench regression gate (tools/check_perf.py) ------------------------------
-
-def _check_perf():
-    spec = importlib.util.spec_from_file_location(
-        "check_perf", os.path.join(REPO, "tools", "check_perf.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def _round(tmp_path, n, payload, wrapper=False):
-    doc = {"n": n, "cmd": f"python bench.py --round {n}", "rc": 0,
-           "tail": "", "parsed": payload} if wrapper else payload
-    p = tmp_path / f"BENCH_r{n:02d}.json"
-    p.write_text(json.dumps(doc))
-    return p
-
-
-GOOD = {"value": 100.0, "mfu": 0.4, "serving": {"value": 50.0},
-        "obs_overhead": {"on_off_ratio": 1.01}}
-
-
-def test_check_perf_flags_regression(tmp_path):
-    cp = _check_perf()
-    _round(tmp_path, 1, GOOD)
-    _round(tmp_path, 2, {**GOOD, "value": 60.0})   # -40% > 25% tol
-    assert cp.main(["--dir", str(tmp_path)]) == 1
-
-
-def test_check_perf_flags_overhead_ratio_growth(tmp_path):
-    cp = _check_perf()
-    _round(tmp_path, 1, GOOD)
-    bad = dict(GOOD)
-    bad["obs_overhead"] = {"on_off_ratio": 1.10}   # lower-is-better
-    _round(tmp_path, 2, bad)
-    assert cp.main(["--dir", str(tmp_path)]) == 1
-
-
-def test_check_perf_passes_within_tolerance(tmp_path):
-    cp = _check_perf()
-    _round(tmp_path, 1, GOOD)
-    _round(tmp_path, 2, {**GOOD, "value": 95.0}, wrapper=True)
-    assert cp.main(["--dir", str(tmp_path)]) == 0
-
-
-def test_check_perf_skips_unusable_rounds(tmp_path):
-    cp = _check_perf()
-    _round(tmp_path, 1, GOOD)
-    _round(tmp_path, 2, None, wrapper=True)        # crashed round
-    _round(tmp_path, 3, {**GOOD, "value": 30.0})   # regressed vs r01
-    assert cp.main(["--dir", str(tmp_path)]) == 1
-
-
-def test_check_perf_passes_with_nothing_to_compare(tmp_path):
-    cp = _check_perf()
-    assert cp.main(["--dir", str(tmp_path)]) == 0
-    _round(tmp_path, 1, GOOD)
-    assert cp.main(["--dir", str(tmp_path)]) == 0
-
-
-def test_check_perf_compares_same_platform_only(tmp_path):
-    cp = _check_perf()
-    _round(tmp_path, 1, {**GOOD, "platform": "tpu"})
-    # a CPU round 10x slower than the TPU one is NOT a regression...
-    _round(tmp_path, 2, {**GOOD, "platform": "cpu", "value": 10.0})
-    assert cp.main(["--dir", str(tmp_path)]) == 0
-    # ...but a slower round on the SAME platform is
-    _round(tmp_path, 3, {**GOOD, "platform": "cpu", "value": 5.0})
-    assert cp.main(["--dir", str(tmp_path)]) == 1
-    # pre-stamp artifacts (no platform key) pair with each other
-    _round(tmp_path, 4, GOOD)
-    assert cp.main(["--dir", str(tmp_path)]) == 0   # no unnamed prior
-    _round(tmp_path, 5, {**GOOD, "value": 30.0})
-    assert cp.main(["--dir", str(tmp_path)]) == 1
-
-
-def test_check_perf_explicit_pair(tmp_path):
-    cp = _check_perf()
-    old = _round(tmp_path, 1, GOOD)
-    new = _round(tmp_path, 2, {**GOOD, "serving": {"value": 10.0}})
-    assert cp.main(["--old", str(old), "--new", str(new)]) == 1
-    assert cp.main(["--old", str(old), "--new", str(old)]) == 0
-
-
-# -- bench round recorder (bench.py --round N) --------------------------------
-
-def _bench():
-    spec = importlib.util.spec_from_file_location(
-        "bench", os.path.join(REPO, "bench.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def test_bench_write_round_artifact_and_perf_md(tmp_path):
-    b = _bench()
-    parsed = {"value": 1.5, "serving": {"value": 2.0},
-              "moe": {"skipped": "needs 8 devices"}}
-    path = b._write_round(7, parsed, root=str(tmp_path))
-    doc = json.loads(open(path).read())
-    assert doc == {"n": 7, "cmd": "python bench.py --round 7", "rc": 0,
-                   "tail": "", "parsed": parsed}
-    md = (tmp_path / "PERF.md").read_text()
-    assert "## Round-7 bench artifact" in md
-    assert "serving.value" in md and "BENCH_r07.json" in md
-    # a crashed round records parsed: null and a FAILED section
-    b._write_round(8, None, rc=1, tail="boom", root=str(tmp_path))
-    doc8 = json.loads((tmp_path / "BENCH_r08.json").read_text())
-    assert doc8["rc"] == 1 and doc8["parsed"] is None
-    assert "FAILED" in (tmp_path / "PERF.md").read_text()
+    # stats() is the same dict on both paths: no host-clock roofline
+    assert "roofline" not in raw_off and "roofline" not in raw_on

@@ -361,7 +361,7 @@ def test_quant_fault_confined_to_one_request(model, point, phase):
 
 
 def test_pool_bytes_per_page_ratio(model):
-    """The bench's capacity multiplier comes from this layout math:
+    """The capacity multiplier comes from this layout math:
     int8 pages + f32 per-page scales must stay under 5/9 of the f32
     pool bytes (>= 1.8x pages at a fixed byte budget)."""
     bf = ServingEngine(model, quant="none", **ENGINE_KW)
