@@ -20,12 +20,13 @@ mirroring what the alpa/levanter-style JAX stacks do:
    dropped and recompiled, never a crash.
 3. **A formal shape-bucket ladder** — :class:`BucketLadder` (powers of
    two by default) makes the runtime shape set finite: chunked prefill
-   decomposes a prompt into descending ladder rungs, the past-KV cover
-   pads to a bucketed page count (garbage masked by ``past_len``, so
-   numerics are exact), and the decode-family batch sizes enumerate
-   ``1..max_seqs``.  ``PagedExecutor.aot_warmup`` pre-compiles every
-   (program x rung) pair at engine build, and ``CheckpointManager``
-   restore invokes the same warmup so rollback resumes in seconds.
+   decomposes a prompt into descending ladder rungs, the past's page
+   ids pad to a bucketed page count (padded columns masked by
+   ``past_len``, so numerics are exact), and the decode-family batch
+   sizes enumerate ``1..max_seqs``.  ``PagedExecutor.aot_warmup``
+   pre-compiles every (program x rung) pair at engine build, and
+   ``CheckpointManager`` restore invokes the same warmup so rollback
+   resumes in seconds.
 
 Gating: ``PT_AOT={off,warm,strict}``.  ``off`` (default) is bit-exact
 r17 — no ladder, no table, no signature hashing on the dispatch path.

@@ -315,6 +315,24 @@ def _put_token(flat, pool_shape, layer, pids, offs, x):
     return flat.at[rows].set(new, mode="drop")
 
 
+def _past_of(pool, layer, pids, dtype):
+    """Pages ``pids`` (int32 ``[n]``) of layer ``layer`` laid dense,
+    ``[KV, n * page_size, D]``: the past a prefill chunk attends to,
+    gathered inside its program by row of the flat pool (slicing the
+    layer out first copies that layer's pool on the TPU).  An int8 pool
+    is ``(pages, scales)``: the pages' scales are gathered the same way
+    and the window dequantized to ``dtype``; a plain pool keeps its own
+    dtype.  Positions past the sequence's length are garbage and must be
+    masked by the consumer."""
+    pages, scales = pool if isinstance(pool, tuple) else (pool, None)
+    KV, D = pages.shape[1], pages.shape[4]
+    rows = _rows(pages.shape, layer, pids).T              # [KV, n]
+    got = _flat(pages).at[rows].get(mode="clip")          # [KV, n, ps, D]
+    if scales is not None:
+        got = _quant.kv_dequant(
+            got, scales.reshape(-1).at[rows].get(mode="clip"), dtype)
+    return got.reshape(KV, -1, D)
+
 
 def _write_span(kp, vp, k, v, pids, offs):
     """Write a token span's K and V, ``[L, KV, T, D]``, into both pools
@@ -725,11 +743,12 @@ class PagedKVCache:
         _faults.fire("sp.gather", "after")
         return pages
 
-    def gather_dense(self, seq: int, length=None):
-        """Gather a sequence's pages into dense [L, KV, P, D] arrays
-        (P = page-multiple cover of ``length``) — the past-KV operand of
-        the chunked-prefill forward.  Positions >= length are garbage
-        and must be masked by the consumer."""
+    def past_pages(self, seq: int, length=None):
+        """The ids of the pages that cover a sequence's first ``length``
+        tokens (all it holds by default), int32 ``[n]`` on the host:
+        what a program needs to read that past out of the pools.  A
+        copy, so that a program it was handed to never sees the table's
+        later changes."""
         L = int(self.lengths[seq]) if length is None else int(length)
         n = -(-L // self.page_size)
         row = self.page_table[seq, :n]
@@ -739,9 +758,21 @@ class PagedKVCache:
             # KV.  That is always a caller bug: fail loudly instead.
             bad = int(np.argmax(row < 0))
             raise RuntimeError(
-                f"gather_dense: sequence {seq} page slot {bad} is "
+                f"past_pages: sequence {seq} page slot {bad} is "
                 f"unset inside the requested length {L} "
                 f"({n} pages) — refusing to read garbage from page 0")
+        return row.copy()
+
+    def gather_dense(self, seq: int, length=None):
+        """Gather a sequence's pages into dense [L, KV, P, D] arrays
+        (P = page-multiple cover of ``length``), eagerly and on the
+        pool's device: the past-KV operand of the sequence-parallel
+        chunk program and of the hybrid executor's, and what the cluster
+        hand-off ships (the Llama-shaped chunk program reads its past
+        inside the program, :func:`_past_of`).  Positions >= length are
+        garbage and must be masked by the consumer."""
+        row = self.past_pages(seq, length)
+        n = len(row)
         pids = jnp.asarray(row)
         k = self.k_pages[:, :, pids]          # [L, KV, n, ps, D]
         v = self.v_pages[:, :, pids]

@@ -28,8 +28,9 @@ from ... import obs
 from ...analysis import CountedJit, ProgramContract, register_program
 from ...ops import quant as _quant
 from ...ops.nn_ops import _rms_norm_plain, _rope_plain
+from ...testing import faults as _faults
 from ..paged import (
-    PagedKVCache, _flat, _put_token, paged_decode_attention,
+    PagedKVCache, _flat, _past_of, _put_token, paged_decode_attention,
 )
 
 
@@ -216,16 +217,15 @@ class PagedExecutor:
         # fn doubles as the lint registration target below
         self._jit_prefill = CountedJit(self._prefill_fwd,
                                        name="serve.prefill")
-        # the chunk program never sees the pools: it donates the dense
-        # past-KV gather (a fresh copy the caller never reuses — the
-        # donation-miss lint check flagged it) and hands its K/V to the
-        # cache's own donated writer (serve.kv_write).  Decode and
-        # verify take the pools themselves donated, and the call sites
-        # replace them with the outputs at once, so every page write is
-        # in place instead of a copy of GBs of KV
+        # the chunk program only READS the pools (its past, gathered
+        # in-graph by page id), so it donates nothing: it hands its K/V
+        # to the cache's own writer (serve.kv_write), which takes the
+        # pools donated next, and a donated array has one owner.  Decode
+        # and verify take the pools themselves donated, and the call
+        # sites replace them with the outputs at once, so every page
+        # write is in place instead of a copy of GBs of KV
         self._jit_chunk = CountedJit(self._chunk_fwd,
-                                     name="serve.prefill_chunk",
-                                     donate_argnums=(4, 5))
+                                     name="serve.prefill_chunk")
         self._jit_decode = CountedJit(self._decode_fwd,
                                       name="serve.decode",
                                       donate_argnums=(4, 5))
@@ -382,8 +382,9 @@ class PagedExecutor:
     def _register_contracts(self):
         """Register the serving programs' graph contracts at
         representative shapes (lint traces ShapeDtypeStructs only — no
-        device work).  Chunk shapes pick past cover == chunk length so
-        the donation aliasing opportunity is visible to the checker.
+        device work).  The chunk program is linted with a past of one
+        page; serve.prefill_sp's dense past picks cover == chunk length
+        so the donation aliasing opportunity is visible to the checker.
 
         Quantized builds register under ``.int8``-suffixed names: the
         registry is replace-by-name and lint_graph builds BOTH engine
@@ -429,8 +430,8 @@ class PagedExecutor:
             args=(layers, tops, i32(1, 2 * ps)), **common))
         register_program(ProgramContract(
             name="serve.prefill_chunk" + sfx, fn=self._chunk_fwd,
-            args=(layers, tops, i32(1, ps), i32(), past, past, i32()),
-            donate_argnums=self._jit_chunk.donate_argnums, **common))
+            args=(layers, tops, i32(1, ps), i32(), kp, kp, i32(1), i32()),
+            **common))
         if self._jit_chunk_sp is not None:
             # the ONLY serving program allowed collectives, and its
             # inventory is exact: the per-layer ring-gather costs
@@ -492,10 +493,11 @@ class PagedExecutor:
 
         * ``serve.prefill_chunk`` — chunk length runs over the pow2
           ``ladder`` rungs (the scheduler floor-quantizes onto them and
-          any prompt decomposes into descending rungs), past-KV cover
-          over the feasible page buckets (a chunk of C at rung r can
-          only ever see ``<= ceil((max_len - C) / page_size)`` past
-          pages).  Whole-prompt prefill is routed through this program
+          any prompt decomposes into descending rungs), the past's
+          page ids over the feasible page buckets (a chunk of C at rung
+          r can only ever see ``<= ceil((max_len - C) / page_size)``
+          past pages; the pools keep their one shape).  Whole-prompt
+          prefill is routed through this program
           (``serve.prefill`` has an unbounded [1, S] shape — the reason
           chunking exists).
         * ``serve.decode`` / ``serve.decode_async`` / ``serve.verify``
@@ -517,9 +519,9 @@ class PagedExecutor:
         L = cfg.num_hidden_layers
         KV, D = cfg.num_key_value_heads, cfg.head_dim
         ps, pps = kvc.page_size, kvc.max_pages_per_seq
-        # past-KV gathers come back dense in the COMPUTE dtype (int8
-        # pools dequantize inside gather_dense), so the chunk program's
-        # past SDS must not mirror the pool storage dtype
+        # serve.prefill_sp's past arrives dense in the COMPUTE dtype
+        # (int8 pools dequantize inside gather_dense), so its past SDS
+        # must not mirror the pool storage dtype
         past_dt = kvc.compute_dtype
 
         def sds(tree):
@@ -546,10 +548,9 @@ class PagedExecutor:
             pmax = aot.bucket_pages(-(-(self.max_len - C) // ps),
                                     buckets)
             for b in (x for x in buckets if x <= pmax):
-                past = jax.ShapeDtypeStruct((L, KV, b * ps, D), past_dt)
                 plan.append((self._jit_chunk,
-                             (layers, tops, i32(1, C), i32(), past,
-                              past, i32()), {}))
+                             (layers, tops, i32(1, C), i32(), kp, kp,
+                              i32(b), i32()), {}))
         if self.sp_degree > 1:
             # sequence-parallel rungs: a chunk only stripes when its
             # length splits evenly across the ranks, so warmup covers
@@ -690,13 +691,18 @@ class PagedExecutor:
         x = _rms_norm_plain(x, tops["norm_w"], epsilon=cfg.rms_norm_eps)
         return self._head(x[:, -1], tops)[0], ks[:, 0], vs[:, 0]
 
-    def _chunk_fwd(self, layers, tops, ids, pos0, past_k, past_v,
+    def _chunk_fwd(self, layers, tops, ids, pos0, k_pages, v_pages, pids,
                    past_len):
         """Chunked-prefill forward: ids [1, C] at positions
-        ``pos0..pos0+C-1``; past_k/past_v [L, KV, P, D] are the
-        sequence's already-written KV gathered dense (P = page-multiple
-        cover, positions >= past_len masked).  Returns (last-position
-        logits [V], chunk k [L,KV,C,D], chunk v [L,KV,C,D]).
+        ``pos0..pos0+C-1``.  The sequence's already-written KV is read
+        from the pools (the form :meth:`PagedKVCache.pools` gives, not
+        donated: this program writes no page) inside the layer scan:
+        ``pids`` int32 [n] names the pages that cover it, each layer
+        gathers its own ``[KV, P, D]`` by row of the flat pool
+        (:func:`~..paged._past_of`; P = n * page_size, positions >=
+        past_len masked, so ``pids`` may be padded with any valid page
+        id).  Returns (last-position logits [V], chunk k [L,KV,C,D],
+        chunk v [L,KV,C,D]).
 
         This is what lets the scheduler interleave one long prompt's
         prefill with in-flight decodes: each scheduler iteration runs
@@ -706,7 +712,8 @@ class PagedExecutor:
         nh, nkv, d = (cfg.num_attention_heads, cfg.num_key_value_heads,
                       cfg.head_dim)
         B, C = ids.shape
-        P = past_k.shape[2]
+        P = pids.shape[0] * self.cache.page_size
+        past_dt = self.cache.compute_dtype
         x = tops["embed"][ids]
         pos = pos0 + jnp.broadcast_to(jnp.arange(C)[None], (B, C))
         scale = 1.0 / np.sqrt(d)
@@ -715,8 +722,10 @@ class PagedExecutor:
             [jnp.broadcast_to((jnp.arange(P) < past_len)[None], (C, P)),
              jnp.tril(jnp.ones((C, C), bool))], axis=1)  # [C, P+C]
 
-        def block(x, lp_kv):
-            lp, pk, pv = lp_kv
+        def block(x, lp_layer):
+            lp, layer = lp_layer
+            pk = _past_of(k_pages, layer, pids, past_dt)
+            pv = _past_of(v_pages, layer, pids, past_dt)
             h = _rms_norm_plain(x, lp["input_layernorm.weight"],
                                 epsilon=cfg.rms_norm_eps)
             q = _mm(h, lp["self_attn.q_proj.weight"]) \
@@ -752,7 +761,9 @@ class PagedExecutor:
                         lp["mlp.down_proj.weight"])
             return x, (jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2))
 
-        x, (ks, vs) = jax.lax.scan(block, x, (layers, past_k, past_v))
+        x, (ks, vs) = jax.lax.scan(
+            block, x,
+            (layers, jnp.arange(cfg.num_hidden_layers, dtype=jnp.int32)))
         x = _rms_norm_plain(x, tops["norm_w"], epsilon=cfg.rms_norm_eps)
         return self._head(x[:, -1], tops)[0], ks[:, 0], vs[:, 0]
 
@@ -1141,39 +1152,36 @@ class PagedExecutor:
     def prefill_chunk(self, sid: int, chunk_ids, start: int,
                       final: bool) -> int | None:
         """One prefill chunk at position ``start``; attends the slot's
-        already-written pages.  When ``final``, records and returns the
+        already-written pages, which the program reads from the pools
+        itself: the host hands it their ids, dispatches it, and then
+        the page writer.  When ``final``, records and returns the
         prompt's first greedy token; else returns None."""
-        with obs.span("kv.gather", cat="serve", past_tokens=start):
-            past_k, past_v = self.cache.gather_dense(sid, start)
-        if self.aot_ladder is not None:
-            # bucket the past cover so its shape comes from the finite
-            # warmup set: pad to the next page bucket with zeros — the
-            # in-graph `arange(P) < past_len` mask drops the padding's
-            # contribution entirely, so numerics are exact
-            from ...core.aot import bucket_pages
-
-            ps = self.cache.page_size
-            pages = past_k.shape[2] // ps
-            b = bucket_pages(pages, self._aot_page_buckets)
-            if b > pages:
-                pad = ((0, 0), (0, 0), (0, (b - pages) * ps), (0, 0))
-                past_k = jnp.pad(past_k, pad)
-                past_v = jnp.pad(past_v, pad)
+        cache = self.cache
         with obs.span("exec.prep", cat="serve", tokens=len(chunk_ids)):
-            ids = jnp.asarray(np.asarray(chunk_ids)[None], jnp.int32)
+            ids = np.asarray(chunk_ids, np.int32)[None]
+            pids = cache.past_pages(sid, start)
+            if self.aot_ladder is not None:
+                # bucket the past's page cover so its shape comes from
+                # the finite warmup set: pad with a valid page id — the
+                # in-graph `arange(P) < past_len` mask drops the padded
+                # columns entirely, so numerics are exact
+                from ...core.aot import bucket_pages
+
+                b = bucket_pages(len(pids), self._aot_page_buckets)
+                pids = np.pad(pids, (0, max(b - len(pids), 0)))
+            kp, vp = cache.pools()
         self.prefill_events.append((sid, int(ids.shape[1])))
-        # past_k/past_v are donated: gather_dense returns fresh dense
-        # copies nothing else references, and when the past cover
-        # equals the chunk length XLA writes the chunk KV in place.
-        # Shapes where the alias is impossible (cover != chunk) would
-        # warn once per compile — expected, so silenced here.
-        with warnings.catch_warnings():
-            warnings.filterwarnings(
-                "ignore", message="Some donated buffers were not usable")
-            logits, k, v = self._jit_chunk(
-                self.layers, self.tops, ids, jnp.int32(start), past_k,
-                past_v, jnp.int32(start))
-        self.cache.write_at(sid, k, v, start)
+        # the past of an int8 pool is dequantized inside the program:
+        # the chaos tests' bracket around that read is the dispatch
+        int8 = self.quant == "int8"
+        if int8:
+            _faults.fire("quant.dequant", "before")
+        logits, k, v = self._jit_chunk(
+            self.layers, self.tops, ids, np.int32(start), kp, vp, pids,
+            np.int32(start))
+        if int8:
+            _faults.fire("quant.dequant", "after")
+        cache.write_at(sid, k, v, start)
         if not final:
             return None
         if sid in self._sp_written:

@@ -128,10 +128,11 @@ REGISTERED = {
     "quant.kv_write": "one host-side quantized KV page write "
                       "(write_at/append; before=pool untouched, after="
                       "pages+scales updated, length not yet bumped)",
-    "quant.dequant": "one dense dequantizing gather of a sequence's "
-                     "int8 pages (gather_dense; before=nothing read, "
-                     "after=dense f32/bf16 copy built — the pool is "
-                     "never mutated by a read)",
+    "quant.dequant": "one dequantizing read of a sequence's int8 "
+                     "pages (serve.prefill_chunk's dispatch, which "
+                     "gathers the past in-graph, and gather_dense; "
+                     "before=nothing read, after=dense f32/bf16 copy "
+                     "built — the pool is never mutated by a read)",
     "route.pick": "one cluster router placement decision (before=no "
                   "replica chosen, nothing submitted; after=decision "
                   "made, request not yet handed to the engine — a "

@@ -4,12 +4,14 @@ costs no chip time to see.  Every test that describes the topology lives in
 this one file (one process at a time may load the TPU's library), and the
 description is made inside a fixture, never while a module is imported.
 
-Pinned so far, each with the pools aliased from input to output and no copy
-of a pool or of a layer of one: mistral7b-serve's page writer
+Pinned so far, each writer with the pools aliased from input to output and
+no copy of a pool or of a layer of one: mistral7b-serve's page writer
 (``serve.kv_write``) and its whole decode step (``serve.decode``, the pools
 carried through the layer scan into the Pallas kernel); granite4h-micro's
 state kernel inside a scan, its state writer, and the page patch and window
-gather on its folded KV pool.  Each has a control beside it or in it that
+gather on its folded KV pool; and, reading the pools without writing them,
+mistral7b-serve's prefill chunk (``serve.prefill_chunk``, its past gathered
+by row inside the layer scan).  Each has a control beside it or in it that
 shows the compiler's copies when the program is written the other way, so a
 serving program can be checked for pool copies before any chip time."""
 import os
@@ -107,19 +109,17 @@ def compiled_kernel(monkeypatch):
     monkeypatch.setattr(paged_decode, "_interpret", lambda: False)
 
 
-@pytest.mark.parametrize("batch", [32, 17])
-def test_the_decode_step_moves_no_pool(sds, compiled_kernel, batch):
-    """serve.decode at the cell's size — Mistral-7B widths, 8 layers, a
-    table of 128 pages a sequence: both pools aliased to the outputs, the
-    kernel in the program, and neither a pool nor a layer of one copied,
-    re-laid or stacked; the temporaries stay under one layer's 134 MB."""
+def mistral_executor(sds):
+    """mistral7b-serve's executor without its arrays (Mistral-7B widths, 8
+    layers), and the shapes of its stacked layers and top weights."""
     from paddle_tpu.inference.server.executor import PagedExecutor
 
     ex = object.__new__(PagedExecutor)
     ex.config = types.SimpleNamespace(
         num_attention_heads=32, num_key_value_heads=8, head_dim=128,
-        rms_norm_eps=1e-5)
-    ex.cache = types.SimpleNamespace(page_size=POOL[3])
+        num_hidden_layers=POOL[0], rms_norm_eps=1e-5)
+    ex.cache = types.SimpleNamespace(page_size=POOL[3],
+                                     compute_dtype=jnp.bfloat16)
     ex._tied = False
     L, H, F, V = POOL[0], 4096, 14336, 32768
     layers = {"input_layernorm.weight": sds((L, H)),
@@ -134,6 +134,16 @@ def test_the_decode_step_moves_no_pool(sds, compiled_kernel, batch):
     tops = {"embed": sds((V, H)), "norm_w": sds((H,)),
             "head_w": sds((H, V)), "cos": sds((V, 128), jnp.float32),
             "sin": sds((V, 128), jnp.float32)}
+    return ex, layers, tops
+
+
+@pytest.mark.parametrize("batch", [32, 17])
+def test_the_decode_step_moves_no_pool(sds, compiled_kernel, batch):
+    """serve.decode at the cell's size, a table of 128 pages a sequence:
+    both pools aliased to the outputs, the kernel in the program, and
+    neither a pool nor a layer of one copied, re-laid or stacked; the
+    temporaries stay under one layer's 134 MB."""
+    ex, layers, tops = mistral_executor(sds)
     i32 = jnp.int32
     exe = jax.jit(ex._decode_fwd, donate_argnums=(4, 5)).lower(
         layers, tops, sds((batch,), i32), sds((batch,), i32), sds(POOL),
@@ -258,3 +268,52 @@ def test_a_token_into_the_folded_kv_pool_moves_no_pool(sds):
     assert mem.temp_size_in_bytes < 400 << 20
     assert not re.search(r"= bf16\[(4,)?4,8192,16,128\]\S* "
                          r"(copy|transpose|fusion)\(", text)
+
+
+# -- the prefill chunk (mistral7b-serve.closed32) -----------------------------
+
+
+def compile_chunk(sds, past_of, pages):
+    """serve.prefill_chunk at the cell's size (a chunk of 256 tokens, a
+    past of ``pages`` pages), its past read by ``past_of``."""
+    from paddle_tpu.inference.server import executor
+
+    ex, layers, tops = mistral_executor(sds)
+    i32 = jnp.int32
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(executor, "_past_of", past_of)
+        return jax.jit(ex._chunk_fwd).lower(
+            layers, tops, sds((1, 256), i32), sds((), i32), sds(POOL),
+            sds(POOL), sds((pages,), i32), sds((), i32)).compile()
+
+
+@pytest.mark.parametrize("pages", [0, 16, 96])
+def test_the_chunk_reads_its_past_and_moves_no_pool(sds, pages):
+    """The chunk program gathers its past from the pools by row, layer by
+    layer inside its scan: neither a pool nor a layer of one is copied,
+    re-laid or produced by a fusion; what is gathered is the past's own
+    pages (6.3 MB a layer at 96), and a first chunk gathers none.  The
+    temporaries the compiler reports are 0.4 MB at the longest past (0 for
+    the program that took its past dense): held under a quarter of a
+    layer, which the control below passes four times over."""
+    from paddle_tpu.inference.paged import _past_of
+
+    exe = compile_chunk(sds, _past_of, pages)
+    text, mem = exe.as_text(), exe.memory_analysis()
+    assert not POOL_OR_LAYER_MOVED.search(text)
+    assert mem.alias_size_in_bytes == 0
+    assert mem.temp_size_in_bytes < 32 << 20
+    assert re.findall(r"= bf16\[8,(\d+),16,128\]\S* gather\(", text) \
+        == [str(pages)] * (2 if pages else 0)
+
+
+def test_a_layer_sliced_out_for_the_chunk_is_copied(sds):
+    """The control: the same program with the layer taken out of the pool
+    first (``pool[layer][:, pids]``) copies that layer, 134 MB, for keys
+    and for values in every iteration of the scan."""
+    def sliced(pool, layer, pids, dtype):
+        return pool[layer][:, pids].reshape(POOL[1], -1, POOL[4])
+
+    exe = compile_chunk(sds, sliced, 96)
+    assert len(POOL_OR_LAYER_MOVED.findall(exe.as_text())) >= 2
+    assert exe.memory_analysis().temp_size_in_bytes > 128 << 20

@@ -256,6 +256,25 @@ def test_serving_span_tree(model):
                 if k.parent == w.id] == [("jit.dispatch", "serve.kv_write")]
 
 
+def test_a_prefill_chunk_is_one_program_and_one_write(model):
+    """On the Llama path the host hands the device a chunk in two
+    dispatches: the chunk's program, which reads its past from the pools
+    itself (no ``kv.gather``, first chunk or not), and the page writer."""
+    _, _, spans = serve(model, lens=(19, 30), new=3)
+    chunks = [s for s in spans if s.name == "req.prefill"]
+    assert [c.args["start"] for c in chunks].count(0) == 2
+    assert len(chunks) == 3 + 4
+    assert not [s for s in spans if s.name == "kv.gather"]
+    for c in chunks:
+        kids = [s for s in spans if s.parent == c.id]
+        assert [k.name for k in kids][:3] == [
+            "exec.prep", "jit.dispatch", "kv.write"]
+        assert [k.name for k in kids[3:]] == \
+            ["exec.fetch"] * bool(c.args["final"])
+        assert kids[1].args["program"] == "serve.prefill_chunk"
+        assert kids[2].args["dispatches"] == 1
+
+
 def test_traced_is_true_exactly_on_first_shapes(model):
     eng, _, spans = serve(model, lens=(5, 19), new=6)
     seen, want = set(), []
@@ -286,7 +305,7 @@ def test_traced_is_true_exactly_on_first_shapes(model):
 
 def test_span_budget_of_a_step(model):
     """A decode-only step records at most 8 spans, a prefill chunk at
-    most 7 more, none per token or page; instants are per request."""
+    most 6 more, none per token or page; instants are per request."""
     eng, _, spans = serve(model, lens=(5, 19, 30), new=12)
     timed = [s for s in spans if s.dur is not None]
     steps = [s for s in timed if s.name == "serve.step"]
@@ -297,7 +316,7 @@ def test_span_budget_of_a_step(model):
                   or (s.ts >= step.ts and s.ts + s.dur <= step.ts + step.dur
                       and "serve.step" in ancestors(s))]
         chunks = sum(s.name == "req.prefill" for s in inside)
-        assert len(inside) <= 8 + 7 * chunks, [s.name for s in inside]
+        assert len(inside) <= 8 + 6 * chunks, [s.name for s in inside]
         decode_only += not chunks
     assert decode_only >= 5
     instants = [s for s in spans if s.dur is None]
