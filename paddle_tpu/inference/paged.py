@@ -350,10 +350,16 @@ def _write_span(kp, vp, k, v, pids, offs):
     pages are read, the span laid over them at ``offs``, and whole
     ``(page_size, head_dim)`` tiles written back.  A row per token is a
     sub-tile write, for which the TPU compiler re-lays the whole pool
-    before and after the scatter (PERF.md section 6, PR 27)."""
+    before and after the scatter (PERF.md section 6, PR 27).
+
+    A latent pool (``PagedKVCache(latent=True)``) is the key pool alone:
+    ``vp`` and ``v`` are ``None`` and stay so."""
     if isinstance(kp, tuple):
         return (_quant.kv_write(*kp, pids, offs, k),
                 _quant.kv_write(*vp, pids, offs, v))
+
+    if vp is None:
+        return _write_rows(kp, k, pids, offs), None
 
     def put(pool, x):
         old = pool.at[:, :, pids].get(mode="clip")  # [L, KV, n, ps, D]
@@ -365,6 +371,26 @@ def _write_span(kp, vp, k, v, pids, offs):
                                        mode="drop")
 
     return put(kp, k), put(vp, v)
+
+
+def _write_rows(pool, x, pids, offs):
+    """:func:`_write_span`'s patch for a latent pool ``[L, 1, pages,
+    page_size, W]``, x ``[L, 1, T, W]``: the touched pages of every layer
+    are read, patched and written back BY ROW OF THE FLAT POOL, as
+    :func:`_put_token` does.  Indexed as ``pool.at[:, :, pids]`` a row of
+    more than 128 lanes makes the TPU compiler split the WHOLE pool by
+    lanes into two copies (2 GB of temporaries and 10 ms a chunk at 640
+    lanes; PERF.md section 6, PR 32)."""
+    L, _, pages, ps, W = pool.shape
+    flat = _flat(pool)
+    rows = jnp.where((pids < pages)[None, :],
+                     jnp.arange(L, dtype=pids.dtype)[:, None] * pages
+                     + pids[None, :], flat.shape[0])            # [L, n]
+    old = flat.at[rows].get(mode="clip")                        # [L, n, ps, W]
+    span = jax.lax.dynamic_update_slice_in_dim(
+        old.reshape(L, -1, W), x[:, 0].astype(pool.dtype), offs, axis=1)
+    return flat.at[rows].set(span.reshape(old.shape),
+                             mode="drop").reshape(pool.shape)
 
 
 # -- block-table cache manager ------------------------------------------
@@ -397,11 +423,20 @@ class PagedKVCache:
     (:meth:`set_pools`), so a write patches pages in place instead of
     copying a pool.  A donated array is deleted: read ``k_pages`` /
     ``v_pages`` afresh for every use and keep none across a write.
+
+    **A latent pool** (``latent=True``) is the same cache holding ONE row
+    a token a layer and no values: the compressed KV of a latent
+    attention (MLA) layer, ``[L, 1, num_pages, page_size, row]`` in
+    ``k_pages`` with ``v_pages`` ``None``.  The page table, the
+    allocator, ``reserve`` / ``trim`` / ``make_writable`` and the one
+    donated ``serve.kv_write`` a span are this class's own; only the
+    entry points that read K and V as heads (``append``, ``attend``, an
+    int8 pool) are refused.
     """
 
     def __init__(self, n_layers, n_kv_heads, head_dim, num_pages,
                  page_size=16, max_seqs=8, dtype=jnp.bfloat16,
-                 max_pages_per_seq=None, quant=None):
+                 max_pages_per_seq=None, quant=None, latent=False):
         self.n_layers = n_layers
         self.page_size = page_size
         self.num_pages = num_pages
@@ -416,6 +451,11 @@ class PagedKVCache:
         #: plain mode, the requested float dtype when the pool is int8.
         self.compute_dtype = dtype
         self.quant = _quant.quant_mode(quant)
+        self.latent = bool(latent)
+        if self.latent and (n_kv_heads != 1 or self.quant != "none"):
+            raise NotImplementedError(
+                "a latent pool is one row a token (n_kv_heads=1) in the "
+                "compute dtype: it has no heads to split and no int8 form")
         shape = (n_layers, n_kv_heads, num_pages, page_size, head_dim)
         if self.quant == "int8":
             # int8 pages + one f32 scale per (layer, kv-head, page),
@@ -429,7 +469,7 @@ class PagedKVCache:
                                       jnp.float32)
         else:
             self.k_pages = jnp.zeros(shape, dtype)
-            self.v_pages = jnp.zeros(shape, dtype)
+            self.v_pages = None if self.latent else jnp.zeros(shape, dtype)
             self.k_scales = None
             self.v_scales = None
         self._free = list(range(num_pages - 1, -1, -1))
@@ -569,8 +609,9 @@ class PagedKVCache:
         # or masked by the length)
         self.k_pages = self.k_pages.at[:, :, new].set(
             self.k_pages[:, :, old])
-        self.v_pages = self.v_pages.at[:, :, new].set(
-            self.v_pages[:, :, old])
+        if self.v_pages is not None:
+            self.v_pages = self.v_pages.at[:, :, new].set(
+                self.v_pages[:, :, old])
         if self.k_scales is not None:
             # a quantized page is meaningless without its scale — the
             # copy must carry both or the COW'd page dequantizes wrong
@@ -668,7 +709,8 @@ class PagedKVCache:
         :attr:`writer` whatever the span's length; on an int8 pool the
         span is quantized on write (``ops.quant.kv_write``:
         scatter-max the touched pages' scales, requantize residents,
-        write the new cells)."""
+        write the new cells).  A latent pool takes its rows as ``k``
+        and ``v=None``."""
         T = int(np.shape(k)[2])
         self._ensure_capacity(seq, start + T)
         # shared pages in the write window are read-only: COW them
@@ -775,6 +817,9 @@ class PagedKVCache:
         n = len(row)
         pids = jnp.asarray(row)
         k = self.k_pages[:, :, pids]          # [L, KV, n, ps, D]
+        if self.latent:
+            return k.reshape(k.shape[0], 1, n * self.page_size,
+                             k.shape[4]), None
         v = self.v_pages[:, :, pids]
         if self.quant == "int8":
             _faults.fire("quant.dequant", "before")
@@ -785,6 +830,13 @@ class PagedKVCache:
             _faults.fire("quant.dequant", "after")
         sh = (k.shape[0], k.shape[1], n * self.page_size, k.shape[4])
         return k.reshape(sh), v.reshape(sh)
+
+    def _heads_only(self, what):
+        if self.latent:
+            raise NotImplementedError(
+                f"PagedKVCache.{what}: a latent pool holds rows, not K "
+                f"and V heads; its decode step writes and attends inside "
+                f"the executor's program (server/latent_executor.py)")
 
     @property
     def free_pages(self) -> int:
@@ -802,6 +854,7 @@ class PagedKVCache:
         sequence's allocation first, commit only if the whole batch
         fits (otherwise an earlier seq would record a length whose
         page slot never got written)."""
+        self._heads_only("append")
         ps = self.page_size
         self.reserve(seqs, extra_tokens=1)  # batch-atomic
         for s in seqs:
@@ -833,6 +886,7 @@ class PagedKVCache:
                pages_per_compute_block=4):
         """Decode attention for one layer: q [B, H, D] over the listed
         sequences' pages."""
+        self._heads_only("attend")
         # clip -1 sentinels (unassigned slots beyond each length) to a
         # valid page id — the length mask excludes them from attention,
         # but gathers/kernel prefetch must stay in range

@@ -633,12 +633,18 @@ class ServingCluster:
             raise ValueError(
                 "disaggregated mode needs >= 2 replicas "
                 "(at least one prefill and one decode role)")
-        if "mamba" in getattr(model.config, "layer_types", ()):
+        kinds = set(getattr(model.config, "layer_types", ()))
+        if "mamba" in kinds:
             # a hand-off moves a request's pages, or replays it from the
             # journal; a recurrent state row is neither
             raise NotImplementedError(
                 "ServingCluster: cluster hand-off not supported for a "
                 "model with recurrent (state-space) layers")
+        if kinds & {"mla_dense", "mla_moe"}:
+            # the hand-off ships K and V heads; a latent pool has neither
+            raise NotImplementedError(
+                "ServingCluster: cluster hand-off not supported for a "
+                "model with latent attention (MLA) layers")
         self.model = model
         self.disaggregated = bool(disaggregated)
         self._engine_kwargs = dict(engine_kwargs)

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 
 from .executor import PagedExecutor, _sp_prefill_enabled
 from .hybrid_executor import HybridExecutor
+from .latent_executor import LatentExecutor
 from .metrics import EngineMetrics
 from .prefix_cache import PrefixCache
 from .request import Request, RequestHandle, RequestState
@@ -44,18 +45,25 @@ def _async_exec_enabled() -> bool:
     return mode == "on"
 
 
-def _refuse_for_recurrent(wanted: dict) -> None:
-    """A model with recurrent (state-space) layers keeps per-sequence
-    state that is not pages: every feature that assumes "state = pages"
-    is refused when the engine is built, by name, never run wrong."""
+#: why the engine's optional features are refused, by the kind of state
+#: a composed executor keeps
+_RECURRENT = ("a model with recurrent (state-space) layers: its "
+              "per-sequence state is one row of the recurrent-state cache, "
+              "which cannot be attached by reference, rolled back, handed "
+              "over or quantised like KV pages")
+_LATENT = ("a model with latent attention (MLA) layers: its pages hold "
+           "one compressed row a token and no K or V heads, which the "
+           "prefix index, the verify, decode_n, sequence-parallel, int8, "
+           "AOT and recovery programs of the paged executor do not read")
+
+
+def _refuse(wanted: dict, why: str) -> None:
+    """Every feature that assumes "state = pages of K and V heads" is
+    refused when the engine is built, by name, never run wrong."""
     asked = [name for name, on in wanted.items() if on]
     if asked:
         raise NotImplementedError(
-            f"ServingEngine: {', '.join(asked)} not supported for a model "
-            f"with recurrent (state-space) layers: its per-sequence state "
-            f"is one row of the recurrent-state cache, which cannot be "
-            f"attached by reference, rolled back, handed over or "
-            f"quantised like KV pages")
+            f"ServingEngine: {', '.join(asked)} not supported for {why}")
 
 
 class ServingEngine:
@@ -78,13 +86,17 @@ class ServingEngine:
         # mesh over every local device).
         # the programs follow the model's layer kinds: a model with
         # state-space layers gets the hybrid executor (a recurrent-state
-        # cache beside the paged KV pool) behind the same slot interface
-        recurrent = "mamba" in getattr(model.config, "layer_types", ())
-        if recurrent:
+        # cache beside the paged KV pool), one with latent attention
+        # layers the latent executor (a latent page pool), behind the
+        # same slot interface
+        kinds = set(getattr(model.config, "layer_types", ()))
+        recurrent = "mamba" in kinds
+        latent = bool(kinds & {"mla_dense", "mla_moe"})
+        if recurrent or latent:
             from paddle_tpu.core import aot as _aot
             from paddle_tpu.ops import quant as _quant
 
-            _refuse_for_recurrent({
+            _refuse({
                 "prefix cache": (_prefix_cache_enabled()
                                  if prefix_cache is None else prefix_cache),
                 "speculative decoding": (
@@ -101,8 +113,9 @@ class ServingEngine:
                                 else aot) != "off",
                 "write-ahead log": (wal_enabled() if wal is None
                                     else wal is not False),
-            })
-            self.executor = HybridExecutor(
+            }, _RECURRENT if recurrent else _LATENT)
+            self.executor = (HybridExecutor if recurrent
+                             else LatentExecutor)(
                 model, max_seqs=max_seqs, page_size=page_size,
                 max_len=max_len, dtype=dtype, num_pages=num_pages)
         else:

@@ -156,7 +156,14 @@ def _read_slot(ssm, conv, slot, d_head):
             jnp.concatenate([row(p, 2) for p in conv]))
 
 
-class HybridExecutor:
+class SlotExecutor:
+    """What the scheduler and the engine's status page ask of an executor
+    whose programs are composed from a model's layer kinds, whatever the
+    kinds: the slot-granular control plane over ``self.cache`` (a
+    :class:`~..paged.PagedKVCache`).  :class:`HybridExecutor` and
+    ``latent_executor.LatentExecutor`` add their state and their two
+    programs."""
+
     #: what the engine's status page reads of every executor
     quant = "none"
     sp_degree = 1
@@ -164,6 +171,29 @@ class HybridExecutor:
     _sp_axis = None
     aot_ladder = None
 
+    def sp_min_tokens_effective(self) -> int:
+        return 0
+
+    @property
+    def free_slots(self) -> int:
+        return self.cache.free_slots
+
+    @property
+    def free_pages(self) -> int:
+        return self.cache.free_pages
+
+    def pages_for(self, tokens: int) -> int:
+        return -(-int(tokens) // self.cache.page_size)
+
+    def prepare_write(self, sid: int, start: int, n_tokens: int) -> None:
+        self.cache._ensure_capacity(sid, start + n_tokens)
+
+    def prefill(self, sid: int, prompt_ids) -> int:
+        """A whole prompt is a chunk that starts at 0 and is final."""
+        return self.prefill_chunk(sid, prompt_ids, 0, True)
+
+
+class HybridExecutor(SlotExecutor):
     def __init__(self, model, max_seqs=4, page_size=16, max_len=256,
                  dtype=jnp.float32, num_pages=None):
         cfg = model.config
@@ -267,9 +297,6 @@ class HybridExecutor:
                 "hybrid_decode": self._jit_decode,
                 "kv_write": self.cache.writer,
                 "state_write": self.state.writer}
-
-    def sp_min_tokens_effective(self) -> int:
-        return 0
 
     # -- pure forwards -------------------------------------------------------
 
@@ -404,14 +431,6 @@ class HybridExecutor:
     # -- slot-granular control plane ----------------------------------------
 
     @property
-    def free_slots(self) -> int:
-        return self.cache.free_slots
-
-    @property
-    def free_pages(self) -> int:
-        return self.cache.free_pages
-
-    @property
     def state_bytes(self) -> int:
         return self.state.nbytes
 
@@ -427,9 +446,6 @@ class HybridExecutor:
         return _read_slot(*self.state.pools(), np.int32(sid),
                           self.config.mamba_d_head)
 
-    def pages_for(self, tokens: int) -> int:
-        return -(-int(tokens) // self.cache.page_size)
-
     def alloc_slot(self) -> int:
         sid = self.cache.allocate()
         self.state.allocate(sid)
@@ -442,13 +458,6 @@ class HybridExecutor:
         self.cache.free(sid)
         self.state.free(sid)
         self.last_token.pop(sid, None)
-
-    def prepare_write(self, sid: int, start: int, n_tokens: int) -> None:
-        self.cache._ensure_capacity(sid, start + n_tokens)
-
-    def prefill(self, sid: int, prompt_ids) -> int:
-        """A whole prompt is a chunk that starts at 0 and is final."""
-        return self.prefill_chunk(sid, prompt_ids, 0, True)
 
     def prefill_chunk(self, sid: int, chunk_ids, start: int,
                       final: bool) -> int | None:

@@ -1,11 +1,12 @@
 """Model zoo: flagship Llama family + training harness; vision models live
 in paddle_tpu.vision.models, BERT in models/bert.py (as added); the Mamba-2 / attention hybrid in
-models/granite_hybrid.py."""
+models/granite_hybrid.py; latent attention + routed experts in models/mla_moe.py."""
 from .llama import (  # noqa: F401
     LlamaConfig, LlamaForCausalLM, LlamaModel, llama_shard_rules,
 )
 from .granite_hybrid import (  # noqa: F401
     GraniteHybridConfig, GraniteHybridForCausalLM,
 )
+from .mla_moe import MLAMoEConfig, MLAMoEForCausalLM  # noqa: F401
 from .training import CompiledTrainStep  # noqa: F401
 from .generation import LlamaDecoder  # noqa: F401

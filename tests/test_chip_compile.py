@@ -317,3 +317,134 @@ def test_a_layer_sliced_out_for_the_chunk_is_copied(sds):
     exe = compile_chunk(sds, sliced, 96)
     assert len(POOL_OR_LAYER_MOVED.findall(exe.as_text())) >= 2
     assert exe.memory_analysis().temp_size_in_bytes > 128 << 20
+
+
+# -- sarvam105b-serve: the latent pool ----------------------------------------
+
+# 5 layers x one row a token x 4,096 pages x 128 tokens x 640 lanes: 3.36 GB
+LATENT_POOL = (5, 1, 4096, 128, 640)
+LATENT_POOL_OR_LAYER_MOVED = re.compile(
+    r"= bf16\[(5,|1,)?(1,)?(4096|20480),128,640\]\S* (copy|transpose|fusion)\(")
+
+
+def latent_executor(sds):
+    """sarvam105b-serve's executor without its arrays (the published
+    widths, one dense and four expert layers, 32 experts held, a quarter of
+    the vocabulary), and the shapes of its parameters."""
+    from paddle_tpu.inference.server.latent_executor import LatentExecutor
+    from paddle_tpu.models import mla_moe as mm
+
+    cfg = mm.MLAMoEConfig(num_hidden_layers=5, vocab_size=65536,
+                          dtype="bfloat16")
+    ex = object.__new__(LatentExecutor)
+    ex.config, ex.held = cfg, tuple(range(32))
+    ex.segments = [("mla_dense", 1), ("mla_moe", 4)]
+    ex.n_expert_layers, ex.rank, ex.row_width = 4, 512, 640
+    ex.cache = types.SimpleNamespace(page_size=LATENT_POOL[3],
+                                     num_pages=LATENT_POOL[2])
+    dense = {n: sds(s) for n, (s, _) in
+             mm._layer_shapes(cfg, "mla_dense", 32).items()}
+    run = {n: sds((4,) + tuple(s)) for n, (s, _) in
+           mm._layer_shapes(cfg, "mla_moe", 32).items()}
+    tops = {"embed": sds((65536, 4096)), "norm_w": sds((4096,)),
+            "lm_head": sds((4096, 65536))}
+    return ex, (dense, run), tops
+
+
+@pytest.fixture
+def compiled_latent_kernel(monkeypatch):
+    """Steer the program as the chip would: the Pallas kernel, compiled
+    (here the backend is the CPU, which takes the ``jax.numpy`` form)."""
+    from paddle_tpu.ops.pallas_kernels import mla_decode
+
+    monkeypatch.setattr(mla_decode, "_on_tpu", lambda: True)
+
+
+def test_the_latent_decode_step_moves_no_pool(sds, compiled_latent_kernel):
+    """serve.mla_decode at the cell's size (64 slots, 64 pages a
+    sequence): the latent pool aliased to the output, the kernel in the
+    program once for the dense layer and once in the scan, neither the
+    pool nor a layer of it copied, re-laid or produced by a fusion, the
+    temporaries under a quarter of one layer's 671 MB, and 12.43 GB of
+    arguments: the weights and the pool, nothing twice."""
+    ex, params, tops = latent_executor(sds)
+    i32 = jnp.int32
+    exe = jax.jit(ex._decode_fwd, donate_argnums=(5,)).lower(
+        params, tops, sds((64,), i32), sds((64,), i32),
+        sds((64,), jnp.bool_), sds(LATENT_POOL), sds((64, 64), i32)).compile()
+    text, mem = exe.as_text(), exe.memory_analysis()
+    assert re.search(r"input_output_alias=\{ \{1\}: \(\d+, \{\}, may-alias\) \}",
+                     text[:text.index("\n")])
+    pool = 2 * 5 * 4096 * 128 * 640
+    assert mem.alias_size_in_bytes == pool == 3_355_443_200
+    assert mem.temp_size_in_bytes < 160 << 20
+    assert 12.42e9 < mem.argument_size_in_bytes < 12.44e9
+    assert text.count("tpu_custom_call") >= 2
+    # the only programs that produce a pool are the token's two scatters
+    # (the dense layer's and the scan's), each in place on its operand
+    made = [line for line in text.splitlines()
+            if LATENT_POOL_OR_LAYER_MOVED.search(line)]
+    assert len(made) == 2
+    assert all("/scatter\"" in line and '"aliasing_operands"' in line
+               and " fusion(" in line for line in made)
+    # the layer's experts are multiplied where they lie in the stacked run
+    assert not re.search(r"= bf16\[(1,)?32,(4096,4096|2048,4096)\]\S* "
+                         r"(copy|transpose)\(", text)
+
+
+@pytest.mark.parametrize("pages", [0, 40])
+def test_the_latent_chunk_reads_its_past_and_moves_no_pool(sds, pages):
+    """serve.mla_chunk at a chunk of 1,024 tokens, first and last of a
+    6,144-token prompt: the pool is read by page id inside the program
+    and never copied; the experts of a layer are never copied out of the
+    stacked run (1.6 GB a layer when the loop over experts took a scan's
+    slice of them: PERF.md section 6, PR 32); the temporaries stay under
+    1 GB (a block of heads' scores, one expert's hidden rows)."""
+    ex, params, tops = latent_executor(sds)
+    i32 = jnp.int32
+    exe = jax.jit(ex._chunk_fwd).lower(
+        params, tops, sds((1024,), i32), sds((), i32), sds(LATENT_POOL),
+        sds((pages,), i32)).compile()
+    text, mem = exe.as_text(), exe.memory_analysis()
+    assert not LATENT_POOL_OR_LAYER_MOVED.search(text)
+    assert mem.alias_size_in_bytes == 0
+    assert mem.temp_size_in_bytes < 1 << 30
+    assert not re.search(r"= bf16\[(1,)?32,(4096,4096|2048,4096)\]\S* "
+                         r"(copy|transpose|fusion|dynamic-slice)\(", text)
+    assert len(re.findall(rf"= bf16\[{pages},128,640\]\S* gather\(",
+                          text)) == (2 if pages else 0)
+
+
+def test_the_latent_kernel_compiles_at_the_cells_size(sds):
+    """The kernel alone: 64 sequences x 64 heads on a shared 640-lane row,
+    pages of 128 fetched by the table from a 3.36 GB pool that stays in
+    HBM (an argument, no temporary)."""
+    from paddle_tpu.ops.pallas_kernels import mla_decode
+
+    i32 = jnp.int32
+    exe = mla_decode._mla_decode_call.lower(
+        sds((64, 64, 640)), sds((5 * 4096, 128, 640)), sds((), i32),
+        sds((64,), i32), sds((64, 64), i32), pages=4096, rank=512).compile()
+    mem = exe.memory_analysis()
+    assert mem.temp_size_in_bytes == 0
+    assert mem.output_size_in_bytes == 64 * 64 * 512 * 2
+
+
+def test_the_latent_page_writer_moves_no_pool(sds):
+    """serve.kv_write on the latent pool at the cell's chunk of 1,024
+    rows: the pool aliased to the output, NO temporary, and the pool
+    produced by one in-place scatter alone.  Indexed as K and V pools are
+    (``pool.at[:, :, pids]``) the 640-lane row made the compiler split
+    the whole pool by lanes into two copies: 2.0 GB of temporaries and
+    10 ms a chunk on the chip (PERF.md section 6, PR 32)."""
+    n = (1024 - 1) // LATENT_POOL[3] + 2
+    exe = jax.jit(_write_span, donate_argnums=(0, 1)).lower(
+        sds(LATENT_POOL), None, sds((5, 1, 1024, 640)), None,
+        sds((n,), jnp.int32), sds((), jnp.int32)).compile()
+    text, mem = exe.as_text(), exe.memory_analysis()
+    assert mem.alias_size_in_bytes == 3_355_443_200
+    assert mem.temp_size_in_bytes < 16 << 20
+    made = [line for line in text.splitlines() if re.search(
+        r"= bf16\[(5,)?(1,)?(4096|20480),128,\d+\]\S* "
+        r"(copy|transpose|fusion)\(", line)]
+    assert len(made) == 1 and "/scatter" in made[0]
