@@ -183,7 +183,10 @@ def paged_decode_attention(q, k_pages, v_pages, lengths, page_indices,
                            k_scales=None, v_scales=None, layer=None):
     """Decode attention over the page pool.  On TPU this is the
     self-authored fused kernel (``ops/pallas_kernels/paged_decode.py``:
-    per-sequence DMA page gather + whole decode attention in VMEM) or
+    per sequence a loop over 256-key blocks of the LIVE pages, every
+    KV head of a page in one DMA, double-buffered into VMEM, an online
+    softmax across blocks — work by the length, not the window; a row
+    of length 0 returns zeros) or
     the stock flash-style ``paged_attention`` kernel; elsewhere the
     dense-gather fallback jit-cached through the op registry.  Routing
     is overridable via ``PT_PAGED_IMPL`` (see ``_select_impl``).

@@ -28,6 +28,7 @@ from ... import obs
 from ...analysis import CountedJit, ProgramContract, register_program
 from ...ops import quant as _quant
 from ...ops.nn_ops import _rms_norm_plain, _rope_plain
+from ...ops.pallas_kernels.paged_decode import block_pages
 from ...testing import faults as _faults
 from ..paged import (
     PagedKVCache, _flat, _past_of, _put_token, paged_decode_attention,
@@ -195,6 +196,10 @@ class PagedExecutor:
                        else int(num_pages)),
             page_size=page_size, max_seqs=max_seqs, dtype=dtype,
             max_pages_per_seq=pages_per_seq, quant=self.quant)
+        #: keys in one block of the fused decode kernel's loop
+        self._decode_block = page_size * block_pages(
+            page_size, cfg.num_key_value_heads, cfg.head_dim,
+            jnp.dtype(self.cache.compute_dtype).itemsize)
         h = obs.handle()
         if h is not None:
             h.registry.gauge(
@@ -1279,7 +1284,15 @@ class PagedExecutor:
         if not sids:
             return {}
         cache = self.cache
-        with obs.span("exec.prep", cat="serve", batch=len(sids)):
+        # how much of the window the fused kernel's block loop visits:
+        # blocks that hold one of the lengths + 1 keys a sequence reads,
+        # of the blocks of every window
+        block = self._decode_block
+        with obs.span("exec.prep", cat="serve", batch=len(sids),
+                      blocks=int((cache.lengths[sids] // block + 1).sum()),
+                      window_blocks=len(sids) * -(
+                          -cache.max_pages_per_seq * cache.page_size
+                          // block)):
             # batch-atomic page reservation BEFORE the jitted
             # write-then-attend: a per-sequence loop would strand
             # earlier sequences' fresh pages when a later one exhausts

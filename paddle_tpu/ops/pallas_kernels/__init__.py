@@ -14,9 +14,11 @@ designed for this framework's hot paths and profiles:
   round-trip.  Also carries the int8-expert-weight variant
   (``PT_QUANT=int8``) with dequant fused at the MXU.
 - ``paged_decode``: single-token decode attention over the paged KV
-  pool, one pipelined DMA burst per (sequence, kv-head); the
-  ``_quant`` variant streams int8 pages with per-page scales via
-  scalar prefetch.
+  pool, per sequence a double-buffered loop over 256-key blocks of
+  the live pages (all KV heads of a page in one DMA) with an online
+  softmax; the ``_quant``
+  variant streams int8 pages with per-page scales via scalar prefetch
+  (one DMA burst over the whole window still).
 - ``quant_matmul``: activation x int8-weight GEMM with the
   per-output-channel dequant applied to the f32 accumulator at flush —
   the serving weight matmul under ``PT_QUANT=int8``.

@@ -6,8 +6,9 @@ description is made inside a fixture, never while a module is imported.
 
 Pinned so far, each writer with the pools aliased from input to output and
 no copy of a pool or of a layer of one: mistral7b-serve's page writer
-(``serve.kv_write``) and its whole decode step (``serve.decode``, the pools
-carried through the layer scan into the Pallas kernel); granite4h-micro's
+(``serve.kv_write``), its whole decode step (``serve.decode``, the pools
+carried through the layer scan into the Pallas kernel) and that kernel at a
+window of 8,192 tokens; granite4h-micro's
 state kernel inside a scan, its state writer, and the page patch and window
 gather on its folded KV pool; and, reading the pools without writing them,
 mistral7b-serve's prefill chunk (``serve.prefill_chunk``, its past gathered
@@ -154,7 +155,9 @@ def test_the_decode_step_moves_no_pool(sds, compiled_kernel, batch):
                      text[:text.index("\n")])
     assert mem.alias_size_in_bytes == 2 * 2 * 8 * 8 * 4096 * 16 * 128
     assert mem.temp_size_in_bytes < 128 << 20
-    assert "tpu_custom_call" in text
+    # ONE kernel a layer: the scan's body holds a single custom call
+    assert len(re.findall(r" custom-call\(.*custom_call_target=\"tpu_custom_call\"",
+                          text)) == 1
     assert not POOL_OR_LAYER_MOVED.search(text)
 
 
@@ -182,6 +185,34 @@ def test_pools_scanned_layer_by_layer_are_moved(sds, compiled_kernel):
         sds((32, 128), i32)).compile()
     assert exe.memory_analysis().temp_size_in_bytes > 128 << 20
     assert len(POOL_OR_LAYER_MOVED.findall(exe.as_text())) >= 4
+
+
+def test_the_decode_kernel_compiles_at_a_window_of_8192_tokens(
+        sds, compiled_kernel):
+    """The kernel alone at 512 pages a sequence (a pool four times the
+    cell's, 32 sequences): Mosaic takes it, the table of 32 x 512 page
+    ids rides the scalar prefetch, and the VMEM scratch is what it is at
+    the cell's 128 pages — two slots of one 256-key block of all 8 KV
+    heads for K and for V, 4 x 512 KB.  (The whole-window form wanted
+    4 MB of scratch and 8 MB of float32 copies a program and KV head
+    there.)"""
+    from paddle_tpu.ops.pallas_kernels import paged_decode
+
+    i32 = jnp.int32
+
+    def args(pages):
+        pool = sds((8, 8, 32 * pages, 16, 128))
+        return (sds((32, 8, 4, 128)), pool, pool, sds((32,), i32),
+                sds((32, pages), i32), sds((), i32))
+
+    def scratch(pages):
+        text = str(paged_decode._call.trace(*args(pages), scale=0.088).jaxpr)
+        return sorted(set(re.findall(r"Ref<vmem>\{(\w+\[[\d,]+\])\}", text)))
+
+    assert scratch(512) == scratch(128) == ["bf16[2,8,256,128]"]
+    exe = paged_decode._call.lower(*args(512), scale=0.088).compile()
+    assert "tpu_custom_call" in exe.as_text()
+    assert exe.memory_analysis().temp_size_in_bytes == 0
 
 
 # -- the hybrid executor's state path (granite4h-micro-serve) -------------------

@@ -3,7 +3,8 @@ scan and address them by layer in place (PR 29): the layer-addressed
 attention equals the attention over the sliced layer, a decode step
 touches one slot of one page a sequence and layer and nothing else, and
 every program that runs the shared layer stack emits the tokens a dense
-float32 recomputation of the model gives.
+float32 recomputation of the model gives — also through the fused
+kernel's block loop (PR 33; interpret mode), across the edge of a block.
 """
 import numpy as np
 import pytest
@@ -104,12 +105,12 @@ def model():
 PROMPT_LENS = (5, 9, 3)
 
 
-def _started(model, **kw):
+def _started(model, lens=PROMPT_LENS, page_size=4, max_len=64, **kw):
     """An executor with three sequences prefilled at ragged lengths."""
-    ex = PagedExecutor(model, max_seqs=4, page_size=4, max_len=64, **kw)
+    ex = PagedExecutor(model, max_seqs=4, page_size=page_size,
+                       max_len=max_len, **kw)
     rng = np.random.RandomState(3)
-    prompts = [rng.randint(1, 256, (n,)).astype(np.int32)
-               for n in PROMPT_LENS]
+    prompts = [rng.randint(1, 256, (n,)).astype(np.int32) for n in lens]
     sids = []
     for p in prompts:
         sids.append(ex.alloc_slot())
@@ -218,3 +219,91 @@ def test_the_int8_pool_keeps_its_scanned_form_and_its_tokens(model):
     many = b.decode_n(sb, 6)
     for x, y in zip(sa, sb):
         assert [t[x] for t in one] == many[y]
+
+
+# -- (d) the same programs through the fused kernel's block loop (PR 33) ----
+#
+# Pages of 32 tokens: a block of the kernel is 8 pages = 256 keys and the
+# window of 320 tokens is two blocks.  Sequence 0 starts six tokens
+# before the edge of a block and decodes across it, sequence 1 starts
+# past it, sequence 2 stays in the first page.
+
+BLOCK_LENS = (250, 259, 3)
+BLOCK_KW = dict(page_size=32, max_len=320)
+
+
+@pytest.fixture(scope="module")
+def long_model():
+    paddle.seed(12)
+    cfg = LlamaConfig(vocab_size=256, hidden_size=64,
+                      intermediate_size=128, num_hidden_layers=2,
+                      num_attention_heads=4, num_key_value_heads=2,
+                      max_position_embeddings=512)
+    return LlamaForCausalLM(cfg)
+
+
+@pytest.fixture
+def fused_kernel(monkeypatch):
+    """The Pallas kernel (interpreted here) instead of the CPU's dense
+    path."""
+    monkeypatch.setenv("PT_PAGED_IMPL", "pallas")
+
+
+@pytest.mark.parametrize("how", list(STEPPERS))
+def test_tokens_through_the_block_loop_equal_a_dense_float32_recomputation(
+        long_model, fused_kernel, how):
+    """Twelve tokens a sequence with the lengths on both sides of a
+    block's edge; a verify window's three rows read 255, 256 and 257
+    keys of one sequence in one call, each row its own trip count."""
+    ex, sids, prompts = _started(long_model, BLOCK_LENS, **BLOCK_KW)
+    if how == "verify":
+        ahead = _drive(*_started(long_model, BLOCK_LENS, **BLOCK_KW)[:2],
+                       "decode", 18)
+        out = _drive_verify(ex, sids, ahead, 12)
+    else:
+        out = _drive(ex, sids, how, 12)
+    for s, p in zip(sids, prompts):
+        answer = np.asarray(out[s][:12])
+        assert len(answer) == 12
+        np.testing.assert_array_equal(answer,
+                                      _recomputed(long_model, p, answer))
+
+
+def test_an_aot_warmed_engine_serves_through_the_block_loop(
+        long_model, fused_kernel, tmp_path):
+    """The AOT ladder's programs (every decode batch size compiled ahead,
+    a prompt cut into rungs whose past page ids are padded to a bucket)
+    give the dense float32 recomputation's tokens with the kernel in
+    them, a long and a short request decoding side by side."""
+    from paddle_tpu.inference.server import ServingEngine
+
+    eng = ServingEngine(long_model, aot="warm", compile_cache=str(tmp_path),
+                        max_seqs=2, prefill_chunk=64, **BLOCK_KW)
+    rng = np.random.RandomState(4)
+    prompts = [rng.randint(1, 256, (n,)).astype(np.int32)
+               for n in (251, 40)]
+    handles = [eng.submit(p, max_new_tokens=10) for p in prompts]
+    eng.run()
+    for h, p in zip(handles, prompts):
+        answer = np.asarray(h.tokens)
+        assert len(answer) == 10
+        np.testing.assert_array_equal(answer,
+                                      _recomputed(long_model, p, answer))
+
+
+def test_a_decode_step_says_how_many_blocks_it_reads(long_model):
+    """``exec.prep``'s decode span carries the kernel's work beside the
+    batch: ``blocks`` = sum of cdiv(length + 1, 256) over the batch,
+    ``window_blocks`` = batch x the window's blocks."""
+    from paddle_tpu import obs
+
+    ex, sids, _ = _started(long_model, BLOCK_LENS, **BLOCK_KW)
+    seen = []
+    for _ in range(7):
+        ex.decode(sids)
+        seen.append([s for s in obs.tracer().spans
+                     if s.name == "exec.prep"][-1].args)
+    # sequence 0 reads 251..257 keys: its second block in the seventh step
+    assert [a["blocks"] for a in seen] == [4] * 6 + [5]
+    assert {a["window_blocks"] for a in seen} == {6}
+    assert {a["batch"] for a in seen} == {3}

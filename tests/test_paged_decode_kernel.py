@@ -1,8 +1,9 @@
 """Self-authored fused paged-decode attention kernel vs the dense
 oracle (reference block_multi_head_attention semantics).  Off-TPU the
 kernel runs in Pallas interpreter mode — same kernel body, no tiling
-constraints — so the fusion logic (DMA page gather, length masking,
-GQA grouping, window-tail zeroing) is exercised everywhere.
+constraints — so the fusion logic (DMA page gather block by block,
+length masking, the running softmax across blocks, GQA grouping, the
+scrubbed tail of the last block) is exercised everywhere.
 """
 import numpy as np
 import pytest
@@ -10,7 +11,7 @@ import pytest
 import jax.numpy as jnp
 
 from paddle_tpu.ops.pallas_kernels.paged_decode import (
-    paged_decode, supported,
+    block_pages, paged_decode, supported,
 )
 
 
@@ -128,19 +129,185 @@ def test_supported_gate():
     assert not supported(head_dim=128, page_size=16, on_tpu=False)
 
 
-def test_the_kernel_body_does_not_grow_with_the_window():
-    """The page DMAs are loops, not an unroll: the traced program is as
-    long at 64 pages a sequence as at 4.  (Unrolled, the kernel was
-    re-traced for every decode batch size at ~400 conditionals a trace,
-    most of a serving engine's set-up.)"""
+def _traced(B, pps, ps=4, dtype=np.float32):
+    """The jaxpr of one call at a batch of ``B`` and ``pps`` pages a
+    sequence."""
     import jax
 
-    def eqns(pps):
-        rng = np.random.RandomState(0)
-        q, kp, vp, table = _mk(rng, B=2, H=4, KV=2, D=16, P=2 * pps, ps=4,
-                               pps=pps)
-        text = str(jax.make_jaxpr(paged_decode)(
-            q, kp, vp, np.array([3, 4 * pps], np.int32), table))
-        return text.count("\n")
+    rng = np.random.RandomState(0)
+    q, kp, vp, table = _mk(rng, B=B, H=4, KV=2, D=16, P=B * pps, ps=ps,
+                           pps=pps, dtype=dtype)
+    lens = np.full((B,), ps * pps, np.int32)
+    lens[0] = 3
+    return jax.make_jaxpr(paged_decode)(q, kp, vp, lens, table)
 
-    assert eqns(64) == eqns(4)
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside it (the jitted
+    wrapper, the kernel's body, its loops and conditionals)."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += 1
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    n += _equations(sub)
+    return n
+
+
+def test_the_kernel_body_does_not_grow_with_the_window():
+    """The page DMAs and the blocks are loops, not an unroll: the traced
+    program holds as many equations at 64 pages a sequence as at 4.
+    (Unrolled, the kernel was re-traced for every decode batch size at
+    ~400 conditionals a trace, most of a serving engine's set-up.)"""
+    few, many = _traced(2, 4).jaxpr, _traced(2, 64).jaxpr
+    assert _equations(many) == _equations(few) > 50
+    # as the pretty-printer has it too, but for where it wraps a line
+    # (a longer shape wraps earlier)
+    assert abs(str(many).count("\n") - str(few).count("\n")) <= 2
+
+
+# -- the block loop (PR 33) ---------------------------------------------------
+#
+# A block is 256 keys: four pages of 64 here, so a window of 12 pages is
+# three blocks, and sixteen pages of 16 as in the serving cell.
+
+BLOCK = 256
+EDGES = (1, BLOCK - 1, BLOCK, BLOCK + 1, 3 * BLOCK - 1, 3 * BLOCK)
+
+
+def _run(q, kp, vp, lens, table):
+    return np.asarray(paged_decode(jnp.asarray(q), jnp.asarray(kp),
+                                   jnp.asarray(vp), lens, table),
+                      np.float32)
+
+
+@pytest.mark.parametrize("ps", [16, 64])
+def test_a_block_is_256_keys_of_whole_pages(ps):
+    """... at the serving cell's pool (8 KV heads x 128, bf16: 2 MiB of
+    scratch) and at these tests'; fewer keys where every KV head's two
+    slots of K and V would pass 4 MiB; never less than a page."""
+    assert block_pages(ps, 8, 128, 2) * ps == BLOCK
+    assert block_pages(ps, 2, 16, 4) * ps == BLOCK
+    assert block_pages(ps, 32, 128, 4) * ps == 64
+    assert block_pages(512, 8, 128, 2) == 1
+    assert block_pages(24, 8, 128, 2) == 10
+
+
+@pytest.mark.parametrize("ps", [16, 64])
+def test_lengths_at_every_edge_of_a_block_in_one_batch(ps):
+    """Ragged batch with GQA whose lengths sit on, before and after each
+    edge of a block of a three-block window: the trip count, the last
+    block's mask and the running softmax across blocks all hold."""
+    rng = np.random.RandomState(6)
+    pps = 3 * BLOCK // ps
+    q, kp, vp, table = _mk(rng, B=len(EDGES), H=8, KV=2, D=16,
+                           P=len(EDGES) * pps + 1, ps=ps, pps=pps)
+    lens = np.array(EDGES, np.int32)
+    np.testing.assert_allclose(_run(q, kp, vp, lens, table),
+                               _oracle(q, kp, vp, lens, table),
+                               rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("length", EDGES)
+def test_one_sequence_at_an_edge_of_a_block(length):
+    """Each edge alone, after a program of the same call that filled
+    both scratch slots to the brim (sequence 0 reads the whole window):
+    what it left there is not seen."""
+    rng = np.random.RandomState(length)
+    q, kp, vp, table = _mk(rng, B=2, H=4, KV=1, D=32, P=24, ps=64, pps=12)
+    lens = np.array([3 * BLOCK, length], np.int32)
+    np.testing.assert_allclose(_run(q, kp, vp, lens, table),
+                               _oracle(q, kp, vp, lens, table),
+                               rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("lens", [(0, 300, 0, 1), (0, 0, 0, 0)],
+                         ids=["mixed", "all"])
+def test_a_row_of_length_zero_reads_nothing_and_is_finite(lens):
+    """A padded batch row (length 0) returns zeros, whatever its table
+    row names, and the live rows beside it are unmoved by it."""
+    rng = np.random.RandomState(7)
+    q, kp, vp, table = _mk(rng, B=4, H=4, KV=2, D=16, P=48, ps=64, pps=12)
+    kp[:, table[0]] = np.nan
+    vp[:, table[0]] = np.nan
+    lens = np.array(lens, np.int32)
+    got = _run(q, kp, vp, lens, table)
+    assert np.isfinite(got).all()
+    assert not got[lens == 0].any()
+    live = lens > 0
+    if live.any():
+        np.testing.assert_allclose(
+            got[live], _oracle(q[live], kp, vp, lens[live], table[live]),
+            rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("length", [100, BLOCK, 2 * BLOCK + 70])
+def test_nothing_dead_is_read_or_used(length):
+    """Every page past the length holds NaN in both pools, and the
+    table's dead entries point at a NaN page: a dead page that was
+    fetched, or a scratch row left unscrubbed under a zero weight, would
+    show as NaN.  (Sequence 0 runs first and leaves live numbers of its
+    own in both slots.)"""
+    rng = np.random.RandomState(length)
+    ps, pps = 64, 12
+    q, kp, vp, table = _mk(rng, B=2, H=4, KV=2, D=16, P=24, ps=ps, pps=pps)
+    lens = np.array([3 * BLOCK, length], np.int32)
+    want = _oracle(q, kp, vp, lens, table)
+    used = -(-length // ps)
+    kp[:, table[1, used:]] = np.nan
+    vp[:, table[1, used:]] = np.nan
+    nan_page = np.full((2, 1, ps, 16), np.nan, np.float32)
+    kp, vp = (np.concatenate([pool, nan_page], axis=1) for pool in (kp, vp))
+    table[1, used:] = 24                        # the page of NaN
+    got = _run(q, kp, vp, lens, table)
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("ps", [16, 64])
+def test_bfloat16_pool_across_three_blocks(ps):
+    """The pool's dtype is the products' operand dtype: a bf16 pool at a
+    length that spans three blocks stays inside the bf16 tolerance of
+    the whole-window form."""
+    rng = np.random.RandomState(8)
+    pps = 3 * BLOCK // ps
+    q, kp, vp, table = _mk(rng, B=2, H=4, KV=2, D=16, P=2 * pps, ps=ps,
+                           pps=pps)
+    lens = np.array([2 * BLOCK + 77, BLOCK + 1], np.int32)
+    got = paged_decode(jnp.asarray(q, jnp.bfloat16),
+                       jnp.asarray(kp, jnp.bfloat16),
+                       jnp.asarray(vp, jnp.bfloat16), lens, table)
+    assert got.dtype == jnp.bfloat16
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), _oracle(q, kp, vp, lens, table),
+        rtol=5e-2, atol=5e-2)
+
+
+@pytest.mark.parametrize("other", [(2, 64), (32, 4), (32, 64)],
+                         ids=["window", "batch", "both"])
+def test_the_traced_body_is_the_same_at_every_window_and_batch(other):
+    """As many equations at 64 pages a sequence as at 4, and at a batch
+    of 32 as at 2: the serving cell traces this body once for each of
+    its 32 decode batch sizes."""
+    assert _equations(_traced(*other).jaxpr) \
+        == _equations(_traced(2, 4).jaxpr)
+
+
+@pytest.mark.parametrize("dtype", [np.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_the_scratch_does_not_grow_with_the_window(dtype):
+    """Two slots of one block of every KV head for K and for V,
+    whatever the window: the VMEM the kernel asks for at 512 pages a
+    sequence is what it asks for at 16."""
+    import re
+
+    def scratch(pps):
+        text = str(_traced(2, pps, ps=16, dtype=dtype))
+        return sorted(set(re.findall(r"Ref<vmem>\{(\w+)\[([\d,]+)\]\}",
+                                     text)))
+
+    name = "f32" if dtype is np.float32 else "bf16"
+    assert scratch(512) == scratch(16)
+    assert scratch(16) == [(name, f"2,2,{BLOCK},16")]    # [slot, KV, keys, D]
