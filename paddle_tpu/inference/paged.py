@@ -19,6 +19,8 @@ one is ever copied.
 """
 from __future__ import annotations
 
+from typing import NamedTuple
+
 import numpy as np
 
 import jax
@@ -75,10 +77,10 @@ def masked_multihead_attention(q, k_cache, v_cache, lengths, name=None):
     return out if wrap else out._data
 
 
-def _window_attention(q, kc, vc, lengths):
+def _window_attention(q, kc, vc, lengths, starts=None):
     """Decode attention over gathered windows, float32: q [B, H, D];
     kc/vc [B, KV, T, D], each sequence's window laid dense; lengths [B]
-    keys each query reads."""
+    keys each query reads, from ``starts`` [B] on where given."""
     B, H, D = q.shape
     KV, T = kc.shape[1], kc.shape[2]
     g = H // KV
@@ -87,6 +89,9 @@ def _window_attention(q, kc, vc, lengths):
                         kc.astype(jnp.float32)) / np.sqrt(D)
     mask = jnp.arange(T)[None, None, None, :] < \
         lengths[:, None, None, None]
+    if starts is not None:
+        mask &= jnp.arange(T)[None, None, None, :] >= \
+            starts[:, None, None, None]
     logits = jnp.where(mask, logits, -1e30)
     p = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bkgt,bktd->bkgd", p, vc.astype(jnp.float32))
@@ -109,18 +114,23 @@ def _dense_paged_attention(q, k_pages, v_pages, lengths, page_indices):
                              vc.reshape(B, KV, T, D), lengths)
 
 
-def _dense_pool_attention(q, k_pool, v_pool, lengths, page_indices, layer):
+def _dense_pool_attention(q, k_pool, v_pool, lengths, page_indices, layer,
+                          starts=None, bases=None):
     """:func:`_dense_paged_attention` over layer ``layer`` (an int32
     scalar, traced or not) of the whole pools ``[L, KV, P, ps, D]``: each
     sequence's window is gathered by row from the pool viewed flat, so no
-    layer is sliced out of it (a copy of that layer on the TPU)."""
+    layer is sliced out of it (a copy of that layer on the TPU).  A
+    window layer gives ``starts`` and ``bases`` as the fused kernel takes
+    them (``ops/pallas_kernels/paged_decode.py``)."""
     B, _, D = q.shape
     KV, ps = k_pool.shape[1], k_pool.shape[3]
     T = page_indices.shape[1] * ps
     rows = _rows(k_pool.shape, layer, page_indices)       # [B, KV, pps]
+    if starts is not None:
+        lengths, starts = lengths - bases, starts - bases
     return _window_attention(q, _flat(k_pool)[rows].reshape(B, KV, T, D),
                              _flat(v_pool)[rows].reshape(B, KV, T, D),
-                             lengths)
+                             lengths, starts)
 
 
 def _dense_paged_attention_q(q, k_pages, v_pages, lengths, page_indices,
@@ -180,7 +190,8 @@ def _select_impl(head_dim, page_size):
 
 def paged_decode_attention(q, k_pages, v_pages, lengths, page_indices,
                            pages_per_compute_block=4,
-                           k_scales=None, v_scales=None, layer=None):
+                           k_scales=None, v_scales=None, layer=None,
+                           starts=None, bases=None):
     """Decode attention over the page pool.  On TPU this is the
     self-authored fused kernel (``ops/pallas_kernels/paged_decode.py``:
     per sequence a loop over 256-key blocks of the LIVE pages, every
@@ -201,6 +212,12 @@ def paged_decode_attention(q, k_pages, v_pages, lengths, page_indices,
     gets a ``dynamic_index_in_dim`` slice — a copy of that layer on the
     TPU, the price of ``PT_PAGED_IMPL=stock`` (PERF.md section 7).
 
+    ``starts`` / ``bases`` [B] make the call a WINDOW layer's (with
+    ``layer``): each sequence reads keys ``[starts, lengths)`` through a
+    table whose first entry stands for token ``bases`` (the fused kernel's
+    contract; the dense path masks the same keys).  The stock kernel and
+    the int8 pool have no such inlet and refuse.
+
     ``k_scales``/``v_scales`` [KV, P] select the int8-page path
     (``PT_QUANT=int8``): the fused quant kernel when its (stricter)
     shape gate passes, else the dense dequantize-the-gather fallback —
@@ -211,6 +228,10 @@ def paged_decode_attention(q, k_pages, v_pages, lengths, page_indices,
     lengths = jnp.asarray(lengths, jnp.int32)
     page_indices = jnp.asarray(page_indices, jnp.int32)
 
+    windowed = starts is not None
+    if windowed and (layer is None or k_scales is not None):
+        raise ValueError("a window layer is attended in the whole plain "
+                         "pools, its layer named")
     if k_scales is not None:
         from ..ops.pallas_kernels import paged_decode as _fused
 
@@ -244,6 +265,9 @@ def paged_decode_attention(q, k_pages, v_pages, lengths, page_indices,
     if layer is not None:
         layer = jnp.asarray(layer, jnp.int32)
         args.append(Tensor(layer))
+    if windowed:
+        args += [Tensor(jnp.asarray(starts, jnp.int32)),
+                 Tensor(jnp.asarray(bases, jnp.int32))]
 
     if impl == "pallas":
         from ..ops.pallas_kernels import paged_decode as _fused
@@ -253,9 +277,15 @@ def paged_decode_attention(q, k_pages, v_pages, lengths, page_indices,
     if impl == "dense":
         name, fn = (("paged_decode_attention", _dense_paged_attention)
                     if layer is None else
+                    ("paged_decode_attention_window", _dense_pool_attention)
+                    if windowed else
                     ("paged_decode_attention_pool", _dense_pool_attention))
         out = _op(name, fn, *args)
         return out if wrap else out._data
+    if windowed:
+        raise NotImplementedError(
+            "PT_PAGED_IMPL=stock: jax's paged_attention kernel reads every "
+            "key from token 0 and cannot attend a window layer")
     from jax.experimental.pallas.ops.tpu.paged_attention import (
         paged_attention,
     )
@@ -356,7 +386,14 @@ def _write_span(kp, vp, k, v, pids, offs):
     before and after the scatter (PERF.md section 6, PR 27).
 
     A latent pool (``PagedKVCache(latent=True)``) is the key pool alone:
-    ``vp`` and ``v`` are ``None`` and stay so."""
+    ``vp`` and ``v`` are ``None`` and stay so.
+
+    A cache of several layer groups gives LISTS, one entry a group, of
+    pools, spans, page ids and slots: every group's pages are patched in
+    this one program, each by its own table's ids."""
+    if isinstance(kp, list):
+        done = [_write_span(*one) for one in zip(kp, vp, k, v, pids, offs)]
+        return [d[0] for d in done], [d[1] for d in done]
     if isinstance(kp, tuple):
         return (_quant.kv_write(*kp, pids, offs, k),
                 _quant.kv_write(*vp, pids, offs, v))
@@ -399,6 +436,66 @@ def _write_rows(pool, x, pids, offs):
 # -- block-table cache manager ------------------------------------------
 
 
+class LayerGroup(NamedTuple):
+    """The spec of a run of layers that keep their keys alike: ``n_layers``
+    layers over a pool of ``num_pages`` pages; ``window`` None for FULL
+    attention (every token is kept) or w for a SLIDING window (a query
+    sees the last w keys, itself included; pages wholly behind the window
+    are released); ``pages_per_seq`` the width of a sequence's table row
+    (for a window group the pages of ``window + the longest span written
+    at once``, plus one for misalignment; ``num_pages // max_seqs`` where
+    none is given)."""
+
+    n_layers: int
+    num_pages: int
+    window: int | None = None
+    pages_per_seq: int | None = None
+
+
+class _Group:
+    """One layer group's state: its pools, its free list, its refcounts
+    and its page-table rows.  ``base[seq]`` is the token a row's first
+    entry stands for: 0 in a full group, the first token of the oldest
+    page kept in a window group (a multiple of the page size)."""
+
+    def __init__(self, spec, shape, dtype, max_seqs, max_pages_per_seq,
+                 quant, latent):
+        self.n_layers, self.num_pages = spec.n_layers, spec.num_pages
+        self.window = spec.window
+        self.max_pages_per_seq = max_pages_per_seq
+        if quant == "int8":
+            # int8 pages + one f32 scale per (layer, kv-head, page),
+            # kept alongside the page table: a page's scale moves,
+            # copies, and frees with the page.
+            self.k_pages = jnp.zeros(shape, jnp.int8)
+            self.v_pages = jnp.zeros(shape, jnp.int8)
+            self.k_scales = jnp.zeros(shape[:3], jnp.float32)
+            self.v_scales = jnp.zeros(shape[:3], jnp.float32)
+        else:
+            self.k_pages = jnp.zeros(shape, dtype)
+            self.v_pages = None if latent else jnp.zeros(shape, dtype)
+            self.k_scales = None
+            self.v_scales = None
+        self._free = list(range(spec.num_pages - 1, -1, -1))
+        # page table: [max_seqs, max_pages_per_seq] int32; -1 = unset
+        # (page id 0 is valid, so 0 cannot double as the sentinel)
+        self.page_table = np.full((max_seqs, max_pages_per_seq), -1,
+                                  np.int32)
+        self.base = np.zeros((max_seqs,), np.int32)
+        # per-page owner count: slots referencing it + the prefix index
+        self.page_refs = np.zeros((spec.num_pages,), np.int32)
+        #: pages dereferenced behind the window, a running sum
+        self.released = 0
+
+
+def _of_first_group(name):
+    """A cache attribute that speaks of its first layer group: what every
+    caller of a cache of one group reads and writes."""
+    return property(lambda self: getattr(self.groups[0], name),
+                    lambda self, value: setattr(self.groups[0], name, value))
+
+
+
 class PagedKVCache:
     """Block-table KV cache (reference block_multi_head_attention's
     pre-allocated block pool + per-sequence block table).
@@ -435,20 +532,58 @@ class PagedKVCache:
     donated ``serve.kv_write`` a span are this class's own; only the
     entry points that read K and V as heads (``append``, ``attend``, an
     int8 pool) are refused.
+
+    **Layer groups** (``groups=[LayerGroup, ...]``): layers that keep
+    their keys differently live in pools of their own, ``[L_g, KV,
+    pages_g, page_size, D]``, each with its free list, its refcounts and
+    its page-table rows, behind the ONE allocator interface: a slot, its
+    length, ``reserve`` / ``_ensure_capacity`` (all groups or none),
+    ``free``, and one donated ``serve.kv_write`` a span that patches every
+    group's pages (``pools()`` then gives lists, one entry a group).  A
+    FULL group keeps every token.  A WINDOW group keeps, per sequence,
+    the pages that hold a token the next query can still see — tokens
+    ``>= length + 1 - window`` — and what the span in flight writes:
+    after every ``write_at`` and every :meth:`release` (a decode step)
+    the pages wholly behind the window are dereferenced to the group's
+    free list, on the host, after the program that last reads them was
+    dispatched (programs run in dispatch order), and the row is shifted
+    left so that its first entry is the oldest page kept
+    (``groups[g].base[seq]`` is the token it stands for).  A released
+    page is gone: what attaches pages by reference, rolls a length back
+    or reads K and V of every token (``attach``, ``trim``, ``append``,
+    ``attend``, the sharded writes, int8, latent) is refused for a cache
+    of more than one group.  ``k_pages``, ``page_table``, ``num_pages``,
+    ``free_pages`` and the other single-pool attributes speak of the FIRST
+    group; a cache built without ``groups`` is one full group and behaves
+    as it always has.
     """
+
+    #: the first group's state under the names a cache of one group has
+    #: always had
+    k_pages = _of_first_group("k_pages")
+    v_pages = _of_first_group("v_pages")
+    k_scales = _of_first_group("k_scales")
+    v_scales = _of_first_group("v_scales")
+    page_table = _of_first_group("page_table")
+    page_refs = _of_first_group("page_refs")
+    num_pages = _of_first_group("num_pages")
+    max_pages_per_seq = _of_first_group("max_pages_per_seq")
+    _free = _of_first_group("_free")
 
     def __init__(self, n_layers, n_kv_heads, head_dim, num_pages,
                  page_size=16, max_seqs=8, dtype=jnp.bfloat16,
-                 max_pages_per_seq=None, quant=None, latent=False):
+                 max_pages_per_seq=None, quant=None, latent=False,
+                 groups=None):
+        if groups is None:
+            groups = [LayerGroup(n_layers, num_pages, None,
+                                 max_pages_per_seq)]
+        elif (sum(g.n_layers for g in groups) != n_layers
+              or groups[0].num_pages != num_pages):
+            raise ValueError(
+                "the groups' layers must add up to n_layers, and "
+                "num_pages is the first group's pool")
         self.n_layers = n_layers
         self.page_size = page_size
-        self.num_pages = num_pages
-        # Per-seq budget decoupled from the pool size: a serving pool is
-        # deliberately OVERSUBSCRIBED (num_pages < max_seqs * budget) so
-        # admission pressure is real and preemption has something to do.
-        self.max_pages_per_seq = (num_pages // max_seqs
-                                  if max_pages_per_seq is None
-                                  else int(max_pages_per_seq))
         self.max_seqs = max_seqs
         #: what consumers compute in — the pool storage dtype in the
         #: plain mode, the requested float dtype when the pool is int8.
@@ -459,31 +594,21 @@ class PagedKVCache:
             raise NotImplementedError(
                 "a latent pool is one row a token (n_kv_heads=1) in the "
                 "compute dtype: it has no heads to split and no int8 form")
-        shape = (n_layers, n_kv_heads, num_pages, page_size, head_dim)
-        if self.quant == "int8":
-            # int8 pages + one f32 scale per (layer, kv-head, page),
-            # kept alongside the page table: a page's scale moves,
-            # copies, and frees with the page.
-            self.k_pages = jnp.zeros(shape, jnp.int8)
-            self.v_pages = jnp.zeros(shape, jnp.int8)
-            self.k_scales = jnp.zeros((n_layers, n_kv_heads, num_pages),
-                                      jnp.float32)
-            self.v_scales = jnp.zeros((n_layers, n_kv_heads, num_pages),
-                                      jnp.float32)
-        else:
-            self.k_pages = jnp.zeros(shape, dtype)
-            self.v_pages = None if self.latent else jnp.zeros(shape, dtype)
-            self.k_scales = None
-            self.v_scales = None
-        self._free = list(range(num_pages - 1, -1, -1))
-        # page table: [max_seqs, max_pages_per_seq] int32; -1 = unset
-        # (page id 0 is valid, so 0 cannot double as the sentinel)
-        self.page_table = np.full((max_seqs, self.max_pages_per_seq),
-                                  -1, np.int32)
+        if len(groups) > 1 and (self.latent or self.quant != "none"):
+            raise NotImplementedError(
+                "a cache of several layer groups holds K and V heads in "
+                "the compute dtype: it has no latent and no int8 form")
+        # Per-seq budget decoupled from the pool size: a serving pool is
+        # deliberately OVERSUBSCRIBED (num_pages < max_seqs * budget) so
+        # admission pressure is real and preemption has something to do.
+        self.groups = [
+            _Group(g, (g.n_layers, n_kv_heads, g.num_pages, page_size,
+                       head_dim), dtype, max_seqs,
+                   (g.num_pages // max_seqs if g.pages_per_seq is None
+                    else g.pages_per_seq), self.quant, self.latent)
+            for g in groups]
         self.lengths = np.zeros((max_seqs,), np.int32)
         self._active = [False] * max_seqs
-        # per-page owner count: slots referencing it + the prefix index
-        self.page_refs = np.zeros((num_pages,), np.int32)
         self.cow_count = 0         # copy-on-write page copies performed
         # optional callable(shortfall_pages) that tries to free pages
         # (the prefix cache's LRU eviction); consulted before any
@@ -497,7 +622,11 @@ class PagedKVCache:
         """The jit-argument form of the KV pools: the bare page arrays
         in the plain mode, or ``(pages, scales)`` tuples on an int8
         pool — jit flattens the tuple, donation covers every leaf, and
-        the programs branch on the pytree form at trace time."""
+        the programs branch on the pytree form at trace time.  A cache
+        of several layer groups gives two LISTS, one entry a group."""
+        if len(self.groups) > 1:
+            return ([g.k_pages for g in self.groups],
+                    [g.v_pages for g in self.groups])
         if self.quant == "int8":
             return ((self.k_pages, self.k_scales),
                     (self.v_pages, self.v_scales))
@@ -506,7 +635,10 @@ class PagedKVCache:
     def set_pools(self, kps, vps) -> None:
         """Store a program's updated pool outputs (the form
         :meth:`pools` gave it) back on the cache."""
-        if self.quant == "int8":
+        if len(self.groups) > 1:
+            for g, kp, vp in zip(self.groups, kps, vps):
+                g.k_pages, g.v_pages = kp, vp
+        elif self.quant == "int8":
             (self.k_pages, self.k_scales), \
                 (self.v_pages, self.v_scales) = kps, vps
         else:
@@ -530,25 +662,29 @@ class PagedKVCache:
         a failed batch step) are recovered too.  A page returns to the
         free list only when its LAST owner lets go: pages shared with
         the prefix index (refcount > 1) merely drop a reference."""
-        for pid in self.page_table[seq]:
-            if pid >= 0:
-                self._deref(int(pid))
-        self.page_table[seq] = -1
+        for g in self.groups:
+            for pid in g.page_table[seq]:
+                if pid >= 0:
+                    self._deref(int(pid), g)
+            g.page_table[seq] = -1
+            g.base[seq] = 0
         self.lengths[seq] = 0
         self._active[seq] = False
 
     # -- refcounted page pool --------------------------------------------
 
-    def _pop_page(self) -> int:
-        pid = self._free.pop()
-        self.page_refs[pid] = 1
+    def _pop_page(self, g=None) -> int:
+        g = self.groups[0] if g is None else g
+        pid = g._free.pop()
+        g.page_refs[pid] = 1
         return pid
 
-    def _deref(self, pid: int) -> None:
-        self.page_refs[pid] -= 1
-        if self.page_refs[pid] == 0:
-            self._free.append(pid)
-        elif self.page_refs[pid] < 0:
+    def _deref(self, pid: int, g=None) -> None:
+        g = self.groups[0] if g is None else g
+        g.page_refs[pid] -= 1
+        if g.page_refs[pid] == 0:
+            g._free.append(pid)
+        elif g.page_refs[pid] < 0:
             raise AssertionError(
                 f"page {pid} refcount went negative (double free)")
 
@@ -567,6 +703,7 @@ class PagedKVCache:
         then begins at the first divergent token.  The final page may be
         partially covered (``n_tokens`` not page-aligned); the first
         write to it copy-on-writes."""
+        self._one_group("attach")
         n_pages = len(page_ids)
         if n_tokens > n_pages * self.page_size:
             raise ValueError(
@@ -599,6 +736,7 @@ class PagedKVCache:
                 self._cow(seq, slot)
 
     def _cow(self, seq: int, slot: int) -> None:
+        self._one_group("copy-on-write")
         _faults.fire("prefix.cow", "before")
         if not self._free:
             self._reclaim(1)
@@ -634,33 +772,44 @@ class PagedKVCache:
                 "Copy-on-write duplications of shared KV pages").inc()
         _faults.fire("prefix.cow", "after")
 
-    def _plan_missing(self, seq: int, new_len: int):
+    def _plan_missing(self, seq: int, new_len: int, g=None):
         """Slot-aware plan (-1 = unset): the list of page-table slots
-        that still need a page for ``seq`` to hold ``new_len`` tokens.
-        Idempotent across retries — already-assigned slots are never
-        re-popped."""
-        need = -(-new_len // self.page_size)
-        if need > self.max_pages_per_seq:
+        of group ``g`` (the first by default) that still need a page for
+        ``seq`` to hold ``new_len`` tokens.  Idempotent across retries —
+        already-assigned slots are never re-popped."""
+        g = self.groups[0] if g is None else g
+        need = -(-(new_len - int(g.base[seq])) // self.page_size)
+        if need > g.max_pages_per_seq:
             raise RuntimeError(
                 f"sequence {seq} needs {need} pages > per-seq budget "
-                f"{self.max_pages_per_seq}")
-        return [i for i in range(need) if self.page_table[seq, i] < 0]
+                f"{g.max_pages_per_seq}")
+        return [i for i in range(need) if g.page_table[seq, i] < 0]
+
+    def _take(self, plans) -> None:
+        """Commit ``[(group, seq, missing slots)]`` if EVERY group's free
+        list covers its part, else raise with nothing changed.
+        Prefix-cache eviction (the first group's) is tried first."""
+        for g in self.groups:
+            need = sum(len(m) for h, _, m in plans if h is g)
+            if g is self.groups[0] and need > len(g._free):
+                self._reclaim(need - len(g._free))
+            if need > len(g._free):
+                raise RuntimeError("KV page pool exhausted")
+        for g, seq, missing in plans:
+            for i in missing:
+                g.page_table[seq, i] = self._pop_page(g)
 
     def _ensure_capacity(self, seq: int, new_len: int) -> None:
-        missing = self._plan_missing(seq, new_len)
-        if len(missing) > len(self._free):
-            self._reclaim(len(missing) - len(self._free))
-        if len(missing) > len(self._free):
-            raise RuntimeError("KV page pool exhausted")
-        for i in missing:
-            self.page_table[seq, i] = self._pop_page()
+        self._take([(g, seq, self._plan_missing(seq, new_len, g))
+                    for g in self.groups])
 
     def reserve(self, seqs, extra_tokens=1) -> None:
         """Batch-atomic capacity reservation: plan every sequence's
-        missing slots first, commit only if the WHOLE batch fits (a
-        per-sequence loop would leak the earlier sequences' pages on a
-        mid-batch failure).  Prefix-cache eviction is tried before
-        giving up, so cold cached pages yield to live sequences.
+        missing slots in every layer group first, commit only if the
+        WHOLE batch fits (a per-sequence loop would leak the earlier
+        sequences' pages on a mid-batch failure).  Prefix-cache eviction
+        is tried before giving up, so cold cached pages yield to live
+        sequences.
 
         ``extra_tokens`` is one int for the whole batch or a per-seq
         sequence aligned with ``seqs`` (speculative decode reserves a
@@ -669,17 +818,43 @@ class PagedKVCache:
         extras = (list(extra_tokens)
                   if isinstance(extra_tokens, (list, tuple, np.ndarray))
                   else [extra_tokens] * len(seqs))
-        plans = [(s, self._plan_missing(
-            s, int(self.lengths[s]) + int(e)))
-            for s, e in zip(seqs, extras)]
-        need = sum(len(m) for _, m in plans)
-        if need > len(self._free):
-            self._reclaim(need - len(self._free))
-        if need > len(self._free):
-            raise RuntimeError("KV page pool exhausted")
-        for s, missing in plans:
-            for i in missing:
-                self.page_table[s, i] = self._pop_page()
+        self._take([(g, s, self._plan_missing(
+            s, int(self.lengths[s]) + int(e), g))
+            for g in self.groups for s, e in zip(seqs, extras)])
+
+    def release(self, seqs) -> int:
+        """Dereference, in every WINDOW group, the pages of ``seqs`` that
+        lie wholly behind the window — every token of them before
+        ``length + 1 - window``, which no later query sees — and shift
+        each row left so that its first entry is the oldest page kept.
+        Call it after the program that last reads those pages was
+        dispatched (``write_at`` does, for the span it wrote; a decode
+        step's executor does once the lengths have moved).  Returns the
+        pages released."""
+        ps, total = self.page_size, 0
+        for gi, g in enumerate(self.groups):
+            if g.window is None:
+                continue
+            freed = 0
+            for seq in seqs:
+                keep_from = max(0, int(self.lengths[seq]) + 1 - g.window)
+                n = (keep_from - int(g.base[seq])) // ps
+                if n <= 0:
+                    continue
+                row = g.page_table[seq]
+                for pid in row[:n]:
+                    if pid >= 0:
+                        self._deref(int(pid), g)
+                        freed += 1
+                row[:-n] = row[n:]
+                row[-n:] = -1
+                g.base[seq] += n * ps
+            if freed:
+                g.released += freed
+                _obs.instant("kv.release", cat="serve", group=gi,
+                             pages=freed)
+            total += freed
+        return total
 
     def trim(self, seq: int) -> int:
         """Release every assigned page-table slot past the page cover of
@@ -688,6 +863,7 @@ class PagedKVCache:
         rejected go back to the pool/refcount pool).  Returns the number
         of slots released.  Refcount-safe: a shared page merely drops
         this slot's reference."""
+        self._one_group("trim")
         keep = -(-int(self.lengths[seq]) // self.page_size)
         freed = 0
         for slot in range(keep, self.max_pages_per_seq):
@@ -713,32 +889,49 @@ class PagedKVCache:
         span is quantized on write (``ops.quant.kv_write``:
         scatter-max the touched pages' scales, requantize residents,
         write the new cells).  A latent pool takes its rows as ``k``
-        and ``v=None``."""
-        T = int(np.shape(k)[2])
+        and ``v=None``; a cache of several layer groups takes ``k`` and
+        ``v`` as lists, one ``[L_g, KV, T, D]`` a group, writes them all
+        in the one dispatch and then releases the pages the span left
+        wholly behind a window (:meth:`release`)."""
+        grouped = len(self.groups) > 1
+        T = int(np.shape(k[0] if grouped else k)[2])
         self._ensure_capacity(seq, start + T)
         # shared pages in the write window are read-only: COW them
         # first (no-op when nothing is shared, i.e. no prefix cache)
         self.make_writable(seq, start, start + T)
         ps = self.page_size
-        first, last = start // ps, -(-(start + T) // ps)
-        row = self.page_table[seq]
-        with _obs.span("kv.write", cat="serve", pages=last - first,
-                       dispatches=1):
+
+        def touched(g):
+            """The table entries of group ``g`` the span touches."""
+            at = start - int(g.base[seq])
+            return g.page_table[seq, at // ps: -(-(at + T) // ps)]
+
+        rows = [touched(g) for g in self.groups]
+        with _obs.span("kv.write", cat="serve",
+                       pages=sum(len(r) for r in rows), dispatches=1):
             if self.quant == "int8":
                 pos = start + np.arange(T)
-                pids, offs = row[pos // ps], (pos % ps).astype(np.int32)
+                pids = self.page_table[seq][pos // ps]
+                offs = (pos % ps).astype(np.int32)
                 _faults.fire("quant.kv_write", "before")
             else:
-                pids = np.full(((T - 1) // ps + 2,), self.num_pages,
-                               np.int32)
-                pids[:last - first] = row[first:last]
-                offs = np.int32(start % ps)
+                pids = []
+                for g, row in zip(self.groups, rows):
+                    padded = np.full(((T - 1) // ps + 2,), g.num_pages,
+                                     np.int32)
+                    padded[:len(row)] = row
+                    pids.append(padded)
+                # a window group's base is a whole page: one slot for all
+                offs = [np.int32(start % ps)] * len(pids)
+                if not grouped:
+                    pids, offs = pids[0], offs[0]
             # the donated call comes last of what can fail, and its
             # outputs replace the deleted pools in the same statement
             self.set_pools(*self.writer(*self.pools(), k, v, pids, offs))
             if self.quant == "int8":
                 _faults.fire("quant.kv_write", "after")
         self.lengths[seq] = start + T
+        self.release([seq])         # nothing, where no group has a window
 
     def write_sharded(self, seq: int, k, v, start: int,
                       n_ranks: int) -> int:
@@ -752,6 +945,7 @@ class PagedKVCache:
         ``sp.shard`` fault point, and a raise there fails ONLY the
         bracketed request (the scheduler's serve.request isolation),
         never the pool.  Returns the number of ranges written."""
+        self._one_group("write_sharded")
         T = int(np.shape(k)[2])
         if n_ranks < 1 or T % n_ranks:
             raise ValueError(
@@ -776,6 +970,7 @@ class PagedKVCache:
         ``all_gather`` of pages a range-sharded multi-host pool pays
         HERE, once, instead of every decode step gathering across the
         mesh.  Returns the number of pages covered."""
+        self._one_group("gather_shards")
         _faults.fire("sp.gather", "before")
         pages = -(-int(self.lengths[seq]) // self.page_size)
         h = _obs.handle()
@@ -788,15 +983,18 @@ class PagedKVCache:
         _faults.fire("sp.gather", "after")
         return pages
 
-    def past_pages(self, seq: int, length=None):
+    def past_pages(self, seq: int, length=None, group=0):
         """The ids of the pages that cover a sequence's first ``length``
         tokens (all it holds by default), int32 ``[n]`` on the host:
-        what a program needs to read that past out of the pools.  A
-        copy, so that a program it was handed to never sees the table's
-        later changes."""
+        what a program needs to read that past out of the pools.  In a
+        window group (``group``) the pages from the oldest one kept
+        (token ``groups[group].base[seq]``) up to ``length``.  A copy,
+        so that a program it was handed to never sees the table's later
+        changes."""
+        g = self.groups[group]
         L = int(self.lengths[seq]) if length is None else int(length)
-        n = -(-L // self.page_size)
-        row = self.page_table[seq, :n]
+        n = -(-(L - int(g.base[seq])) // self.page_size)
+        row = g.page_table[seq, :n]
         if (row < 0).any():
             # an unset (-1) slot inside the requested length used to be
             # clipped to page 0 — silently serving another sequence's
@@ -808,22 +1006,24 @@ class PagedKVCache:
                 f"({n} pages) — refusing to read garbage from page 0")
         return row.copy()
 
-    def gather_dense(self, seq: int, length=None):
+    def gather_dense(self, seq: int, length=None, group=0):
         """Gather a sequence's pages into dense [L, KV, P, D] arrays
         (P = page-multiple cover of ``length``), eagerly and on the
         pool's device: the past-KV operand of the sequence-parallel
         chunk program and of the hybrid executor's, and what the cluster
         hand-off ships (the Llama-shaped chunk program reads its past
         inside the program, :func:`_past_of`).  Positions >= length are
-        garbage and must be masked by the consumer."""
-        row = self.past_pages(seq, length)
+        garbage and must be masked by the consumer.  Of a window group
+        (``group``) the tokens from ``groups[group].base[seq]`` on."""
+        g = self.groups[group]
+        row = self.past_pages(seq, length, group)
         n = len(row)
         pids = jnp.asarray(row)
-        k = self.k_pages[:, :, pids]          # [L, KV, n, ps, D]
+        k = g.k_pages[:, :, pids]             # [L, KV, n, ps, D]
         if self.latent:
             return k.reshape(k.shape[0], 1, n * self.page_size,
                              k.shape[4]), None
-        v = self.v_pages[:, :, pids]
+        v = g.v_pages[:, :, pids]
         if self.quant == "int8":
             _faults.fire("quant.dequant", "before")
             k = _quant.kv_dequant(k, self.k_scales[:, :, pids],
@@ -833,6 +1033,14 @@ class PagedKVCache:
             _faults.fire("quant.dequant", "after")
         sh = (k.shape[0], k.shape[1], n * self.page_size, k.shape[4])
         return k.reshape(sh), v.reshape(sh)
+
+    def _one_group(self, what):
+        if len(self.groups) > 1:
+            raise NotImplementedError(
+                f"PagedKVCache.{what}: a cache of several layer groups "
+                f"releases the pages behind a window, so nothing attaches "
+                f"pages by reference, rolls a length back or reads K and V "
+                f"of every token through it")
 
     def _heads_only(self, what):
         if self.latent:
@@ -847,7 +1055,17 @@ class PagedKVCache:
 
     @property
     def free_slots(self) -> int:
-        return self._active.count(False)
+        """Sequence slots that can still be claimed.  A WINDOW group
+        bounds them too: a sequence holds at most ``max_pages_per_seq``
+        of its pages whatever its length, so its pool seats ``num_pages
+        // max_pages_per_seq`` sequences and no more are let in — a live
+        sequence then never finds that pool exhausted."""
+        free = self._active.count(False)
+        for g in self.groups:
+            if g.window is not None:
+                free = min(free, g.num_pages // g.max_pages_per_seq
+                           - self._active.count(True))
+        return max(free, 0)
 
     def append(self, seqs, k, v) -> None:
         """Decode-step write: one new token per listed sequence.
@@ -858,6 +1076,7 @@ class PagedKVCache:
         fits (otherwise an earlier seq would record a length whose
         page slot never got written)."""
         self._heads_only("append")
+        self._one_group("append")
         ps = self.page_size
         self.reserve(seqs, extra_tokens=1)  # batch-atomic
         for s in seqs:
@@ -890,6 +1109,7 @@ class PagedKVCache:
         """Decode attention for one layer: q [B, H, D] over the listed
         sequences' pages."""
         self._heads_only("attend")
+        self._one_group("attend")
         # clip -1 sentinels (unassigned slots beyond each length) to a
         # valid page id — the length mask excludes them from attention,
         # but gathers/kernel prefetch must stay in range

@@ -645,6 +645,12 @@ class ServingCluster:
             raise NotImplementedError(
                 "ServingCluster: cluster hand-off not supported for a "
                 "model with latent attention (MLA) layers")
+        if "sliding_attention" in kinds:
+            # the hand-off ships every token's K and V; the pages behind
+            # a window were released
+            raise NotImplementedError(
+                "ServingCluster: cluster hand-off not supported for a "
+                "model that mixes sliding-window and full attention layers")
         self.model = model
         self.disaggregated = bool(disaggregated)
         self._engine_kwargs = dict(engine_kwargs)

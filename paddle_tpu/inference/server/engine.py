@@ -27,6 +27,7 @@ from .request import Request, RequestHandle, RequestState
 from .scheduler import Scheduler
 from .spec_decode import SpecDecode, spec_mode
 from .wal import resolve_wal, wal_enabled
+from .window_executor import WindowExecutor
 
 
 def _prefix_cache_enabled() -> bool:
@@ -55,6 +56,14 @@ _LATENT = ("a model with latent attention (MLA) layers: its pages hold "
            "one compressed row a token and no K or V heads, which the "
            "prefix index, the verify, decode_n, sequence-parallel, int8, "
            "AOT and recovery programs of the paged executor do not read")
+
+
+_WINDOWED = ("a model that mixes sliding-window and full attention layers: "
+             "its cache releases the pages behind the window, so a page "
+             "cannot be attached by the prefix index, rolled back after a "
+             "verify, handed over or replayed, and its two pools are not "
+             "what the decode_n, sequence-parallel, int8 and AOT programs "
+             "of the paged executor read")
 
 
 def _refuse(wanted: dict, why: str) -> None:
@@ -87,12 +96,14 @@ class ServingEngine:
         # the programs follow the model's layer kinds: a model with
         # state-space layers gets the hybrid executor (a recurrent-state
         # cache beside the paged KV pool), one with latent attention
-        # layers the latent executor (a latent page pool), behind the
-        # same slot interface
+        # layers the latent executor (a latent page pool), one that mixes
+        # sliding-window and full attention layers the window executor
+        # (a cache of two layer groups), behind the same slot interface
         kinds = set(getattr(model.config, "layer_types", ()))
         recurrent = "mamba" in kinds
         latent = bool(kinds & {"mla_dense", "mla_moe"})
-        if recurrent or latent:
+        windowed = "sliding_attention" in kinds
+        if recurrent or latent or windowed:
             from paddle_tpu.core import aot as _aot
             from paddle_tpu.ops import quant as _quant
 
@@ -113,11 +124,14 @@ class ServingEngine:
                                 else aot) != "off",
                 "write-ahead log": (wal_enabled() if wal is None
                                     else wal is not False),
-            }, _RECURRENT if recurrent else _LATENT)
-            self.executor = (HybridExecutor if recurrent
-                             else LatentExecutor)(
+            }, _RECURRENT if recurrent else _LATENT if latent else _WINDOWED)
+            # a window row holds the window and one chunk: its executor
+            # is told the chunk
+            more = {"prefill_chunk": prefill_chunk} if windowed else {}
+            self.executor = (HybridExecutor if recurrent else LatentExecutor
+                             if latent else WindowExecutor)(
                 model, max_seqs=max_seqs, page_size=page_size,
-                max_len=max_len, dtype=dtype, num_pages=num_pages)
+                max_len=max_len, dtype=dtype, num_pages=num_pages, **more)
         else:
             self.executor = PagedExecutor(
                 model, max_seqs=max_seqs, page_size=page_size,

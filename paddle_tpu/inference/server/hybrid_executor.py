@@ -192,6 +192,24 @@ class SlotExecutor:
         """A whole prompt is a chunk that starts at 0 and is final."""
         return self.prefill_chunk(sid, prompt_ids, 0, True)
 
+    def _count_experts(self, counts):
+        """One decode step's expert counter (int32 ``[expert layers,
+        held]``, what the decode program of a model with routed experts
+        returns beside its tokens) into the executor's running sums
+        ``expert_rows``, ``expert_steps``, ``experts_hit`` and
+        ``expert_max_over_mean``, and the step's ``moe.load`` instant."""
+        if not counts.size:
+            return
+        self.expert_rows += counts
+        self.expert_steps += 1
+        hit = int((counts > 0).sum())
+        self.experts_hit += hit
+        mean = counts.mean(axis=1)
+        ratio = float(np.mean(counts.max(axis=1) / np.maximum(mean, 1e-9)))
+        self.expert_max_over_mean += ratio
+        obs.instant("moe.load", cat="serve", max=int(counts.max()),
+                    mean=float(counts.mean()), hit=hit)
+
 
 class HybridExecutor(SlotExecutor):
     def __init__(self, model, max_seqs=4, page_size=16, max_len=256,

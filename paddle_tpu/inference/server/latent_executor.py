@@ -193,7 +193,7 @@ class LatentExecutor(SlotExecutor):
                 out = out[None]
             else:
                 # the experts stay whole outside the scan and each layer
-                # addresses its own in place (mla_moe._layer_of)
+                # addresses its own in place (models/moe.py, _layer_of)
                 big = {k: v for k, v in lp.items() if k in _EXPERT_LEAVES}
                 small = {k: v for k, v in lp.items() if k not in big}
 
@@ -344,18 +344,3 @@ class LatentExecutor(SlotExecutor):
         for s in sids:
             out[s] = self.last_token[s] = int(toks[s])
         return out
-
-    def _count_experts(self, counts):
-        """One decode step's expert counter into the running sums, and the
-        step's ``moe.load`` instant."""
-        if not counts.size:
-            return
-        self.expert_rows += counts
-        self.expert_steps += 1
-        hit = int((counts > 0).sum())
-        self.experts_hit += hit
-        mean = counts.mean(axis=1)
-        ratio = float(np.mean(counts.max(axis=1) / np.maximum(mean, 1e-9)))
-        self.expert_max_over_mean += ratio
-        obs.instant("moe.load", cat="serve", max=int(counts.max()),
-                    mean=float(counts.mean()), hit=hit)

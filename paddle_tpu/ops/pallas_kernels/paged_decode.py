@@ -15,10 +15,14 @@ pages, pages a block)`` of them by the prefetched length:
     start the DMAs of block j + 1's live pages   (the other VMEM slot)
     wait for block j's
     s      = q @ K_block^T * scale       [KV, group, 256]  float32
-    s      masked to col < length
+    s      masked to first <= col < length
     m, l, acc  <- online softmax over the blocks, acc += p @ V_block
 
-and divides once at the end.  A page is ONE strided DMA for K and one
+and divides once at the end.  On a WINDOW layer the walk begins at the
+block of the sequence's first visible key (``starts``) and the table's
+first entry stands for token ``bases`` (the pages behind the window were
+released and the table shifted): one kernel for both kinds of layer, a
+full layer passing zeros for both.  A page is ONE strided DMA for K and one
 for V that takes all its KV heads, ``pool[layer, :, page]`` =
 ``[KV, page_size, head_dim]``, into one of two VMEM slots of one block,
 so a block's copies fly while the block before it is multiplied.  What
@@ -62,6 +66,8 @@ Layout contract (matches PagedKVCache):
   layer        int32 scalar       the layer this call attends over
   lengths      [B]   int32        valid tokens per sequence
   page_indices [B, pps] int32     each sequence's block-table window
+  starts       [B]   int32        first visible key (0: a full layer)
+  bases        [B]   int32        token the table's first entry stands for
 returns        [B, KV, G, D]
 
 The layer is a prefetched scalar beside the lengths and the page table,
@@ -102,8 +108,9 @@ def block_pages(page_size, kv_heads, head_dim, itemsize):
     return max(1, keys // page_size)
 
 
-def _kernel(len_ref, tbl_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf,
-            v_buf, sem, *, page_size, pages_per_seq, scale):
+def _kernel(len_ref, tbl_ref, layer_ref, start_ref, base_ref, q_ref, k_hbm,
+            v_hbm, o_ref, k_buf, v_buf, sem, *, page_size, pages_per_seq,
+            scale):
     # Scalars are explicitly i32 and combined by lax ops: the repo's
     # global x64 mode turns weak Python-int constants into i64 at
     # lowering, which Mosaic refuses; and an engine traces this body once
@@ -114,10 +121,21 @@ def _kernel(len_ref, tbl_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf,
     layer = layer_ref[0]
     zero, one, two = i32(0), i32(1), i32(2)
     ps, bp = i32(page_size), i32(k_buf.shape[2] // page_size)
-    # a verify window's last rows may name a length past the table
-    length = lax.min(len_ref[b], i32(pages_per_seq * page_size))
+    # keys are counted from the table's first entry, which stands for
+    # token ``base`` (0 on a full layer; a window layer's table begins
+    # where its released pages end): the sequence reads keys
+    # [first, length).  A verify window's last rows may name a length
+    # past the table
+    base = base_ref[b]
+    length = lax.min(lax.sub(len_ref[b], base),
+                     i32(pages_per_seq * page_size))
+    first = lax.max(lax.sub(start_ref[b], base), zero)
     npages = lax.div(lax.add(length, lax.sub(ps, one)), ps)
     nblocks = lax.div(lax.add(npages, lax.sub(bp, one)), bp)
+    page0 = lax.div(first, ps)                   # the first visible page
+    block0 = lax.div(page0, bp)                  # and its block
+    # nothing visible (a padded row, or a start at the length): no trip
+    nblocks = lax.select(lax.lt(first, length), nblocks, block0)
 
     q = q_ref[0]                                         # [KV, G, D]
     KV, G, D = q.shape
@@ -161,17 +179,21 @@ def _kernel(len_ref, tbl_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf,
 
     def block(j, carry):
         m, l, acc = carry
-        first = lax.mul(j, bp)                   # the block's pages:
-        ahead = lax.add(first, bp)               # [first, ahead)
+        here = lax.mul(j, bp)                    # the block's pages:
+        ahead = lax.add(here, bp)                # [here, ahead)
         live = lax.min(npages, ahead)            # those with keys end here
+        # the first trip's block begins at the first visible page
+        seen0 = lax.select(lax.eq(j, block0), page0, here)
         # start the NEXT block's live pages (in the first trip this
         # block's too), so its copies fly while this block is multiplied
-        lax.fori_loop(lax.select(lax.eq(j, zero), zero, ahead),
+        lax.fori_loop(lax.select(lax.eq(j, block0), page0, ahead),
                       lax.min(npages, lax.add(ahead, bp)), start, 0)
-        lax.fori_loop(first, live, wait, 0)      # wait for this block's
-        # zero V's rows past the live pages (the last block's):
-        # VMEM scratch holds what an earlier program left, and a NaN bit
-        # pattern in V would poison p @ V even at p == 0
+        lax.fori_loop(seen0, live, wait, 0)      # wait for this block's
+        # zero V's rows before the first visible page (the first block's)
+        # and past the live pages (the last block's): VMEM scratch holds
+        # what an earlier program left, and a NaN bit pattern in V would
+        # poison p @ V even at p == 0
+        lax.fori_loop(here, seen0, scrub, 0)
         lax.fori_loop(live, ahead, scrub, 0)
 
         slot = lax.rem(j, two)
@@ -179,7 +201,9 @@ def _kernel(len_ref, tbl_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf,
         v = v_buf[slot].astype(operand)
         s = lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
                             preferred_element_type=jnp.float32)
-        seen = cols < lax.sub(length, lax.mul(first, ps))
+        at = lax.mul(here, ps)                   # the block's first key
+        seen = jnp.logical_and(cols >= lax.sub(first, at),
+                               cols < lax.sub(length, at))
         s = lax.select(seen, s * jnp.float32(scale), masked)
         m_new = jnp.maximum(m, jnp.max(s, axis=2, keepdims=True))
         alpha = jnp.exp(m - m_new)
@@ -193,7 +217,7 @@ def _kernel(len_ref, tbl_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf,
     m0 = jnp.full((KV, G, 1), -1e30, jnp.float32)
     l0 = jnp.zeros((KV, G, 1), jnp.float32)
     acc0 = jnp.zeros((KV, G, D), jnp.float32)
-    _, l, acc = lax.fori_loop(zero, nblocks, block, (m0, l0, acc0))
+    _, l, acc = lax.fori_loop(block0, nblocks, block, (m0, l0, acc0))
     # a row of length 0 (a padded batch row) read nothing: zeros
     o_ref[0] = (acc / jnp.maximum(l, jnp.float32(1e-30))) \
         .astype(o_ref.dtype)
@@ -204,7 +228,8 @@ def _interpret():
 
 
 @functools.partial(jax.jit, static_argnames=("scale",))
-def _call(q, k_pages, v_pages, lengths, page_indices, layer, scale):
+def _call(q, k_pages, v_pages, lengths, page_indices, layer, starts, bases,
+          scale):
     """The jitted wrapper: the device trace names the kernel's event
     ``_call [tpu_custom_call]`` after it (the benchmark's
     ``paged_decode_roofline`` matches that name)."""
@@ -215,16 +240,16 @@ def _call(q, k_pages, v_pages, lengths, page_indices, layer, scale):
     kernel = functools.partial(_kernel, page_size=ps, pages_per_seq=pps,
                                scale=scale)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,          # lengths + page table + layer
+        # lengths + page table + layer + first visible keys + table bases
+        num_scalar_prefetch=5,
         grid=(B,),
         in_specs=[
-            pl.BlockSpec((1, KV, G, D),
-                         lambda b, lens, tbl, layer: (b, 0, 0, 0)),
+            pl.BlockSpec((1, KV, G, D), lambda b, *scalars: (b, 0, 0, 0)),
             pl.BlockSpec(memory_space=pl.ANY),   # K pool stays in HBM
             pl.BlockSpec(memory_space=pl.ANY),   # V pool stays in HBM
         ],
         out_specs=pl.BlockSpec((1, KV, G, D),
-                               lambda b, lens, tbl, layer: (b, 0, 0, 0)),
+                               lambda b, *scalars: (b, 0, 0, 0)),
         scratch_shapes=[                # two slots of one block each
             pltpu.VMEM((2, KV, block, D), k_pages.dtype),
             pltpu.VMEM((2, KV, block, D), v_pages.dtype),
@@ -241,11 +266,13 @@ def _call(q, k_pages, v_pages, lengths, page_indices, layer, scale):
             interpret=_interpret(),
         )(jnp.asarray(lengths, jnp.int32),
           jnp.asarray(page_indices, jnp.int32),
-          jnp.asarray(layer, jnp.int32).reshape(1), q, k_pages, v_pages)
+          jnp.asarray(layer, jnp.int32).reshape(1),
+          jnp.asarray(starts, jnp.int32), jnp.asarray(bases, jnp.int32),
+          q, k_pages, v_pages)
 
 
 def paged_decode(q, k_pages, v_pages, lengths, page_indices, layer=None,
-                 scale=None):
+                 starts=None, bases=None, scale=None):
     """Fused paged-decode attention over the page pool.
 
     q [B, H, D] (H % KV == 0); lengths [B]; page_indices [B, pps];
@@ -254,6 +281,15 @@ def paged_decode(q, k_pages, v_pages, lengths, page_indices, layer=None,
     one layer's pool ``[KV, P, ps, D]`` with no ``layer``.  Returns
     [B, H, D].  Pure function of its arguments (no custom VJP: decode is
     inference-only).
+
+    A WINDOW layer names, per sequence, ``starts`` [B], the first key its
+    query may see (``max(0, p + 1 - w)`` for the token at position ``p``)
+    and ``bases`` [B], the token its table's first entry stands for (a
+    multiple of the page size; the pages before it were released):
+    ``lengths`` and ``starts`` count tokens of the sequence, the table
+    covers tokens ``bases ..``.  The block loop begins at the first
+    visible key's block and masks the keys before it.  Both default to
+    zeros: a full layer, every key from token 0.
     """
     B, H, D = q.shape
     if k_pages.ndim == 4:
@@ -268,8 +304,10 @@ def paged_decode(q, k_pages, v_pages, lengths, page_indices, layer=None,
     if scale is None:
         scale = 1.0 / (D ** 0.5)
     qg = q.reshape(B, KV, H // KV, D)
+    zeros = jnp.zeros((B,), jnp.int32)
     out = _call(qg, k_pages, v_pages, lengths, page_indices, layer,
-                float(scale))
+                zeros if starts is None else starts,
+                zeros if bases is None else bases, float(scale))
     return out.reshape(B, H, D)
 
 
@@ -442,7 +480,8 @@ def supported_quant(head_dim, page_size, on_tpu):
 
 
 def paged_decode_spmd_rule(mesh, q_spec, k_spec, v_spec, len_spec,
-                           tbl_spec, layer_spec=None):
+                           tbl_spec, layer_spec=None, start_spec=None,
+                           base_spec=None):
     """SPMD rule: shard the batch dim (the grid — programs are
     independent per sequence) and/or the head dim (a program takes
     whatever KV heads its shard holds — the pools' KV axis must carry

@@ -203,7 +203,8 @@ def test_the_decode_kernel_compiles_at_a_window_of_8192_tokens(
     def args(pages):
         pool = sds((8, 8, 32 * pages, 16, 128))
         return (sds((32, 8, 4, 128)), pool, pool, sds((32,), i32),
-                sds((32, pages), i32), sds((), i32))
+                sds((32, pages), i32), sds((), i32), sds((32,), i32),
+                sds((32,), i32))
 
     def scratch(pages):
         text = str(paged_decode._call.trace(*args(pages), scale=0.088).jaxpr)
@@ -479,3 +480,110 @@ def test_the_latent_page_writer_moves_no_pool(sds):
         r"= bf16\[(5,)?(1,)?(4096|20480),128,\d+\]\S* "
         r"(copy|transpose|fusion)\(", line)]
     assert len(made) == 1 and "/scatter" in made[0]
+
+
+# -- trinity-mini-serve: a cache of two layer groups ---------------------------
+
+# the full group: 1 layer x 4 KV heads x 64 x 112 pages x 128 x 128 (1.88 GB
+# for K and V); the window group: 5 layers x 64 x 25 pages (2.10 GB)
+FULL_POOL, WINDOW_POOL = (1, 4, 7168, 128, 128), (5, 4, 1600, 128, 128)
+GROUP_POOL_MOVED = re.compile(
+    r"= bf16\[(1,|5,)?(4,)?(7168|1600|28672|32000),128,128\]\S* "
+    r"(copy|transpose|fusion)\(")
+EXPERTS_MOVED = re.compile(
+    r"= bf16\[(1,)?128,(2048,2048|1024,2048)\]\S* "
+    r"(copy|transpose|dynamic-slice)\(")
+
+
+def window_executor(sds):
+    """trinity-mini-serve's executor without its arrays (the published
+    widths, s s | s f s s, all 128 experts held, the whole vocabulary), and
+    the shapes of its parameters."""
+    from paddle_tpu.inference.server.window_executor import WindowExecutor
+    from paddle_tpu.models import window_moe as wm
+
+    cfg = wm.WindowMoEConfig(num_hidden_layers=6, dtype="bfloat16")
+    kinds = cfg.layer_types
+    assert kinds == (wm.SLIDING,) * 3 + (wm.FULL,) + (wm.SLIDING,) * 2
+    ex = object.__new__(WindowExecutor)
+    ex.config, ex.held = cfg, tuple(range(128))
+    ex.slot_of = [(int(k == wm.SLIDING), kinds[:n].count(k))
+                  for n, k in enumerate(kinds)]
+    ex.cache = types.SimpleNamespace(page_size=128)
+    params = tuple({name: sds(shape) for name, (shape, _) in
+                    wm._layer_shapes(cfg, cfg.is_dense(n), 128).items()}
+                   for n in range(6))
+    tops = {"embed": sds((200192, 2048)), "norm_w": sds((2048,)),
+            "lm_head": sds((2048, 200192))}
+    return ex, params, tops
+
+
+def test_the_window_decode_step_moves_no_pool(sds, compiled_kernel):
+    """serve.window_decode at the cell's size (64 slots, 112 and 25 pages
+    a sequence): the four pools of both layer groups aliased to the
+    outputs, the one kernel in the program six times (once a layer, the
+    windowed start an argument), no pool or layer of one copied, re-laid
+    or produced by anything but the token's in-place scatters (K and V of
+    six layers), the temporaries a few MB, and 12.59 GB of arguments: the
+    weights and the pools, nothing twice; no expert leaf copied."""
+    ex, params, tops = window_executor(sds)
+    i32 = jnp.int32
+    pools = [sds(FULL_POOL), sds(WINDOW_POOL)]
+    exe = jax.jit(ex._decode_fwd, donate_argnums=(5, 6)).lower(
+        params, tops, sds((64,), i32), sds((64,), i32),
+        sds((64,), jnp.bool_), pools, pools,
+        [sds((64, 112), i32), sds((64, 25), i32)], sds((64,), i32)).compile()
+    text, mem = exe.as_text(), exe.memory_analysis()
+    assert re.search(r"input_output_alias=\{ " + ", ".join(
+        rf"\{{{i}\}}: \(\d+, \{{\}}, may-alias\)" for i in (1, 2, 3, 4))
+        + r" \}", text[:text.index("\n")])
+    assert mem.alias_size_in_bytes == 3_976_200_192 == 2 * 4 * 128 * 128 \
+        * 2 * (7168 + 5 * 1600)
+    assert mem.temp_size_in_bytes < 64 << 20
+    assert 12.58e9 < mem.argument_size_in_bytes < 12.60e9
+    assert text.count("tpu_custom_call") == 6
+    made = [line for line in text.splitlines()
+            if GROUP_POOL_MOVED.search(line)]
+    assert len(made) == 12
+    assert all("/scatter\"" in line and " fusion(" in line for line in made)
+    assert not EXPERTS_MOVED.search(text)
+
+
+@pytest.mark.parametrize("pages", [0, 88])
+def test_the_window_chunk_reads_its_past_and_moves_no_pool(sds, pages):
+    """serve.window_chunk at a chunk of 1,024 tokens, first and last of a
+    12,288-token prompt: both groups' pools are read by page id inside
+    the program (the full layer's 88 pages, a sliding layer's 16) and
+    never copied or written; no expert leaf is copied out for the loop
+    over 128 experts; the temporaries stay under 512 MB (a block of
+    heads' scores, one expert's hidden rows)."""
+    ex, params, tops = window_executor(sds)
+    i32 = jnp.int32
+    pools = [sds(FULL_POOL), sds(WINDOW_POOL)]
+    exe = jax.jit(ex._chunk_fwd).lower(
+        params, tops, sds((1024,), i32), sds((), i32), pools, pools,
+        [sds((pages,), i32), sds((min(pages, 16),), i32)],
+        sds((), i32)).compile()
+    text, mem = exe.as_text(), exe.memory_analysis()
+    assert not GROUP_POOL_MOVED.search(text)
+    assert mem.alias_size_in_bytes == 0
+    assert mem.temp_size_in_bytes < 512 << 20
+    assert not EXPERTS_MOVED.search(text)
+    assert "tpu_custom_call" not in text        # stock attention (PERF.md)
+
+
+def test_the_window_page_writer_moves_no_pool(sds):
+    """serve.kv_write on both groups' pools at the cell's chunk of 1,024
+    tokens: ONE program, the four pools aliased to the outputs, no
+    temporary worth the name and no pool re-laid."""
+    n = (1024 - 1) // 128 + 2
+    pools = [sds(FULL_POOL), sds(WINDOW_POOL)]
+    span = [sds((1, 4, 1024, 128)), sds((5, 4, 1024, 128))]
+    exe = jax.jit(_write_span, donate_argnums=(0, 1)).lower(
+        pools, pools, span, span, [sds((n,), jnp.int32)] * 2,
+        [sds((), jnp.int32)] * 2).compile()
+    text, mem = exe.as_text(), exe.memory_analysis()
+    assert mem.alias_size_in_bytes == 3_976_200_192
+    assert mem.temp_size_in_bytes < 64 << 20
+    assert not re.search(
+        r"= bf16\[(1,|5,)4,(7168|1600),128,128\]\S* (copy|transpose)\(", text)

@@ -30,6 +30,7 @@ from paddle_tpu.inference.paged import PagedKVCache
 from paddle_tpu.inference.server import ServingCluster, ServingEngine
 from paddle_tpu.inference.server.latent_executor import LatentExecutor
 from paddle_tpu.models import mla_moe as mm
+from paddle_tpu.models import moe
 from paddle_tpu.models.mla_moe import MLAMoEConfig, MLAMoEForCausalLM
 from paddle_tpu.ops.pallas_kernels import mla_decode
 
@@ -259,7 +260,7 @@ def test_a_loop_over_experts_equals_the_batched_product(model, monkeypatch):
     x = jax.random.normal(jax.random.PRNGKey(3), (19, 64))
     batched, took = mm.feed_forward(cfg, "mla_moe", lp, x,
                                     model.held_experts)
-    monkeypatch.setattr(mm, "_BATCHED_EXPERT_ROWS", 0)
+    monkeypatch.setattr(moe, "_BATCHED_EXPERT_ROWS", 0)
     looped, took2 = mm.feed_forward(cfg, "mla_moe", lp, x,
                                     model.held_experts)
     assert rel(looped, batched) < 1e-6
@@ -268,7 +269,7 @@ def test_a_loop_over_experts_equals_the_batched_product(model, monkeypatch):
     x24 = jax.random.normal(jax.random.PRNGKey(5), (24, 64))
     whole24, took24 = mm.feed_forward(cfg, "mla_moe", lp, x24,
                                       model.held_experts)
-    monkeypatch.setattr(mm, "_EXPERT_BLOCK", 8)
+    monkeypatch.setattr(moe, "_EXPERT_BLOCK", 8)
     blocked, _ = mm.feed_forward(cfg, "mla_moe", lp, x24,
                                  model.held_experts)
     assert rel(blocked, whole24) < 1e-6

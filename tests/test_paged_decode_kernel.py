@@ -311,3 +311,117 @@ def test_the_scratch_does_not_grow_with_the_window(dtype):
     name = "f32" if dtype is np.float32 else "bf16"
     assert scratch(512) == scratch(16)
     assert scratch(16) == [(name, f"2,2,{BLOCK},16")]    # [slot, KV, keys, D]
+
+
+# -- the windowed start (PR 34) ----------------------------------------------
+#
+# A window layer reads keys [start, length) through a table whose first
+# entry stands for token ``base``: the walk begins at the start's block.
+
+def _window_oracle(q, k_pages, v_pages, lens, table, starts, bases):
+    """Dense masked attention in numpy float64: row b reads the keys of
+    tokens ``starts[b] .. lens[b] - 1``, token t at table position
+    ``t - bases[b]``."""
+    B, H, D = q.shape
+    KV, _, ps, _ = k_pages.shape
+    T = table.shape[1] * ps
+    out = np.zeros((B, H, D), np.float32)
+    for b in range(B):
+        kc = k_pages[:, table[b]].reshape(KV, T, D).astype(np.float64)
+        vc = v_pages[:, table[b]].reshape(KV, T, D).astype(np.float64)
+        lo, hi = starts[b] - bases[b], lens[b] - bases[b]
+        if hi <= lo:
+            continue
+        for h in range(H):
+            kv = h // (H // KV)
+            lg = (q[b, h].astype(np.float64) @ kc[kv, lo:hi].T) / np.sqrt(D)
+            p = np.exp(lg - lg.max())
+            out[b, h] = (p / p.sum()) @ vc[kv, lo:hi]
+    return out
+
+
+def _run_window(q, kp, vp, lens, table, starts, bases):
+    return np.asarray(paged_decode(
+        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp), lens, table,
+        starts=jnp.asarray(starts, jnp.int32),
+        bases=jnp.asarray(bases, jnp.int32)), np.float32)
+
+
+WINDOW_STARTS = {
+    "zero": 0, "mid-page": 37, "page-aligned": 64, "block-aligned": BLOCK,
+    "past-a-block": BLOCK + 70, "last-key": None}
+
+
+@pytest.mark.parametrize("ps", [16, 64])
+@pytest.mark.parametrize("which", sorted(WINDOW_STARTS))
+def test_a_window_starts_at_its_first_visible_key(ps, which):
+    """Starts at 0, inside a page, on a page's edge, on a block's edge and
+    past one, and at the last key: the keys before the start are masked
+    whatever the table's first pages hold (NaN here, as a released page
+    another sequence took over may)."""
+    rng = np.random.RandomState(11)
+    pps = 3 * BLOCK // ps
+    q, kp, vp, table = _mk(rng, B=3, H=4, KV=2, D=16, P=4 * pps, ps=ps,
+                           pps=pps)
+    lens = np.array([3 * BLOCK, 2 * BLOCK + 5, BLOCK + 71], np.int32)
+    start = WINDOW_STARTS[which]
+    starts = (lens - 1 if start is None
+              else np.minimum(start, lens - 1)).astype(np.int32)
+    bases = np.zeros(3, np.int32)
+    want = _window_oracle(q, kp, vp, lens, table, starts, bases)
+    # what lies wholly before the first visible page is never read
+    for b in range(3):
+        dead = table[b, :starts[b] // ps]
+        kp[:, dead] = np.nan
+        vp[:, dead] = np.nan
+    got = _run_window(q, kp, vp, lens, table, starts, bases)
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("ps", [16, 64])
+def test_a_shifted_table_stands_for_its_base(ps):
+    """The table's first entry stands for token ``base``: the same keys
+    read through a table shifted left by the released pages give the same
+    output as through the unshifted one."""
+    rng = np.random.RandomState(12)
+    pps = 3 * BLOCK // ps
+    q, kp, vp, table = _mk(rng, B=3, H=8, KV=2, D=16, P=4 * pps, ps=ps,
+                           pps=pps)
+    lens = np.array([3 * BLOCK - 3, 2 * BLOCK + 5, 300], np.int32)
+    starts = np.array([BLOCK + 130, BLOCK - 1, 0], np.int32)
+    whole = _run_window(q, kp, vp, lens, table, starts, np.zeros(3, np.int32))
+    shifted, bases = np.zeros_like(table), starts // ps * ps
+    for b in range(3):
+        n = bases[b] // ps
+        shifted[b, :pps - n] = table[b, n:]
+    got = _run_window(q, kp, vp, lens, shifted, starts, bases)
+    np.testing.assert_allclose(got, whole, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(
+        whole, _window_oracle(q, kp, vp, lens, table, starts,
+                              np.zeros(3, np.int32)), rtol=2e-4, atol=2e-4)
+
+
+def test_zeros_for_starts_and_bases_are_the_full_layer():
+    rng = np.random.RandomState(13)
+    q, kp, vp, table = _mk(rng, B=2, H=4, KV=2, D=16, P=40, ps=16, pps=20)
+    lens = np.array([301, 17], np.int32)
+    zeros = np.zeros(2, np.int32)
+    assert np.array_equal(_run(q, kp, vp, lens, table),
+                          _run_window(q, kp, vp, lens, table, zeros, zeros))
+
+
+def test_the_dense_fallback_masks_the_same_window():
+    from paddle_tpu.inference.paged import _dense_pool_attention
+
+    rng = np.random.RandomState(14)
+    q, kp, vp, table = _mk(rng, B=2, H=4, KV=2, D=16, P=40, ps=16, pps=8)
+    lens = np.array([200, 150], np.int32)
+    starts, bases = np.array([140, 75], np.int32), np.array([128, 64],
+                                                            np.int32)
+    got = _dense_pool_attention(
+        jnp.asarray(q), jnp.asarray(kp)[None], jnp.asarray(vp)[None],
+        jnp.asarray(lens), jnp.asarray(table), 0, jnp.asarray(starts),
+        jnp.asarray(bases))
+    np.testing.assert_allclose(
+        np.asarray(got), _window_oracle(q, kp, vp, lens, table, starts,
+                                        bases), rtol=2e-4, atol=2e-4)
