@@ -77,16 +77,18 @@ def masked_multihead_attention(q, k_cache, v_cache, lengths, name=None):
     return out if wrap else out._data
 
 
-def _window_attention(q, kc, vc, lengths, starts=None):
+def _window_attention(q, kc, vc, lengths, starts=None, scale=None):
     """Decode attention over gathered windows, float32: q [B, H, D];
     kc/vc [B, KV, T, D], each sequence's window laid dense; lengths [B]
-    keys each query reads, from ``starts`` [B] on where given."""
+    keys each query reads, from ``starts`` [B] on where given; the scores
+    times ``scale`` (``1 / sqrt(D)`` unless given)."""
     B, H, D = q.shape
     KV, T = kc.shape[1], kc.shape[2]
     g = H // KV
     qg = q.reshape(B, KV, g, D)
     logits = jnp.einsum("bkgd,bktd->bkgt", qg.astype(jnp.float32),
-                        kc.astype(jnp.float32)) / np.sqrt(D)
+                        kc.astype(jnp.float32))
+    logits = logits / np.sqrt(D) if scale is None else logits * scale
     mask = jnp.arange(T)[None, None, None, :] < \
         lengths[:, None, None, None]
     if starts is not None:
@@ -98,7 +100,8 @@ def _window_attention(q, kc, vc, lengths, starts=None):
     return out.reshape(B, H, D).astype(q.dtype)
 
 
-def _dense_paged_attention(q, k_pages, v_pages, lengths, page_indices):
+def _dense_paged_attention(q, k_pages, v_pages, lengths, page_indices,
+                           scale=None):
     """Reference semantics of the Pallas kernel, in plain XLA ops —
     the off-TPU fallback and the parity oracle for tests.
 
@@ -111,11 +114,11 @@ def _dense_paged_attention(q, k_pages, v_pages, lengths, page_indices):
     kc = jnp.swapaxes(k_pages[:, page_indices], 0, 1)  # [B, KV, pps, ps, D]
     vc = jnp.swapaxes(v_pages[:, page_indices], 0, 1)
     return _window_attention(q, kc.reshape(B, KV, T, D),
-                             vc.reshape(B, KV, T, D), lengths)
+                             vc.reshape(B, KV, T, D), lengths, scale=scale)
 
 
 def _dense_pool_attention(q, k_pool, v_pool, lengths, page_indices, layer,
-                          starts=None, bases=None):
+                          starts=None, bases=None, scale=None):
     """:func:`_dense_paged_attention` over layer ``layer`` (an int32
     scalar, traced or not) of the whole pools ``[L, KV, P, ps, D]``: each
     sequence's window is gathered by row from the pool viewed flat, so no
@@ -130,11 +133,11 @@ def _dense_pool_attention(q, k_pool, v_pool, lengths, page_indices, layer,
         lengths, starts = lengths - bases, starts - bases
     return _window_attention(q, _flat(k_pool)[rows].reshape(B, KV, T, D),
                              _flat(v_pool)[rows].reshape(B, KV, T, D),
-                             lengths, starts)
+                             lengths, starts, scale)
 
 
 def _dense_paged_attention_q(q, k_pages, v_pages, lengths, page_indices,
-                             k_scales, v_scales):
+                             k_scales, v_scales, scale=None):
     """Int8-page analog of ``_dense_paged_attention`` — dequantize the
     GATHERED window (never the whole pool) with the per-page scales,
     then the same f32 einsum/softmax/einsum.  The off-TPU fallback and
@@ -150,7 +153,7 @@ def _dense_paged_attention_q(q, k_pages, v_pages, lengths, page_indices,
     kc = kc.astype(jnp.float32) * ksc[..., None, None]
     vc = vc.astype(jnp.float32) * vsc[..., None, None]
     return _window_attention(q, kc.reshape(B, KV, T, D),
-                             vc.reshape(B, KV, T, D), lengths)
+                             vc.reshape(B, KV, T, D), lengths, scale=scale)
 
 
 def _select_impl(head_dim, page_size):
@@ -191,7 +194,7 @@ def _select_impl(head_dim, page_size):
 def paged_decode_attention(q, k_pages, v_pages, lengths, page_indices,
                            pages_per_compute_block=4,
                            k_scales=None, v_scales=None, layer=None,
-                           starts=None, bases=None):
+                           starts=None, bases=None, scale=None):
     """Decode attention over the page pool.  On TPU this is the
     self-authored fused kernel (``ops/pallas_kernels/paged_decode.py``:
     per sequence a loop over 256-key blocks of the LIVE pages, every
@@ -217,6 +220,11 @@ def paged_decode_attention(q, k_pages, v_pages, lengths, page_indices,
     table whose first entry stands for token ``bases`` (the fused kernel's
     contract; the dense path masks the same keys).  The stock kernel and
     the int8 pool have no such inlet and refuse.
+
+    ``scale`` multiplies the float32 scores on every path (a Python
+    number, part of the program): ``1 / sqrt(D)`` unless given.  A caller
+    whose queries arrive scaled, or whose ``D`` is wider than the model's
+    head (heads folded onto the lanes), gives its own.
 
     ``k_scales``/``v_scales`` [KV, P] select the int8-page path
     (``PT_QUANT=int8``): the fused quant kernel when its (stricter)
@@ -247,7 +255,7 @@ def paged_decode_attention(q, k_pages, v_pages, lengths, page_indices,
                 Tensor(jnp.asarray(v_pages)), Tensor(lengths),
                 Tensor(page_indices),
                 Tensor(jnp.asarray(k_scales, jnp.float32)),
-                Tensor(jnp.asarray(v_scales, jnp.float32)))
+                Tensor(jnp.asarray(v_scales, jnp.float32)), scale=scale)
         else:
             out = _op("paged_decode_attention_q",
                       _dense_paged_attention_q,
@@ -255,7 +263,8 @@ def paged_decode_attention(q, k_pages, v_pages, lengths, page_indices,
                       Tensor(jnp.asarray(v_pages)), Tensor(lengths),
                       Tensor(page_indices),
                       Tensor(jnp.asarray(k_scales, jnp.float32)),
-                      Tensor(jnp.asarray(v_scales, jnp.float32)))
+                      Tensor(jnp.asarray(v_scales, jnp.float32)),
+                      scale=scale)
         return out if wrap else out._data
 
     impl = _select_impl(q.shape[-1], k_pages.shape[-2])
@@ -272,7 +281,7 @@ def paged_decode_attention(q, k_pages, v_pages, lengths, page_indices,
     if impl == "pallas":
         from ..ops.pallas_kernels import paged_decode as _fused
 
-        out = _fused.handle()(*args)
+        out = _fused.handle()(*args, scale=scale)
         return out if wrap else out._data
     if impl == "dense":
         name, fn = (("paged_decode_attention", _dense_paged_attention)
@@ -280,7 +289,7 @@ def paged_decode_attention(q, k_pages, v_pages, lengths, page_indices,
                     ("paged_decode_attention_window", _dense_pool_attention)
                     if windowed else
                     ("paged_decode_attention_pool", _dense_pool_attention))
-        out = _op(name, fn, *args)
+        out = _op(name, fn, *args, scale=scale)
         return out if wrap else out._data
     if windowed:
         raise NotImplementedError(
@@ -300,8 +309,8 @@ def paged_decode_attention(q, k_pages, v_pages, lengths, page_indices,
         blk -= 1
     # The stock kernel mixes int32/int64 under global x64 mode — trace
     # it x64-off (same guard as the flash-attention wrappers).  It also
-    # applies NO logits scaling: pre-scale q by 1/sqrt(D).
-    q = q / np.sqrt(q.shape[-1])
+    # applies NO logits scaling: pre-scale q (by 1/sqrt(D) unless told).
+    q = q / np.sqrt(q.shape[-1]) if scale is None else q * scale
     with jax.enable_x64(False):
         out = paged_attention(
             jnp.asarray(q), jnp.asarray(k_pages),

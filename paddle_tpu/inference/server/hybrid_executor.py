@@ -53,16 +53,19 @@ Two programs:
 
 **The KV pool at a head size under 128.**  A TPU tile is 128 lanes wide;
 a pool whose last dimension is a head of 64 is padded to twice its size
-in HBM and re-laid for every gather (compiled for the v5e at the
-benchmark's size, :func:`~..paged.paged_decode_attention`'s dense path
-wants 19 GB).  So the cache is built with ``fold`` KV heads side by side
-in its last dimension (``[A, KV / fold, pages, page_size, fold * D]``,
-``fold * D <= 128``): every page is whole tiles, and the same
-``PagedKVCache`` — its page table, ``write_at``, ``gather_dense`` — serves
-unchanged.  Decode attention over the folded pool is :func:`_pool_attention`
-here: a dense gather of each sequence's window in the pool's dtype with
-float32 accumulation, each query row zero outside its own head's lanes
-(neither Pallas decode kernel takes a head of 64; PERF.md section 7).
+in HBM and re-laid for every gather.  So the cache is built with ``fold``
+KV heads side by side in its last dimension (``[A, KV / fold, pages,
+page_size, fold * D]``, ``fold * D <= 128``): every page is whole tiles,
+the same ``PagedKVCache`` — its page table, ``write_at``,
+``gather_dense`` — serves unchanged, and the pool is one the fused
+paged-decode kernel reads as it stands: ``KV / fold`` rows of
+``fold * D`` lanes.  The decode step attends through
+:func:`~..paged.paged_decode_attention` over that pool in place, by the
+sequences' lengths (:func:`_folded_attention`): a query head is laid over
+its whole folded row, zero outside its own head's lanes, so the kernel's
+two products contract or produce whole rows (twice the arithmetic of a
+64-wide head, the same bytes; decode attention is bound by bytes), and
+the output keeps each head's own lanes.
 """
 from __future__ import annotations
 
@@ -78,7 +81,10 @@ from ... import obs
 from ...analysis import CountedJit
 from ...models import granite_hybrid as gh
 from ...ops.pallas_kernels import ssm_decode as _ssm
-from ..paged import PagedKVCache, _flat, _put_token, _rows
+from ...ops.pallas_kernels.paged_decode import block_pages
+from ..paged import (
+    PagedKVCache, _flat, _put_token, paged_decode_attention,
+)
 from ..state_cache import RecurrentStateCache
 
 _F32 = jnp.float32
@@ -104,37 +110,28 @@ def _fold_factor(n_kv_heads, head_dim, lanes=128):
                 if n_kv_heads % f == 0 and f * head_dim <= lanes] or [1])
 
 
-def _pool_attention(q, k_flat, v_flat, pool_shape, layer, lengths, tables,
-                    fold):
-    """Decode attention of one layer over the folded pool (flat, see
-    :func:`_flat`).  q [S, heads, D], already scaled; lengths [S] keys
-    each sequence reads; tables [S, pages per sequence].  Returns
-    [S, heads * D].
+def _folded_attention(q, k_pages, v_pages, layer, lengths, tables, fold):
+    """Decode attention of one layer over the folded pools ``[A, KV /
+    fold, pages, page_size, fold * D]``, in place.  q [S, heads, D],
+    already scaled (the scores are taken as they come: scale 1); lengths
+    [S] keys each sequence reads, 0 for a slot that is not live (the
+    kernel reads nothing for it); tables [S, pages per sequence].
+    Returns [S, heads * D].
 
-    Every sequence's window is gathered dense in the pool's dtype,
-    straight into ``[S, KV / fold, T, fold * D]``.  A KV row holds
-    ``fold`` heads; a query head's row is laid over the whole row, zero
-    outside its own head's lanes, so both products contract or produce
-    whole 128-lane rows and nothing of the window is re-laid; the output
-    keeps each head's own lanes."""
+    A KV row holds ``fold`` heads.  A query head's row is laid over the
+    whole row, zero outside its own head's lanes, which makes this a
+    call of :func:`~..paged.paged_decode_attention` with ``KV / fold`` KV
+    heads of ``fold * D`` lanes and ``fold`` times the query rows a KV
+    head; of each output row the head's own lanes are kept."""
     S, nh, D = q.shape
-    KVf, ps, W = pool_shape[1], pool_shape[3], pool_shape[4]
+    KVf, W = k_pages.shape[1], k_pages.shape[4]
     g = nh // (KVf * fold)
-    T = tables.shape[1] * ps
-    rows = _rows(pool_shape, layer, tables)               # [S, KVf, pps]
-    kc = k_flat[rows].reshape(S, KVf, T, W)
-    vc = v_flat[rows].reshape(S, KVf, T, W)
     own = jnp.eye(fold, dtype=q.dtype)[None, None, :, None, :, None]
-    qw = (q.reshape(S, KVf, fold, g, 1, D) * own) \
-        .reshape(S, KVf, fold * g, W).astype(kc.dtype)
-    s = jnp.einsum("skxl,sktl->skxt", qw, kc,
-                   preferred_element_type=_F32)
-    seen = jnp.arange(T)[None, None, None, :] < lengths[:, None, None, None]
-    p = jax.nn.softmax(jnp.where(seen, s, -1e30), axis=-1)
-    o = jnp.einsum("skxt,sktl->skxl", p.astype(vc.dtype), vc,
-                   preferred_element_type=_F32)
-    o = o.reshape(S, KVf, fold, g, fold, D) * own.astype(_F32)
-    return o.sum(axis=4).reshape(S, nh * D)
+    qw = (q.reshape(S, KVf, fold, g, 1, D) * own).reshape(S, nh, W)
+    o = paged_decode_attention(qw.astype(k_pages.dtype), k_pages, v_pages,
+                               lengths, tables, layer=layer, scale=1.0)
+    return (o.reshape(S, KVf, fold, g, fold, D) * own).sum(axis=4) \
+        .reshape(S, nh * D)
 
 
 @jax.jit
@@ -277,6 +274,10 @@ class HybridExecutor(SlotExecutor):
             page_size=page_size, max_seqs=max_seqs, dtype=dtype,
             max_pages_per_seq=pages_per_seq)
         self.state = RecurrentStateCache(**state_args)
+        #: keys in a block of the decode kernel's loop (``exec.prep``)
+        self._decode_block = page_size * block_pages(
+            page_size, cfg.num_key_value_heads // self.kv_fold,
+            cfg.head_dim * self.kv_fold, jnp.dtype(dtype).itemsize)
 
         # -- the parameters: a run is stacked, one run at a time ----------
         self.params = []
@@ -436,8 +437,9 @@ class HybridExecutor(SlotExecutor):
                             k.reshape(S, -1, width))
             vf = _put_token(vf, pool_shape, a, pids, offs,
                             v.reshape(S, -1, width))
-            o = _pool_attention(q, kf, vf, pool_shape, a, lengths, tables,
-                                self.kv_fold)
+            o = _folded_attention(q, kf.reshape(pool_shape),
+                                  vf.reshape(pool_shape), a, lengths, tables,
+                                  self.kv_fold)
             o = o.astype(x.dtype) @ lp["self_attn.o_proj.weight"]
             x = gh.mlp_residual(cfg, lp, x + cfg.residual_multiplier * o)
             a += 1
@@ -513,7 +515,16 @@ class HybridExecutor(SlotExecutor):
         if not sids:
             return {}
         cache = self.cache
-        with obs.span("exec.prep", cat="serve", batch=len(sids)):
+        # how much of the window the fused kernel's block loop visits:
+        # the blocks that hold one of the lengths + 1 keys a sequence
+        # reads, in every attention layer, of the blocks of every window
+        block = self._decode_block
+        with obs.span("exec.prep", cat="serve", batch=len(sids),
+                      blocks=int(cache.n_layers * (
+                          cache.lengths[sids] // block + 1).sum()),
+                      window_blocks=cache.n_layers * len(sids) * -(
+                          -cache.max_pages_per_seq * cache.page_size
+                          // block)):
             cache.reserve(sids, extra_tokens=1)
             n = cache.max_seqs
             ids = np.zeros((n,), np.int32)

@@ -9,8 +9,9 @@ no copy of a pool or of a layer of one: mistral7b-serve's page writer
 (``serve.kv_write``), its whole decode step (``serve.decode``, the pools
 carried through the layer scan into the Pallas kernel) and that kernel at a
 window of 8,192 tokens; granite4h-micro's
-state kernel inside a scan, its state writer, and the page patch and window
-gather on its folded KV pool; and, reading the pools without writing them,
+whole decode step (``serve.hybrid_decode``), its state kernel inside a
+scan, its state writer, and the page patch and the fused kernel's call on
+its folded KV pool; and, reading the pools without writing them,
 mistral7b-serve's prefill chunk (``serve.prefill_chunk``, its past gathered
 by row inside the layer scan).  Each has a control beside it or in it that
 shows the compiler's copies when the program is written the other way, so a
@@ -274,20 +275,24 @@ def test_the_state_writer_moves_no_pool(sds):
     assert not STATE_SIZED_COPY.search(text)
 
 
-def test_a_token_into_the_folded_kv_pool_moves_no_pool(sds):
-    """The hybrid decode's page patch and window gather on the pool of 4
+def test_a_token_into_the_folded_kv_pool_moves_no_pool(sds, compiled_kernel):
+    """The hybrid decode's page patch and attention on the pool of 4
     attention layers x 4 folded KV heads x 8,192 pages x 16 x 128: the
-    pool is aliased, and neither a layer (134 MB) nor the pool is copied
-    — only each sequence's window is gathered."""
+    pool is aliased and handed to the fused kernel where it lies — neither
+    a layer (134 MB) nor the pool is copied, no sequence's window is
+    gathered (``bf16[32768,16,128]``, 134 MB each for keys and values,
+    before the kernel was on this path), and the temporaries are the
+    widened queries and the output, a few MB."""
     from paddle_tpu.inference.server import hybrid_executor as hx
 
-    assert (hx._flat, hx._rows, hx._put_token) == (_flat, _rows, _put_token)
+    assert (hx._flat, hx._put_token) == (_flat, _put_token)
     shape = (4, 4, 8192, 16, 128)
 
     def step(pool, pids, offs, x, q, lengths, tables):
-        flat = _put_token(_flat(pool), shape, 2, pids, offs, x)
-        o = hx._pool_attention(q, flat, flat, shape, 2, lengths, tables, 2)
-        return flat.reshape(shape), o
+        pool = _put_token(_flat(pool), shape, 2, pids, offs,
+                          x).reshape(shape)
+        return pool, hx._folded_attention(q, pool, pool, 2, lengths,
+                                          tables, 2)
 
     i32 = jnp.int32
     exe = jax.jit(step, donate_argnums=0).lower(
@@ -296,8 +301,71 @@ def test_a_token_into_the_folded_kv_pool_moves_no_pool(sds):
         sds((64,), i32), sds((64, 128), i32)).compile()
     text, mem = exe.as_text(), exe.memory_analysis()
     assert mem.alias_size_in_bytes == 2 * 4 * 4 * 8192 * 16 * 128
-    # the gathered windows (134 MB each for keys and values) and no more
-    assert mem.temp_size_in_bytes < 400 << 20
+    assert mem.temp_size_in_bytes < 4 << 20
+    assert text.count("tpu_custom_call") == 1
+    assert "bf16[32768,16,128]" not in text
+    assert not re.search(r"= bf16\[(4,)?4,8192,16,128\]\S* "
+                         r"(copy|transpose|fusion)\(", text)
+
+
+def test_the_hybrid_decode_step_moves_no_pool(sds, compiled_kernel,
+                                              monkeypatch):
+    """serve.hybrid_decode at the cell's size (granite-4.0-h-micro whole:
+    5 scanned runs of state-space layers, 4 inlined attention layers, 64
+    slots, tables of 128 pages): the two KV pools and the ten state pools
+    aliased to the outputs, 6.0 GB of them; the state kernel once a run
+    and the fused paged-decode kernel once an attention layer; no KV pool
+    or layer of one copied or re-laid, no sequence's window gathered; the
+    temporaries 6 MB, far under one KV layer's 134 MB."""
+    from paddle_tpu.inference.server.hybrid_executor import HybridExecutor
+    from paddle_tpu.models import granite_hybrid as gh
+    from paddle_tpu.ops.pallas_kernels import ssm_decode
+
+    monkeypatch.setattr(ssm_decode, "_on_tpu", lambda: True)
+    cfg = gh.GraniteHybridConfig(dtype="bfloat16")
+    ex = object.__new__(HybridExecutor)
+    ex.config, ex.kv_fold = cfg, 2
+    ex.segments = [(kind, n) for run in (5, 9, 9, 9)
+                   for kind, n in (("mamba", run), ("attention", 1))] \
+        + [("mamba", 4)]
+    assert sum(n for _, n in ex.segments) == cfg.num_hidden_layers
+    ex.cache = types.SimpleNamespace(page_size=16, num_pages=8192)
+    ex.state = types.SimpleNamespace(ssm_shape=ssm_decode.state_shape(
+        cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state))
+    h, i = cfg.hidden_size, cfg.shared_intermediate_size
+    shared = {"input_layernorm.weight": (h,),
+              "post_attention_layernorm.weight": (h,),
+              "shared_mlp.input_linear.weight": (h, 2 * i),
+              "shared_mlp.output_linear.weight": (i, h)}
+
+    def layer(kind, lead):
+        mixer = {f"{'mamba' if kind == 'mamba' else 'self_attn'}.{name}":
+                 shape for name, (shape, _) in
+                 gh._mixer_shapes(cfg, kind).items()}
+        shapes = {**mixer, **shared}
+        assert set(shapes) == set(gh.layer_param_names(kind))
+        return {name: sds(lead + shape) for name, shape in shapes.items()}
+
+    params = tuple(layer(kind, (n,) if kind == "mamba" else ())
+                   for kind, n in ex.segments)
+    tops = {"embed": sds((cfg.vocab_size, h)), "norm_w": sds((h,))}
+    runs = [n for kind, n in ex.segments if kind == "mamba"]
+    ssm = tuple(sds((n, 64) + ex.state.ssm_shape, jnp.float32) for n in runs)
+    conv = tuple(sds((n, 3, 64, cfg.mamba_conv_dim)) for n in runs)
+    pool, i32 = sds((4, 4, 8192, 16, 128)), jnp.int32
+    exe = jax.jit(ex._decode_fwd, donate_argnums=(5, 6, 8, 9)).lower(
+        params, tops, sds((64,), i32), sds((64,), i32),
+        sds((64,), jnp.bool_), pool, pool, sds((64, 128), i32), ssm,
+        conv).compile()
+    text, mem = exe.as_text(), exe.memory_analysis()
+    aliased = re.findall(r"\{(\d+)\}: \(\d+, \{\}, may-alias\)",
+                         text[:text.index("\n")])
+    assert aliased == [str(n) for n in range(1, 13)]
+    assert mem.alias_size_in_bytes == 2 * 2 * 4 * 4 * 8192 * 16 * 128 \
+        + 36 * 64 * (4 * 32 * 128 * 128 + 2 * 3 * cfg.mamba_conv_dim)
+    assert mem.temp_size_in_bytes < 16 << 20
+    assert text.count("tpu_custom_call") == 5 + 4
+    assert "bf16[32768,16,128]" not in text
     assert not re.search(r"= bf16\[(4,)?4,8192,16,128\]\S* "
                          r"(copy|transpose|fusion)\(", text)
 

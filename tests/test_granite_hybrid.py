@@ -271,6 +271,198 @@ def test_a_slots_state_is_the_references_after_the_same_tokens(model):
     np.testing.assert_allclose(unpadded, want, rtol=1e-6, atol=1e-9)
 
 
+# -- decode attention: the paged-decode entry over the folded pool ---------------
+
+#: three requests that read across pages of 4 and across the kernel's
+#: 256-key block while they decode, and one that leaves after three steps
+LONG_LENS, LEAVES, LONG_STEPS = (3, 250, 9, 254), 2, 9
+
+
+def fly(model, impl):
+    """The executor driven slot by slot under ``PT_PAGED_IMPL=impl`` (the
+    dense path the CPU takes by itself; the fused kernel, which the chip
+    takes, in the interpreter): four prompts prefilled whole, nine decode
+    steps, slot 2 freed after the third so that a dead slot with a stale
+    table sits inside the batch.  Then the decode program's own function
+    once more, eagerly and with nothing donated, for the next step's
+    LOGITS.  Returns (prompts, tokens, logits, ``exec.prep`` args) by
+    slot."""
+    from paddle_tpu import obs
+    from paddle_tpu.inference.server.hybrid_executor import HybridExecutor
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("PT_PAGED_IMPL", impl)
+        ex = HybridExecutor(model, max_seqs=4, page_size=4, max_len=288,
+                            dtype=jnp.float32)
+        prompts, tokens, preps = {}, {}, []
+        for n in LONG_LENS:
+            sid = ex.alloc_slot()
+            prompts[sid] = prompt(n, 21)
+            tokens[sid] = [ex.prefill(sid, prompts[sid])]
+        live = sorted(tokens)
+        for step in range(LONG_STEPS):
+            if step == 3:
+                ex.free_slot(LEAVES)
+                live.remove(LEAVES)
+            reads = ex.cache.lengths[live] + 1
+            for sid, tok in ex.decode(live).items():
+                tokens[sid].append(tok)
+            preps.append((reads, [s for s in obs.tracer().spans
+                                  if s.name == "exec.prep"][-1].args))
+        cache, seen = ex.cache, []
+        mp.setattr(gh, "head", lambda *a, head=gh.head:
+                   seen.append(head(*a)) or seen[-1])
+        cache.reserve(live, extra_tokens=1)
+        ids, positions = np.zeros((2, cache.max_seqs), np.int32)
+        alive = np.zeros((cache.max_seqs,), bool)
+        ids[live] = [ex.last_token[s] for s in live]
+        positions[live] = cache.lengths[live]
+        alive[live] = True
+        ex._decode_fwd(ex.params, ex.tops, ids, positions, alive,
+                       *cache.pools(), np.maximum(cache.page_table, 0),
+                       *ex.state.pools())
+    return prompts, tokens, {s: np.asarray(seen[0])[s] for s in live}, preps
+
+
+@pytest.fixture(scope="module")
+def flight(model):
+    """``flight(impl)``: :func:`fly`, once a path for the module."""
+    flown = {}
+
+    def of(impl):
+        if impl not in flown:
+            flown[impl] = fly(model, impl)
+        return flown[impl]
+    return of
+
+
+@pytest.mark.parametrize("impl", ["dense", "pallas"])
+def test_decode_over_the_folded_pool_serves_the_models_tokens(
+        model, reference, flight, impl):
+    """Every token of every slot is the reference's greedy choice after
+    the same tokens (the 250- and 254-token requests read keys 251..263:
+    across pages and the first 256-key block), the short one's also the
+    eager model's; the slot that left was served right while it lived.
+    ``attention_multiplier`` is 1/16 here against 1/4 = 1/sqrt(head_dim)
+    (and 1/sqrt(32) of the folded row): a scale that is not the
+    configuration's moves every logit."""
+    prompts, tokens, _, _ = flight(impl)
+    assert CFG["attention_multiplier"] not in (16 ** -0.5, 32 ** -0.5)
+    assert [len(tokens[s]) for s in sorted(tokens)] == [10, 10, 4, 10]
+    for sid, ids in prompts.items():
+        seq = np.concatenate([ids, tokens[sid][:-1]]).astype(np.int32)
+        assert tokens[sid] == reference(seq)[len(ids) - 1:] \
+            .argmax(-1).tolist()
+        assert gap_of(reference, ids, tokens[sid]) <= TOKEN_TOL
+    seq = np.concatenate([prompts[0], tokens[0][:-1]]).astype(np.int32)
+    eager = np.asarray(model(paddle.to_tensor(seq[None]))._data)[0]
+    assert tokens[0] == eager[len(prompts[0]) - 1:].argmax(-1).tolist()
+
+
+@pytest.mark.parametrize("impl", ["dense", "pallas"])
+def test_decode_over_the_folded_pool_computes_the_models_logits(
+        model, reference, flight, impl):
+    """The tenth step's logits of the three live slots (263 and 259 keys
+    read, and 12) against the reference's after the same tokens, and the
+    short one's against the eager model's."""
+    prompts, tokens, logits, _ = flight(impl)
+    assert sorted(logits) == [0, 1, 3]
+    for sid, got in logits.items():
+        seq = np.concatenate([prompts[sid], tokens[sid]]).astype(np.int32)
+        want = reference(seq)[-1]
+        assert np.abs(got - want).max() <= LOGIT_TOL * np.sqrt(
+            np.square(want).mean())
+    seq = np.concatenate([prompts[0], tokens[0]]).astype(np.int32)
+    want = np.asarray(model(paddle.to_tensor(seq[None]))._data)[0, -1]
+    assert np.abs(logits[0] - want).max() <= LOGIT_TOL * np.sqrt(
+        np.square(want).mean())
+
+
+def test_a_decode_step_says_how_many_blocks_it_reads(flight):
+    """``exec.prep``'s decode span carries the kernel's work beside the
+    batch: ``blocks`` = both attention layers x the sum of cdiv(keys
+    read, 256) over the batch, ``window_blocks`` = layers x batch x the
+    two blocks of a 288-token window."""
+    _, _, _, preps = flight("dense")
+    assert [a["batch"] for _, a in preps] == [4] * 3 + [3] * 6
+    for reads, args in preps:
+        assert args["blocks"] == 2 * int((-(-reads // 256)).sum())
+        assert args["window_blocks"] == 2 * len(reads) * 2
+    # the 254-token request reads its 257th key in the third step, the
+    # 250-token one in the seventh
+    assert [a["blocks"] for _, a in preps] == \
+        [8] * 2 + [10] + [8] * 3 + [10] * 3
+
+
+def window_gather_attention(q, k_flat, v_flat, pool_shape, layer, lengths,
+                            tables, fold):
+    """The decode attention this executor had before it called the fused
+    kernel, kept as the reference: every sequence's whole window gathered
+    dense in the pool's dtype, float32 accumulation, each query row laid
+    over its folded KV row and zero outside its own head's lanes."""
+    import jax
+
+    from paddle_tpu.inference.paged import _rows
+
+    S, nh, D = q.shape
+    KVf, ps, W = pool_shape[1], pool_shape[3], pool_shape[4]
+    g = nh // (KVf * fold)
+    T = tables.shape[1] * ps
+    rows = _rows(pool_shape, layer, tables)               # [S, KVf, pps]
+    kc = k_flat[rows].reshape(S, KVf, T, W)
+    vc = v_flat[rows].reshape(S, KVf, T, W)
+    own = jnp.eye(fold, dtype=q.dtype)[None, None, :, None, :, None]
+    qw = (q.reshape(S, KVf, fold, g, 1, D) * own) \
+        .reshape(S, KVf, fold * g, W).astype(kc.dtype)
+    s = jnp.einsum("skxl,sktl->skxt", qw, kc,
+                   preferred_element_type=jnp.float32)
+    seen = jnp.arange(T)[None, None, None, :] < lengths[:, None, None, None]
+    p = jax.nn.softmax(jnp.where(seen, s, -1e30), axis=-1)
+    o = jnp.einsum("skxt,sktl->skxl", p.astype(vc.dtype), vc,
+                   preferred_element_type=jnp.float32)
+    o = o.reshape(S, KVf, fold, g, fold, D) * own.astype(jnp.float32)
+    return o.sum(axis=4).reshape(S, nh * D)
+
+
+@pytest.mark.parametrize("impl,dtype,tol", [
+    ("dense", jnp.float32, 1e-5), ("pallas", jnp.float32, 1e-5),
+    ("dense", jnp.bfloat16, 2e-2), ("pallas", jnp.bfloat16, 2e-2)])
+def test_the_widened_query_call_equals_the_window_gather(monkeypatch, impl,
+                                                         dtype, tol):
+    """``_folded_attention`` (the paged-decode entry with ``fold`` times
+    the query rows a folded KV row, scale 1) against the gather it
+    replaced, on random pools at the model's head size: 3 layers x 2
+    folded rows of two 64-wide heads, pages of 16, lengths of one key, a
+    page's edge, a block's edge and beyond, and a dead row (length 0),
+    which is not compared — the kernel gives it zeros.  In bf16 the two
+    round the probabilities alike and differ by the order of the sums."""
+    import jax
+
+    from paddle_tpu.inference.paged import _flat
+    from paddle_tpu.inference.server import hybrid_executor as hx
+
+    monkeypatch.setenv("PT_PAGED_IMPL", impl)
+    shape, fold, nh, D = (3, 2, 128, 16, 128), 2, 8, 64
+    lengths = np.array([1, 16, 0, 256, 257, 300], np.int32)
+    S, pps = len(lengths), 20
+    kk, kv, kq, kt = jax.random.split(jax.random.PRNGKey(35), 4)
+    k_pages = jax.random.normal(kk, shape, jnp.float32).astype(dtype)
+    v_pages = jax.random.normal(kv, shape, jnp.float32).astype(dtype)
+    q = (jax.random.normal(kq, (S, nh, D), jnp.float32) * 0.2).astype(dtype)
+    tables = np.asarray(jax.random.permutation(kt, shape[2]))[:S * pps] \
+        .reshape(S, pps).astype(np.int32)
+    got = np.asarray(hx._folded_attention(
+        q, k_pages, v_pages, 1, lengths, tables, fold), np.float32)
+    want = np.asarray(window_gather_attention(
+        q, _flat(k_pages), _flat(v_pages), shape, 1, lengths, tables, fold))
+    assert got.shape == want.shape == (S, nh * D)
+    live = lengths > 0
+    assert np.abs(got[live] - want[live]).max() <= tol
+    assert np.isfinite(got).all()
+    if impl == "pallas":
+        assert not got[~live].any()
+
+
 # -- what is held, decided at build --------------------------------------------
 
 def fresh_model():

@@ -425,3 +425,46 @@ def test_the_dense_fallback_masks_the_same_window():
     np.testing.assert_allclose(
         np.asarray(got), _window_oracle(q, kp, vp, lens, table, starts,
                                         bases), rtol=2e-4, atol=2e-4)
+
+
+# -- the entry's scale ---------------------------------------------------------
+
+@pytest.mark.parametrize("impl", ["dense", "pallas"])
+@pytest.mark.parametrize("pool", ["layer", "whole", "window", "int8"])
+def test_a_given_scale_multiplies_the_scores_on_every_path(monkeypatch, impl,
+                                                           pool):
+    """``paged_decode_attention(.., scale=s)`` is the default call with the
+    queries times ``s * sqrt(D)``: on one layer's pool, on the whole pools
+    with a layer named, on a window layer and on the int8 pool, through
+    the fused kernel and through the dense fallback (a caller whose
+    queries arrive scaled, or whose rows are wider than its heads, says
+    1.0 and none of them divides by sqrt(D) behind its back)."""
+    from paddle_tpu.inference.paged import paged_decode_attention
+
+    monkeypatch.setenv("PT_PAGED_IMPL", impl)
+    rng = np.random.RandomState(35)
+    q, kp, vp, table = _mk(rng, B=2, H=4, KV=2, D=16, P=40, ps=32, pps=10)
+    lens, scale = np.array([300, 33], np.int32), 0.37
+    kw = {}
+    if pool != "layer":
+        kp, vp = np.stack([kp[::-1], kp]), np.stack([vp[::-1], vp])
+        kw = dict(layer=1)
+    if pool == "window":
+        kw.update(starts=np.array([40, 0], np.int32),
+                  bases=np.array([32, 0], np.int32))
+    if pool == "int8":
+        kp, vp = (rng.randint(-127, 128, p[1].shape).astype(np.int8)
+                  for p in (kp, vp))
+        kw = dict(k_scales=rng.rand(2, 40).astype(np.float32) * 0.02,
+                  v_scales=rng.rand(2, 40).astype(np.float32) * 0.02)
+    got = paged_decode_attention(jnp.asarray(q), jnp.asarray(kp),
+                                 jnp.asarray(vp), lens, table, scale=scale,
+                                 **kw)
+    want = paged_decode_attention(jnp.asarray(q * scale * 4.0),
+                                  jnp.asarray(kp), jnp.asarray(vp), lens,
+                                  table, **kw)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+    plain = paged_decode_attention(jnp.asarray(q), jnp.asarray(kp),
+                                   jnp.asarray(vp), lens, table, **kw)
+    assert np.abs(np.asarray(got) - np.asarray(plain)).max() > 1e-2
