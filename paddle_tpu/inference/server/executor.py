@@ -30,6 +30,7 @@ from ...ops import quant as _quant
 from ...ops.nn_ops import _rms_norm_plain, _rope_plain
 from ...ops.pallas_kernels.paged_decode import block_pages
 from ...testing import faults as _faults
+from .handoff import Handoff
 from ..paged import (
     PagedKVCache, _flat, _past_of, _put_token, paged_decode_attention,
 )
@@ -213,6 +214,9 @@ class PagedExecutor:
                 "the active mode)", labels=("mode",)).labels(
                 mode=self.quant).set(1)
         self.last_token = {}
+        # what prefill_chunk and decode hand the device outside their
+        # programs, and their blocking reads (handoff.py)
+        self.handoff = Handoff()
         # (sid, n_tokens) per prefill dispatch — the audit trail the
         # prefix-cache tests use to assert prefill FLOPs covered only
         # the novel suffix of a warm request
@@ -1145,12 +1149,12 @@ class PagedExecutor:
                 "has an unbounded [1, S] shape and cannot be warmed — "
                 "the scheduler routes prompts through prefill_chunk's "
                 "bucket ladder instead")
-        ids = jnp.asarray(np.asarray(prompt_ids)[None], jnp.int32)
+        with self.handoff.prep(tokens=len(prompt_ids)) as io:
+            ids = io.put(np.asarray(prompt_ids)[None], jnp.int32)
         self.prefill_events.append((sid, int(ids.shape[1])))
         logits, k, v = self._jit_prefill(self.layers, self.tops, ids)
         self.cache.prefill(sid, k, v)
-        with obs.span("exec.fetch", cat="serve", what="prefill"):
-            tok = int(jnp.argmax(logits))
+        tok = int(io.fetch("prefill", lambda: io.argmax(logits)))
         self.last_token[sid] = tok
         return tok
 
@@ -1162,7 +1166,7 @@ class PagedExecutor:
         the page writer.  When ``final``, records and returns the
         prompt's first greedy token; else returns None."""
         cache = self.cache
-        with obs.span("exec.prep", cat="serve", tokens=len(chunk_ids)):
+        with self.handoff.prep(tokens=len(chunk_ids)) as io:
             ids = np.asarray(chunk_ids, np.int32)[None]
             pids = cache.past_pages(sid, start)
             if self.aot_ladder is not None:
@@ -1174,6 +1178,8 @@ class PagedExecutor:
 
                 b = bucket_pages(len(pids), self._aot_page_buckets)
                 pids = np.pad(pids, (0, max(b - len(pids), 0)))
+            at = np.int32(start)
+            io.host(ids, at, pids, at)
             kp, vp = cache.pools()
         self.prefill_events.append((sid, int(ids.shape[1])))
         # the past of an int8 pool is dequantized inside the program:
@@ -1182,8 +1188,7 @@ class PagedExecutor:
         if int8:
             _faults.fire("quant.dequant", "before")
         logits, k, v = self._jit_chunk(
-            self.layers, self.tops, ids, np.int32(start), kp, vp, pids,
-            np.int32(start))
+            self.layers, self.tops, ids, at, kp, vp, pids, at)
         if int8:
             _faults.fire("quant.dequant", "after")
         cache.write_at(sid, k, v, start)
@@ -1195,8 +1200,7 @@ class PagedExecutor:
             # transition even though the last (short) chunk ran dense
             self.cache.gather_shards(sid)
             self._sp_written.discard(sid)
-        with obs.span("exec.fetch", cat="serve", what="prefill_chunk"):
-            tok = int(jnp.argmax(logits))
+        tok = int(io.fetch("prefill_chunk", lambda: io.argmax(logits)))
         self.last_token[sid] = tok
         return tok
 
@@ -1272,8 +1276,8 @@ class PagedExecutor:
             return None
         self.cache.gather_shards(sid)
         self._sp_written.discard(sid)
-        with obs.span("exec.fetch", cat="serve", what="prefill_sp"):
-            tok = int(jnp.argmax(logits))
+        io = self.handoff
+        tok = int(io.fetch("prefill_sp", lambda: io.argmax(logits)))
         self.last_token[sid] = tok
         return tok
 
@@ -1288,11 +1292,12 @@ class PagedExecutor:
         # blocks that hold one of the lengths + 1 keys a sequence reads,
         # of the blocks of every window
         block = self._decode_block
-        with obs.span("exec.prep", cat="serve", batch=len(sids),
-                      blocks=int((cache.lengths[sids] // block + 1).sum()),
-                      window_blocks=len(sids) * -(
-                          -cache.max_pages_per_seq * cache.page_size
-                          // block)):
+        with self.handoff.prep(
+                batch=len(sids),
+                blocks=int((cache.lengths[sids] // block + 1).sum()),
+                window_blocks=len(sids) * -(
+                    -cache.max_pages_per_seq * cache.page_size
+                    // block)) as io:
             # batch-atomic page reservation BEFORE the jitted
             # write-then-attend: a per-sequence loop would strand
             # earlier sequences' fresh pages when a later one exhausts
@@ -1302,12 +1307,11 @@ class PagedExecutor:
             for s in sids:
                 pos = int(cache.lengths[s])
                 cache.make_writable(s, pos, pos + 1)
-            ids = jnp.asarray([self.last_token[s] for s in sids],
-                              jnp.int32)
-            positions = jnp.asarray(
-                [int(cache.lengths[s]) for s in sids], jnp.int32)
-            tables = jnp.asarray(np.maximum(cache.page_table[sids], 0))
-            lengths = jnp.asarray(cache.lengths[sids])
+            ids = io.put([self.last_token[s] for s in sids], jnp.int32)
+            positions = io.put([int(cache.lengths[s]) for s in sids],
+                               jnp.int32)
+            tables = io.put(np.maximum(cache.page_table[sids], 0))
+            lengths = io.put(cache.lengths[sids])
             kp, vp = self.cache.pools()
         logits, kps, vps = self._jit_decode(
             self.layers, self.tops, ids, positions, kp, vp, lengths,
@@ -1316,8 +1320,7 @@ class PagedExecutor:
         for s in sids:
             cache.lengths[s] += 1
         # single batched argmax + ONE host transfer for the whole step
-        with obs.span("exec.fetch", cat="serve", what="decode"):
-            toks = np.asarray(jnp.argmax(logits, axis=-1))
+        toks = io.fetch("decode", lambda: io.argmax(logits, axis=-1))
         out = {}
         for i, s in enumerate(sids):
             tok = int(toks[i])

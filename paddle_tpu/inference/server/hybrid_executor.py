@@ -86,6 +86,7 @@ from ..paged import (
     PagedKVCache, _flat, _put_token, paged_decode_attention,
 )
 from ..state_cache import RecurrentStateCache
+from .handoff import Handoff
 
 _F32 = jnp.float32
 #: device memory left for the programs' temporaries when the executor
@@ -302,6 +303,7 @@ class HybridExecutor(SlotExecutor):
             self.cache.k_pages.shape[:2] + (0, self.cache.k_pages.shape[4]),
             dtype)
         self.last_token = {}
+        self.handoff = Handoff()
         #: (sid, n_tokens) per prefill dispatch, as PagedExecutor keeps
         self.prefill_events = []
         self._jit_chunk = CountedJit(self._chunk_fwd,
@@ -490,22 +492,23 @@ class HybridExecutor(SlotExecutor):
                 past_k, past_v = cache.gather_dense(sid, start)
         else:
             past_k = past_v = self._no_past
-        with obs.span("exec.prep", cat="serve", tokens=len(chunk_ids)):
-            ids = jnp.asarray(np.asarray(chunk_ids), jnp.int32)
+        with self.handoff.prep(tokens=len(chunk_ids)) as io:
+            ids = io.put(np.asarray(chunk_ids), jnp.int32)
+            at, slot = np.int32(start), np.int32(sid)
+            io.host(at, slot)
         self.prefill_events.append((sid, int(ids.shape[0])))
         with obs.span("state.read", cat="serve", slot=int(sid),
                       start=int(start)):
             ssm, conv = self.state.pools()
         tok, k, v, new_ssm, new_conv = self._jit_chunk(
-            self.params, self.tops, ids, np.int32(start), past_k, past_v,
-            np.int32(sid), ssm, conv)
+            self.params, self.tops, ids, at, past_k, past_v, slot, ssm,
+            conv)
         del ssm, conv
         cache.write_at(sid, k, v, start)
         self.state.write(sid, new_ssm, new_conv, int(ids.shape[0]))
         if not final:
             return None
-        with obs.span("exec.fetch", cat="serve", what="prefill_chunk"):
-            tok = int(tok)
+        tok = int(io.fetch("prefill_chunk", tok))
         self.last_token[sid] = tok
         return tok
 
@@ -519,12 +522,13 @@ class HybridExecutor(SlotExecutor):
         # the blocks that hold one of the lengths + 1 keys a sequence
         # reads, in every attention layer, of the blocks of every window
         block = self._decode_block
-        with obs.span("exec.prep", cat="serve", batch=len(sids),
-                      blocks=int(cache.n_layers * (
-                          cache.lengths[sids] // block + 1).sum()),
-                      window_blocks=cache.n_layers * len(sids) * -(
-                          -cache.max_pages_per_seq * cache.page_size
-                          // block)):
+        with self.handoff.prep(
+                batch=len(sids),
+                blocks=int(cache.n_layers * (
+                    cache.lengths[sids] // block + 1).sum()),
+                window_blocks=cache.n_layers * len(sids) * -(
+                    -cache.max_pages_per_seq * cache.page_size
+                    // block)) as io:
             cache.reserve(sids, extra_tokens=1)
             n = cache.max_seqs
             ids = np.zeros((n,), np.int32)
@@ -534,6 +538,7 @@ class HybridExecutor(SlotExecutor):
             positions[sids] = cache.lengths[sids]
             live[sids] = True
             tables = np.maximum(cache.page_table, 0)
+            io.host(ids, positions, live, tables)
             kp, vp = cache.pools()
             ssm, conv = self.state.pools()
         toks, kp, vp, ssm, conv = self._jit_decode(
@@ -542,8 +547,7 @@ class HybridExecutor(SlotExecutor):
         cache.set_pools(kp, vp)
         self.state.set_pools(ssm, conv)
         cache.lengths[sids] += 1
-        with obs.span("exec.fetch", cat="serve", what="decode"):
-            toks = np.asarray(toks)
+        toks = io.fetch("decode", toks)
         out = {}
         for s in sids:
             out[s] = self.last_token[s] = int(toks[s])

@@ -47,11 +47,11 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ... import obs
 from ...analysis import CountedJit
 from ...models import mla_moe as mm
 from ...ops.pallas_kernels import mla_decode as _mla
 from ..paged import PagedKVCache, _flat, _past_of, _put_token
+from .handoff import Handoff
 from .hybrid_executor import _HEADROOM, SlotExecutor, _free_device_bytes
 
 _EXPERT_LEAVES = ("mlp.experts.gate_up_proj", "mlp.experts.down_proj")
@@ -153,6 +153,7 @@ class LatentExecutor(SlotExecutor):
             page_size=page_size, max_seqs=max_seqs, dtype=dtype,
             max_pages_per_seq=pages_per_seq, latent=True)
         self.last_token = {}
+        self.handoff = Handoff()
         #: (sid, n_tokens) per prefill dispatch, as PagedExecutor keeps
         self.prefill_events = []
         #: running sums of the decode program's expert counter: rows each
@@ -300,19 +301,20 @@ class LatentExecutor(SlotExecutor):
         written pages inside the program.  When ``final``, records and
         returns the first greedy token."""
         cache = self.cache
-        with obs.span("exec.prep", cat="serve", tokens=len(chunk_ids)):
-            ids = jnp.asarray(np.asarray(chunk_ids), jnp.int32)
+        with self.handoff.prep(tokens=len(chunk_ids)) as io:
+            ids = io.put(np.asarray(chunk_ids), jnp.int32)
             pids = cache.past_pages(sid, start)
+            at = np.int32(start)
+            io.host(at, pids)
             pool, _ = cache.pools()
         self.prefill_events.append((sid, int(ids.shape[0])))
-        tok, rows = self._jit_chunk(self.params, self.tops, ids,
-                                    np.int32(start), pool, pids)
+        tok, rows = self._jit_chunk(self.params, self.tops, ids, at, pool,
+                                    pids)
         del pool
         cache.write_at(sid, rows, None, start)
         if not final:
             return None
-        with obs.span("exec.fetch", cat="serve", what="prefill_chunk"):
-            tok = int(tok)
+        tok = int(io.fetch("prefill_chunk", tok))
         self.last_token[sid] = tok
         return tok
 
@@ -322,7 +324,7 @@ class LatentExecutor(SlotExecutor):
         if not sids:
             return {}
         cache = self.cache
-        with obs.span("exec.prep", cat="serve", batch=len(sids)):
+        with self.handoff.prep(batch=len(sids)) as io:
             cache.reserve(sids, extra_tokens=1)
             n = cache.max_seqs
             ids = np.zeros((n,), np.int32)
@@ -332,13 +334,13 @@ class LatentExecutor(SlotExecutor):
             positions[sids] = cache.lengths[sids]
             live[sids] = True
             tables = np.maximum(cache.page_table, 0)
+            io.host(ids, positions, live, tables)
             pool, _ = cache.pools()
         toks, pool, counts = self._jit_decode(
             self.params, self.tops, ids, positions, live, pool, tables)
         cache.set_pools(pool, None)
         cache.lengths[sids] += 1
-        with obs.span("exec.fetch", cat="serve", what="decode"):
-            toks, counts = jax.device_get((toks, counts))
+        toks, counts = io.fetch("decode", (toks, counts))
         self._count_experts(counts)
         out = {}
         for s in sids:

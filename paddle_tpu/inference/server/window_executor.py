@@ -58,15 +58,14 @@ from __future__ import annotations
 
 import numpy as np
 
-import jax
 import jax.numpy as jnp
 
-from ... import obs
 from ...analysis import CountedJit
 from ...models import window_moe as wm
 from ...ops.pallas_kernels import paged_decode as _fused
 from ..paged import (LayerGroup, PagedKVCache, _flat, _past_of, _put_token,
                      paged_decode_attention)
+from .handoff import Handoff
 from .hybrid_executor import _HEADROOM, SlotExecutor, _free_device_bytes
 from .latent_executor import _head_block
 
@@ -132,6 +131,7 @@ class WindowExecutor(SlotExecutor):
             page_size, cfg.num_key_value_heads, cfg.head_dim,
             jnp.dtype(dtype).itemsize)
         self.last_token = {}
+        self.handoff = Handoff()
         #: (sid, n_tokens, start) per prefill dispatch (PagedExecutor keeps
         #: the first two; the start says which keys a window layer saw)
         self.prefill_events = []
@@ -287,22 +287,22 @@ class WindowExecutor(SlotExecutor):
         written pages inside the program.  When ``final``, records and
         returns the first greedy token."""
         cache = self.cache
-        with obs.span("exec.prep", cat="serve", tokens=len(chunk_ids)):
-            ids = jnp.asarray(np.asarray(chunk_ids), jnp.int32)
+        with self.handoff.prep(tokens=len(chunk_ids)) as io:
+            ids = io.put(np.asarray(chunk_ids), jnp.int32)
             pids = [cache.past_pages(sid, start, g) for g in (FULL, WINDOW)]
+            at = np.int32(start)
             base = np.int32(cache.groups[WINDOW].base[sid])
+            io.host(at, *pids, base)
             k_pools, v_pools = cache.pools()
         self.prefill_events.append((sid, int(ids.shape[0]), int(start)))
-        tok, k, v = self._jit_chunk(self.params, self.tops, ids,
-                                    np.int32(start), k_pools, v_pools, pids,
-                                    base)
+        tok, k, v = self._jit_chunk(self.params, self.tops, ids, at, k_pools,
+                                    v_pools, pids, base)
         del k_pools, v_pools
         cache.write_at(sid, k, v, start)     # and releases behind the window
         self._count_pages()
         if not final:
             return None
-        with obs.span("exec.fetch", cat="serve", what="prefill_chunk"):
-            tok = int(tok)
+        tok = int(io.fetch("prefill_chunk", tok))
         self.last_token[sid] = tok
         return tok
 
@@ -319,15 +319,15 @@ class WindowExecutor(SlotExecutor):
         reads = cache.lengths[sids] + 1
         seen_from = np.maximum(reads - cfg.sliding_window, 0) \
             - window.base[sids]
-        with obs.span(
-                "exec.prep", cat="serve", batch=len(sids),
+        with self.handoff.prep(
+                batch=len(sids),
                 blocks=int(full.n_layers * (-(-reads // block)).sum()
                            + window.n_layers * (
                                -(-(reads - window.base[sids]) // block)
                                - seen_from // block).sum()),
                 window_blocks=len(sids) * sum(
                     g.n_layers * -(-g.max_pages_per_seq * cache.page_size
-                                   // block) for g in cache.groups)):
+                                   // block) for g in cache.groups)) as io:
             cache.reserve(sids, extra_tokens=1)
             n = cache.max_seqs
             ids = np.zeros((n,), np.int32)
@@ -338,6 +338,7 @@ class WindowExecutor(SlotExecutor):
             live[sids] = True
             tables = [np.maximum(g.page_table, 0) for g in cache.groups]
             bases = window.base.copy()
+            io.host(ids, positions, live, *tables, bases)
             k_pools, v_pools = cache.pools()
         toks, k_pools, v_pools, counts = self._jit_decode(
             self.params, self.tops, ids, positions, live, k_pools, v_pools,
@@ -348,8 +349,7 @@ class WindowExecutor(SlotExecutor):
         # wholly behind the window go back to their pool
         cache.release(sids)
         self._count_pages()
-        with obs.span("exec.fetch", cat="serve", what="decode"):
-            toks, counts = jax.device_get((toks, counts))
+        toks, counts = io.fetch("decode", (toks, counts))
         self._count_experts(counts)
         out = {}
         for s in sids:

@@ -164,6 +164,11 @@ class Tracer:
             self.dropped += 1
         self.spans.append(span)
 
+    def now(self):
+        """One read of the tracer's clock, for a moment inside a span that
+        the span keeps among its args (``exec.fetch``'s ``ready``)."""
+        return self._clock()
+
     def span(self, name, cat="host", trace_id=None, **args):
         if trace_id is not None:
             args["trace_id"] = trace_id

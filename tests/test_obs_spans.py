@@ -421,3 +421,200 @@ def test_hybrid_span_budget_of_a_step(hybrid):
     assert decode_only >= 5
     instants = [s for s in spans if s.dur is None]
     assert len(instants) == 6 * 3       # + state.alloc, state.free
+
+
+# -- what a step hands the device, and when the device drained ------------------
+
+@pytest.fixture(scope="module")
+def latent():
+    from paddle_tpu.models.mla_moe import MLAMoEConfig, MLAMoEForCausalLM
+
+    paddle.seed(13)
+    return MLAMoEForCausalLM(MLAMoEConfig.tiny())
+
+
+@pytest.fixture(scope="module")
+def windowed():
+    from paddle_tpu.models.window_moe import (WindowMoEConfig,
+                                              WindowMoEForCausalLM)
+
+    paddle.seed(14)
+    return WindowMoEForCausalLM(WindowMoEConfig.tiny())
+
+
+#: fixture -> (executor, spans a prefill chunk may add to a step's 8,
+#: host arrays and eager ops of a decode step)
+EXECUTORS = {"model": ("PagedExecutor", 6, 4, 3),
+             "hybrid": ("HybridExecutor", 11, 4, 0),
+             "latent": ("LatentExecutor", 6, 4, 0),
+             "windowed": ("WindowExecutor", 6, 6, 0)}
+#: the page and state writers' arguments go to the device under
+#: ``kv.write`` / ``state.write``, which count their own dispatches
+WRITERS = ("serve.kv_write", "serve.state_write")
+
+
+class Seen:
+    """``jnp`` or ``jax`` as a serving module sees it: the calls that put
+    an array on the device or run a device op outside a program are
+    tallied (a call with a tracer among its arguments is a program's
+    own body being traced, and is not)."""
+
+    WATCHED = ("asarray", "array", "device_put", "argmax")
+
+    def __init__(self, module, tally):
+        self._module, self._tally = module, tally
+
+    def __getattr__(self, name):
+        real = getattr(self._module, name)
+        if name not in self.WATCHED:
+            return real
+
+        def counted(*args, **kw):
+            leaves = jax.tree_util.tree_leaves((args, kw))
+            if not any(isinstance(x, jax.core.Tracer) for x in leaves):
+                kind = "eager" if name == "argmax" else "h2d"
+                self._tally[kind] += 1
+                # jnp.asarray(x, dtype) converts on the device
+                dtype = args[1] if len(args) > 1 else kw.get("dtype")
+                if name in ("asarray", "array") and dtype is not None:
+                    self._tally["eager"] += 1
+            return real(*args, **kw)
+        return counted
+
+
+def watch(monkeypatch):
+    """Puts :class:`Seen` in the place of ``jnp`` and ``jax`` in the four
+    executor modules and the hand-over's own, and counts the host values
+    among the arguments of every program but the writers.  Returns the
+    tally."""
+    from paddle_tpu.analysis import CountedJit
+    from paddle_tpu.inference.server import (executor, handoff,
+                                             hybrid_executor,
+                                             latent_executor,
+                                             window_executor)
+
+    tally = {"h2d": 0, "eager": 0}
+    for mod in (executor, handoff, hybrid_executor, latent_executor,
+                window_executor):
+        for name in ("jnp", "jax"):
+            if hasattr(mod, name):
+                monkeypatch.setattr(mod, name,
+                                    Seen(getattr(mod, name), tally))
+    call = CountedJit.__call__
+
+    def counted(self, *args, **kw):
+        if self.name not in WRITERS:
+            tally["h2d"] += sum(
+                isinstance(x, (np.ndarray, np.generic, int, float))
+                for x in jax.tree_util.tree_leaves(args))
+        return call(self, *args, **kw)
+
+    monkeypatch.setattr(CountedJit, "__call__", counted)
+    return tally
+
+
+def steps_of(eng, tally, lens=(5, 19), new=4):
+    """Serves ``lens`` step by step: per step the spans it recorded, and
+    what ``tally`` saw handed to the device during it."""
+    rng = np.random.RandomState(0)
+    handles = [eng.submit(rng.randint(1, 256, (n,)).astype(np.int32),
+                          max_new_tokens=new) for n in lens]
+    out = []
+    while eng.scheduler.has_work():
+        n, before = len(obs.tracer().spans), dict(tally)
+        eng.step()
+        out.append(([s for s in list(obs.tracer().spans)[n:]
+                     if s.cat != "jit"],
+                    {k: tally[k] - before[k] for k in tally}))
+    assert all(len(h.tokens) == new for h in handles)
+    return out
+
+
+def claimed(spans):
+    """What a step's ``exec.prep`` and ``exec.fetch`` spans say was
+    handed to the device."""
+    marks = [s for s in spans if s.name in ("exec.prep", "exec.fetch")]
+    return {"h2d": sum(s.args.get("h2d", 0) for s in marks),
+            "eager": sum(s.args["eager"] for s in marks)}
+
+
+@pytest.mark.parametrize("which", EXECUTORS)
+def test_a_step_counts_what_it_hands_the_device(request, monkeypatch, which):
+    executor, chunk_spans, h2d, eager = EXECUTORS[which]
+    eng = ServingEngine(request.getfixturevalue(which), **ENGINE_KW)
+    assert type(eng.executor).__name__ == executor
+    steps_of(eng, {})               # every shape traced and compiled
+    obs.configure(mode="off", clock=LogicalClock())
+    steps = steps_of(eng, watch(monkeypatch))
+    spans = [s for step, _ in steps for s in step]
+    assert not any(s.args["traced"] for s in spans
+                   if s.name == "jit.dispatch")
+    # every blocking read says when the device drained, inside itself:
+    # the logical clock makes the three reads strictly ordered
+    fetches = [s for s in spans if s.name == "exec.fetch"]
+    # (the Llama path alone takes a prompt that fits one chunk whole)
+    assert {s.args["what"] for s in fetches} - {"prefill"} == {
+        "decode", "prefill_chunk"}
+    assert all(s.ts < s.args["ready"] < s.ts + s.dur for s in fetches)
+    # the counts are what was seen handed over, step by step
+    decode_only = 0
+    for step, seen in steps:
+        assert claimed(step) == seen, [(s.name, s.args) for s in step]
+        timed = [s for s in step if s.dur is not None]
+        chunks = sum(s.name == "req.prefill" for s in timed)
+        assert len(timed) <= 8 + chunk_spans * chunks, \
+            [s.name for s in timed]
+        if not chunks and any(s.name == "exec.prep" for s in step):
+            decode_only += 1
+            assert seen == {"h2d": h2d, "eager": eager}
+            (prep,) = [s for s in step if s.name == "exec.prep"]
+            (fetch,) = [s for s in step if s.name == "exec.fetch"]
+            assert prep.args["h2d"] == h2d
+            assert prep.args["eager"] + fetch.args["eager"] == eager
+    assert decode_only >= 2
+
+
+def test_a_transfer_beside_the_hand_over_is_caught(model, monkeypatch):
+    """What the test above stands on: a line in an executor that puts an
+    array on the device without going through ``Handoff`` is seen and
+    not claimed."""
+    from paddle_tpu.inference.server import executor
+
+    eng = ServingEngine(model, **ENGINE_KW)
+    steps_of(eng, {})
+    tally = watch(monkeypatch)
+    decode = eng.executor.decode
+
+    def decode_with_a_stray_line(sids):
+        executor.jnp.asarray(np.zeros((1,), np.int32))
+        return decode(sids)
+
+    monkeypatch.setattr(eng.executor, "decode", decode_with_a_stray_line)
+    steps = steps_of(eng, tally)
+    off = [seen["h2d"] - claimed(step)["h2d"] for step, seen in steps]
+    assert set(off) == {0, 1} and all(
+        d == any(s.name == "serve.decode" and s.args["batch"]
+                 for s in step) for d, (step, _) in zip(off, steps))
+
+
+def test_a_dtype_on_the_put_is_one_eager_convert(monkeypatch):
+    """``Handoff.put`` counts the ``convert_element_type`` that
+    ``jnp.asarray(x, dtype)`` runs on the device, and none without a
+    dtype: held to the primitives jax executes eagerly."""
+    from jax._src import dispatch
+
+    from paddle_tpu.inference.server.handoff import Handoff
+
+    ran = []
+    compiled = dispatch.xla_primitive_callable
+    monkeypatch.setattr(
+        dispatch, "xla_primitive_callable",
+        lambda prim, **kw: ran.append(prim.name) or compiled(prim, **kw))
+    io = Handoff()
+    io.put([3, 1, 2], jnp.int32)
+    assert (io.h2d, io.eager, ran) == (1, 1, ["convert_element_type"])
+    io.put(np.arange(3, dtype=np.int32))
+    io.host(np.zeros(2), np.int32(1))
+    assert (io.h2d, io.eager, ran) == (4, 1, ["convert_element_type"])
+    assert int(io.fetch("decode", lambda: io.argmax(jnp.ones((2, 5))))) == 0
+    assert io.eager == 2
