@@ -80,6 +80,7 @@ import jax.numpy as jnp
 from ... import obs
 from ...analysis import CountedJit
 from ...models import granite_hybrid as gh
+from ...models import moe
 from ...ops.pallas_kernels import ssm_decode as _ssm
 from ...ops.pallas_kernels.paged_decode import block_pages
 from ..paged import (
@@ -168,6 +169,8 @@ class SlotExecutor:
     sp_prefill_tokens = 0
     _sp_axis = None
     aot_ladder = None
+    #: layers of routed experts (an executor of such a model counts its own)
+    n_expert_layers = 0
 
     def sp_min_tokens_effective(self) -> int:
         return 0
@@ -189,6 +192,14 @@ class SlotExecutor:
     def prefill(self, sid: int, prompt_ids) -> int:
         """A whole prompt is a chunk that starts at 0 and is final."""
         return self.prefill_chunk(sid, prompt_ids, 0, True)
+
+    def grouped_expert_layers(self, tokens: int) -> int:
+        """The expert layers a chunk of ``tokens`` tokens runs as ONE
+        grouped product over rows sorted by expert (``models/moe.py``):
+        every expert layer of the model or, for a short chunk or a model
+        without routed experts, none.  ``Scheduler._prefill`` puts it on
+        the chunk's ``req.prefill`` span."""
+        return self.n_expert_layers if moe.grouped(tokens) else 0
 
     def _count_experts(self, counts):
         """One decode step's expert counter (int32 ``[expert layers,

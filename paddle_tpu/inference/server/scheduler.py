@@ -705,7 +705,7 @@ class Scheduler:
             final = start + chunk == total
             with obs.span("req.prefill", cat="serve", trace_id=req.rid,
                           start=start, tokens=chunk, final=final,
-                          tick=self.tick):
+                          tick=self.tick) as span:
                 try:
                     # page work FIRST, outside the per-request bracket:
                     # a pool-exhausted raise preempts (not fails) the
@@ -743,6 +743,12 @@ class Scheduler:
                             req.sid, ids[start:start + chunk], start,
                             final)
                     faults.fire("serve.request", "after")
+                    # an executor of routed experts says how many expert
+                    # layers ran this chunk as one grouped product
+                    grouped = getattr(self.executor, "grouped_expert_layers",
+                                      lambda tokens: 0)(chunk)
+                    if grouped:
+                        span.set(**{"experts.grouped_layers": grouped})
                 except RuntimeError as e:
                     if _POOL_EXHAUSTED in str(e):
                         # decodes ate the pages between admission and

@@ -45,7 +45,7 @@ stands in for the absent chips.
 The layer's parts are functions of plain arrays (one sequence,
 ``[T, ...]``), used by the eager model below and by the serving programs
 (``inference/server/latent_executor.py``) alike.  The router, the share
-and the loop over the held experts are ``models/moe.py``'s, the one expert
+and the held experts' products are ``models/moe.py``'s, the one expert
 layer every model with routed experts imports.
 """
 from __future__ import annotations
@@ -366,12 +366,11 @@ def feed_forward(cfg, kind, lp, x, held):
                            lp["mlp.down_proj.weight"]),
                 jnp.zeros((x.shape[0], 0), bool))
     sel, w = route(cfg, lp, h)
-    dense_w = held_weights(sel, w, held)
-    y = routed_experts(h, dense_w, lp["mlp.experts.gate_up_proj"],
+    y = routed_experts(h, sel, w, held, lp["mlp.experts.gate_up_proj"],
                        lp["mlp.experts.down_proj"])
     y = y + swiglu(h, lp["mlp.shared_experts.gate_up_proj.weight"],
                    lp["mlp.shared_experts.down_proj.weight"]).astype(_F32)
-    return x + y.astype(x.dtype), dense_w > 0
+    return x + y.astype(x.dtype), held_weights(sel, w, held) > 0
 
 
 def head(cfg, norm_w, lm_head, x):

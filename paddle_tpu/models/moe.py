@@ -7,6 +7,15 @@ arguments.  ``models/mla_moe.py`` and ``models/window_moe.py`` import
 them; the serving programs (``inference/server/latent_executor.py``,
 ``window_executor.py``) run them inside their chunk and decode programs.
 
+**Two forms, by the rows the function sees.**  A few rows (a decode step):
+every held expert sees every row in one batched product.  A long run of
+rows (a prefill chunk): the (token, held expert) pairs are sorted by
+expert (:func:`sorted_rows`) and go through ONE grouped SwiGLU
+(``ops/pallas_kernels/grouped_swiglu.py``: on the TPU a Pallas kernel
+that reads each expert's matrices where they lie, elsewhere
+``jax.lax.ragged_dot`` twice over the same rows), and each token gathers
+its own ``top_k`` results back and sums them in float32.
+
 **The share.**  A model is built with the ids of the routed experts whose
 weights it holds (one chip's share under expert parallelism, or all of
 them).  It routes over ALL experts with the whole router and computes
@@ -20,13 +29,15 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from .. import obs
+from ..ops.pallas_kernels import grouped_swiglu as _grouped
+
 _F32 = jnp.float32
+_I32 = jnp.int32
 #: tokens up to which the routed experts run as one batched product (a
-#: decode step); longer runs go expert by expert so that no
+#: decode step); longer runs go through the grouped product, so that no
 #: ``[experts, T, width]`` intermediate is ever held
 _BATCHED_EXPERT_ROWS = 256
-#: rows of a long run that go through an expert at a time
-_EXPERT_BLOCK = 128
 
 
 def swiglu_hidden(h, gate_up):
@@ -62,67 +73,96 @@ def held_weights(sel, w, held):
 def _layer_of(w):
     """An expert leaf ``[E, ...]`` as it is, or layer ``i`` of a stacked
     run given as ``(run [n, E, ...], i)``: addressed in the run, in place
-    (as a scan's per-layer slice the TPU compiler copies the layer's
-    experts, 1.6 GB at the published widths, before a long loop over
-    them)."""
+    (a scan's per-layer slice of the experts is a copy of 1.6 GB at the
+    published widths where the TPU compiler cannot fuse it away)."""
     if isinstance(w, tuple):
         return jax.lax.dynamic_index_in_dim(w[0], w[1], 0, keepdims=False)
     return w
 
 
-def _expert_of(w, e):
-    """Expert ``e``'s matrix of an expert leaf (see :func:`_layer_of`):
-    ONE slice of the run, never the layer's experts first."""
-    if isinstance(w, tuple):
-        run, i = w
-        at = [jnp.asarray(j, jnp.int32)
-              for j in (i, e, *[0] * (run.ndim - 2))]
-        return jax.lax.dynamic_slice(
-            run, at, (1, 1) + run.shape[2:]).reshape(run.shape[2:])
-    return jax.lax.dynamic_index_in_dim(w, e, 0, keepdims=False)
+def grouped(rows):
+    """Whether a run of ``rows`` tokens takes the grouped form."""
+    return rows > _BATCHED_EXPERT_ROWS
 
 
-def routed_experts(h, dense_w, gate_up, down):
-    """``sum_e dense_w[:, e] * E_e(h)`` over the held experts, float32
-    [T, H].  gate_up [E, H, 2F], down [E, F, H] (or each a layer of a
-    stacked run, :func:`_layer_of`).  Exact and dropless, with shapes that
-    do not depend on the routing.  A few tokens (a decode step): every
-    held expert sees every token in one batched product, and the routing
-    weight (zero for a token that did not choose it) scales the result;
-    the step is bound by the experts' bytes whatever the rows."""
-    if h.shape[0] <= _BATCHED_EXPERT_ROWS:
+def sorted_rows(sel, held, row_tile):
+    """Where the (token, held expert) pairs of ``sel`` [T, top_k] lie when
+    laid in the order of their experts, each expert's rows filled up to
+    whole tiles of ``row_tile`` rows, with shapes that do not depend on
+    the routing.  Compares, a running count and sums over ``[pairs, held]``
+    and ONE scatter of the pairs' numbers: no sort and no gather of single
+    numbers, which the TPU does one at a time (a sort of the pairs and a
+    row -> token map gathered by it cost 0.3 ms a call more on the chip:
+    PERF.md section 6, PR 37).  A dict of int32 arrays:
+
+    - ``place`` [T, top_k]: the laid row of each pair, token by token an
+      expert's rows; a pair whose expert is not held lies at ``rows``, one
+      past the last laid row;
+    - ``token`` [rows]: the token a laid row holds (0 for a row that fills
+      up a tile or lies past the live tiles); ``rows`` is what the worst
+      routing takes, every pair held and every expert's last tile holding
+      one row;
+    - ``group_rows`` [held]: the laid rows of each expert, filling counted;
+    - ``tile_expert`` [rows / row_tile] and ``live``: the expert of each
+      tile among the held, and how many tiles hold rows at all."""
+    (T, k), E = sel.shape, len(held)
+    P = T * k
+    tiles = (P + E * (row_tile - 1)) // row_tile
+    chose = sel.reshape(P, 1) == jnp.asarray(np.asarray(held, np.int32))
+    seen = jnp.cumsum(chose, axis=0, dtype=_I32)     # [P, E], this pair too
+    tiles_of = (seen[-1] + (row_tile - 1)) // row_tile
+    ends = jnp.cumsum(tiles_of)
+    laid = (ends - tiles_of) * row_tile              # an expert's first row
+    place = jnp.where(chose.any(axis=1),
+                      jnp.sum(jnp.where(chose, laid + seen - 1, 0), axis=1),
+                      tiles * row_tile)
+    token = jnp.zeros((tiles * row_tile,), _I32).at[place].set(
+        jnp.arange(P, dtype=_I32) // k, mode="drop", unique_indices=True)
+    tile_expert = jnp.minimum(jnp.sum(
+        jnp.arange(tiles, dtype=_I32)[:, None] >= ends, axis=1, dtype=_I32),
+        E - 1)
+    return dict(place=place.reshape(T, k), token=token,
+                group_rows=tiles_of * row_tile, tile_expert=tile_expert,
+                live=ends[-1])
+
+
+def routed_experts(h, sel, w, held, gate_up, down):
+    """``sum_{e in sel, e in held} w_e E_e(h)``, float32 [T, H]: sel, w
+    [T, top_k] as :func:`route` gives them, ``held`` the ids of the held
+    experts, gate_up [held, H, 2F], down [held, F, H] (or each a layer of
+    a stacked run, :func:`_layer_of`).  Exact and dropless, with shapes
+    that do not depend on the routing.  A few tokens (a decode step):
+    every held expert sees every token in one batched product, and the
+    routing weight (zero for a token that did not choose it) scales the
+    result; the step is bound by the experts' bytes whatever the rows."""
+    T, H = h.shape
+    if not grouped(T):
         gate_up, down = _layer_of(gate_up), _layer_of(down)
         gu = jnp.einsum("th,ehf->etf", h, gate_up)
         gate, up = jnp.split(gu, 2, axis=-1)
         y = jnp.einsum("etf,efh->eth", jax.nn.silu(gate) * up, down,
                        preferred_element_type=_F32)
-        return jnp.einsum("eth,te->th", y, dense_w)
-    # a long run of tokens (a prefill chunk): expert by expert, and of each
-    # expert only the blocks of rows that chose it.  The rows are ordered
-    # choosers first; a block's rows are gathered, go through the expert,
-    # are scaled by their routing weight (zero for a row that fills up the
-    # last block, so the sum stays exact) and are added back in place.
-    # Shapes do not depend on the routing, trip counts do: at 8 of 128
-    # experts a token a held expert is chosen by a sixteenth of the rows.
-    T, E = dense_w.shape
-    B = _EXPERT_BLOCK if T % _EXPERT_BLOCK == 0 else T
-    hit = dense_w > 0
-    order = jnp.argsort(~hit, axis=0, stable=True).astype(jnp.int32)
-    blocks = (jnp.sum(hit, axis=0, dtype=jnp.int32) + (B - 1)) // B
-
-    def one(e, acc):
-        rows = jax.lax.dynamic_index_in_dim(order, e, 1, keepdims=False)
-        w_e = jax.lax.dynamic_index_in_dim(dense_w, e, 1, keepdims=False)
-
-        def block(b, acc):
-            idx = jax.lax.dynamic_slice_in_dim(rows, b * B, B)
-            y = jnp.matmul(swiglu_hidden(h[idx], _expert_of(gate_up, e)),
-                           _expert_of(down, e), preferred_element_type=_F32)
-            return acc.at[idx].add(y * w_e[idx][:, None])
-
-        return jax.lax.fori_loop(
-            jnp.int32(0), jax.lax.dynamic_index_in_dim(blocks, e, 0, False),
-            block, acc)
-
-    return jax.lax.fori_loop(jnp.int32(0), jnp.int32(E), one,
-                             jnp.zeros(h.shape, _F32))
+        return jnp.einsum("eth,te->th", y, held_weights(sel, w, held))
+    # a long run of tokens (a prefill chunk): ONE grouped product over the
+    # pairs sorted by expert.  On the TPU every row tile belongs to one
+    # expert (whose matrices the kernel reads once a tile, where they lie);
+    # elsewhere the rows lie close and go through ragged_dot.  A token then
+    # gathers its own top_k results and sums them, scaled, in float32.
+    F = (down[0] if isinstance(down, tuple) else down).shape[-2]
+    kernel = _grouped.supported(H, F, _grouped._on_tpu())
+    row_tile = _grouped.ROW_TILE if kernel else 1
+    lay = sorted_rows(sel, held, row_tile)
+    place, rows = lay["place"], lay["token"].shape[0]
+    obs.instant("experts.grouped", cat="serve", pairs=sel.size,
+                row_tile=row_tile, tiles=rows // row_tile, kernel=kernel)
+    x = h[lay["token"]]
+    if kernel:
+        y = _grouped.grouped_swiglu(x, gate_up, down, lay["tile_expert"],
+                                    lay["live"])
+    else:
+        y = _grouped.grouped_swiglu_reference(
+            x, _layer_of(gate_up), _layer_of(down), lay["group_rows"])
+    # a row past the live tiles holds whatever the buffer held
+    mine = jnp.where((place < rows)[:, :, None],
+                     y[jnp.minimum(place, rows - 1)], 0.0)
+    return jnp.sum(mine * w[:, :, None], axis=1)

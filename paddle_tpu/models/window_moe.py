@@ -275,14 +275,14 @@ def feed_forward(cfg, dense, lp, x, held):
         took = jnp.zeros((x.shape[0], 0), bool)
     else:
         sel, w = route(cfg, lp, b)
-        dense_w = _moe.held_weights(sel, w, held)
-        y = _moe.routed_experts(b, dense_w, lp["mlp.experts.gate_up_proj"],
+        y = _moe.routed_experts(b, sel, w, held,
+                                lp["mlp.experts.gate_up_proj"],
                                 lp["mlp.experts.down_proj"])
         y = (y + _moe.swiglu(
             b, lp["mlp.shared_experts.gate_up_proj.weight"],
             lp["mlp.shared_experts.down_proj.weight"]).astype(_F32)) \
             .astype(x.dtype)
-        took = dense_w > 0
+        took = _moe.held_weights(sel, w, held) > 0
     return x + _norm(cfg, y, lp["post_mlp_layernorm.weight"]), took
 
 

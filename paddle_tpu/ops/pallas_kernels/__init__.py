@@ -13,6 +13,15 @@ designed for this framework's hot paths and profiles:
   hidden activation VMEM-resident per tile instead of an HBM
   round-trip.  Also carries the int8-expert-weight variant
   (``PT_QUANT=int8``) with dequant fused at the MXU.
+- ``grouped_swiglu``: the routed SwiGLU experts of a prefill chunk
+  (``models/moe.py``, serving) as one grouped product over rows sorted
+  by expert, every 128-row tile one expert's, dropless: the tile's
+  expert and the layer of a stacked run are prefetched scalars, so each
+  expert's gate / up / down matrices are read where they lie in
+  ``[E, H, 2F]`` / ``[E, F, H]`` (or ``[n, E, ..]``), in panels of F
+  that stay double-buffered in VMEM; float32 hidden rows and result.
+  (``grouped_gemm`` above is the TRAINING layer's: capacity buckets,
+  gelu, biases, dropped rows.)
 - ``paged_decode``: single-token decode attention over the paged KV
   pool, per sequence a double-buffered loop over 256-key blocks of
   the live pages (all KV heads of a page in one DMA) with an online

@@ -13,9 +13,13 @@ whole decode step (``serve.hybrid_decode``), its state kernel inside a
 scan, its state writer, and the page patch and the fused kernel's call on
 its folded KV pool; and, reading the pools without writing them,
 mistral7b-serve's prefill chunk (``serve.prefill_chunk``, its past gathered
-by row inside the layer scan).  Each has a control beside it or in it that
-shows the compiler's copies when the program is written the other way, so a
-serving program can be checked for pool copies before any chip time."""
+by row inside the layer scan); and the grouped expert kernel of a chunk
+(``ops/pallas_kernels/grouped_swiglu.py``) alone at both MoE cells' sizes
+and inside ``serve.window_chunk`` / ``serve.mla_chunk``, where no expert is
+sliced or copied out and no loop over experts is left.  Each pool test has
+a control beside it or in it that shows the compiler's copies when the
+program is written the other way, so a serving program can be checked for
+pool copies before any chip time."""
 import os
 import re
 import types
@@ -105,10 +109,12 @@ def compiled_kernel(monkeypatch):
     """Steer the program as the chip would: the fused kernel, compiled
     (here the backend is the CPU, which takes the dense path and the
     interpreter)."""
-    from paddle_tpu.ops.pallas_kernels import paged_decode
+    from paddle_tpu.ops.pallas_kernels import grouped_swiglu, paged_decode
 
     monkeypatch.setenv("PT_PAGED_IMPL", "pallas")
     monkeypatch.setattr(paged_decode, "_interpret", lambda: False)
+    monkeypatch.setattr(grouped_swiglu, "_on_tpu", lambda: True)
+    monkeypatch.setattr(grouped_swiglu, "_interpret", lambda: False)
 
 
 def mistral_executor(sds):
@@ -493,13 +499,18 @@ def test_the_latent_decode_step_moves_no_pool(sds, compiled_latent_kernel):
 
 
 @pytest.mark.parametrize("pages", [0, 40])
-def test_the_latent_chunk_reads_its_past_and_moves_no_pool(sds, pages):
+def test_the_latent_chunk_reads_its_past_and_moves_no_pool(
+        sds, compiled_kernel, pages):
     """serve.mla_chunk at a chunk of 1,024 tokens, first and last of a
     6,144-token prompt: the pool is read by page id inside the program
-    and never copied; the experts of a layer are never copied out of the
-    stacked run (1.6 GB a layer when the loop over experts took a scan's
-    slice of them: PERF.md section 6, PR 32); the temporaries stay under
-    1 GB (a block of heads' scores, one expert's hidden rows)."""
+    and never copied; the routed experts are ONE grouped kernel in the
+    scan's body, which reads each expert where it lies in the stacked
+    run: neither the experts of a layer (1.6 GB a layer when the loop
+    over experts took a scan's slice of them: PERF.md section 6, PR 32)
+    nor one expert's matrices (once a 128-row block until PR 37) are
+    sliced or copied out, and the only loops left are the scan over the
+    expert layers and the attention's blocks of heads; the temporaries
+    stay under 1 GB (a block of heads' scores, the sorted rows)."""
     ex, params, tops = latent_executor(sds)
     i32 = jnp.int32
     exe = jax.jit(ex._chunk_fwd).lower(
@@ -511,6 +522,9 @@ def test_the_latent_chunk_reads_its_past_and_moves_no_pool(sds, pages):
     assert mem.temp_size_in_bytes < 1 << 30
     assert not re.search(r"= bf16\[(1,)?32,(4096,4096|2048,4096)\]\S* "
                          r"(copy|transpose|fusion|dynamic-slice)\(", text)
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert not re.search(r"bf16\[1,1,(4096|2048),4096\]", text)
+    assert len(re.findall(r" while\(", text)) == 3
     assert len(re.findall(rf"= bf16\[{pages},128,640\]\S* gather\(",
                           text)) == (2 if pages else 0)
 
@@ -618,13 +632,17 @@ def test_the_window_decode_step_moves_no_pool(sds, compiled_kernel):
 
 
 @pytest.mark.parametrize("pages", [0, 88])
-def test_the_window_chunk_reads_its_past_and_moves_no_pool(sds, pages):
+def test_the_window_chunk_reads_its_past_and_moves_no_pool(
+        sds, compiled_kernel, pages):
     """serve.window_chunk at a chunk of 1,024 tokens, first and last of a
     12,288-token prompt: both groups' pools are read by page id inside
     the program (the full layer's 88 pages, a sliding layer's 16) and
-    never copied or written; no expert leaf is copied out for the loop
-    over 128 experts; the temporaries stay under 512 MB (a block of
-    heads' scores, one expert's hidden rows)."""
+    never copied or written; each of the four expert layers is ONE
+    grouped kernel that reads an expert where it lies in its leaf: no
+    expert's matrix is sliced or copied out (``bf16[1,2048,2048]`` once a
+    trip of a 128-trip loop until PR 37), and the only loops left are
+    the attention's blocks of heads, one a layer; the temporaries stay
+    under 512 MB (a block of heads' scores, the sorted rows)."""
     ex, params, tops = window_executor(sds)
     i32 = jnp.int32
     pools = [sds(FULL_POOL), sds(WINDOW_POOL)]
@@ -637,7 +655,36 @@ def test_the_window_chunk_reads_its_past_and_moves_no_pool(sds, pages):
     assert mem.alias_size_in_bytes == 0
     assert mem.temp_size_in_bytes < 512 << 20
     assert not EXPERTS_MOVED.search(text)
-    assert "tpu_custom_call" not in text        # stock attention (PERF.md)
+    # stock attention (PERF.md): the kernels are the experts'
+    assert text.count('custom_call_target="tpu_custom_call"') == 4
+    assert not re.search(r"bf16\[1,(2048|1024),2048\]", text)
+    assert len(re.findall(r" while\(", text)) == 6
+
+
+@pytest.mark.parametrize("experts, H, F, run", [(128, 2048, 1024, 1),
+                                                (32, 4096, 2048, 5)])
+def test_the_grouped_expert_kernel_compiles_at_the_cells_size(
+        sds, experts, H, F, run):
+    """The kernel alone at 8,192 pairs in tiles of 128 rows: Trinity-Mini's
+    128 experts in a leaf (a run of one), sarvam's 32 held in a stacked
+    run of 5 layers.  Mosaic takes it inside the VMEM limit the call asks
+    for (an expert's panels twice: 25 MB), the experts stay where they are
+    (arguments, no temporary) and the output is the tiles' float32
+    rows."""
+    from paddle_tpu.ops.pallas_kernels import grouped_swiglu as gs
+
+    i32 = jnp.int32
+    tiles = (8192 + experts * (gs.ROW_TILE - 1)) // gs.ROW_TILE
+    exe = gs._grouped_swiglu_call.lower(
+        sds((tiles * gs.ROW_TILE, H)), sds((run, experts, H, 2 * F)),
+        sds((run, experts, F, H)), sds((), i32), sds((tiles,), i32),
+        sds((), i32)).compile()
+    mem = exe.memory_analysis()
+    assert "tpu_custom_call" in exe.as_text()
+    assert mem.temp_size_in_bytes == 0
+    assert mem.output_size_in_bytes == tiles * gs.ROW_TILE * H * 4
+    assert 2 * 3 * H * gs.panel(H, F, 2) * 2 <= gs._PANEL_BYTES \
+        < gs._VMEM_LIMIT
 
 
 def test_the_window_page_writer_moves_no_pool(sds):

@@ -255,33 +255,38 @@ def test_eager_logits_and_rows_match_the_reference(model, reference):
         < LOGIT_TOL
 
 
-def test_a_loop_over_experts_equals_the_batched_product(model, monkeypatch):
+def test_the_grouped_product_equals_the_batched_product(model, monkeypatch):
+    """A long run of rows goes through the grouped product over rows
+    sorted by expert (off the TPU ``lax.ragged_dot``; the kernel itself:
+    tests/test_grouped_swiglu.py): the same layer as the batched product
+    of a decode step, at the row count that separates them and above."""
     cfg, lp = model.config, layer_params(model, 2)
+    held = model.held_experts
     x = jax.random.normal(jax.random.PRNGKey(3), (19, 64))
-    batched, took = mm.feed_forward(cfg, "mla_moe", lp, x,
-                                    model.held_experts)
+    batched, took = mm.feed_forward(cfg, "mla_moe", lp, x, held)
     monkeypatch.setattr(moe, "_BATCHED_EXPERT_ROWS", 0)
-    looped, took2 = mm.feed_forward(cfg, "mla_moe", lp, x,
-                                    model.held_experts)
-    assert rel(looped, batched) < 1e-6
-    # blocks of 8 rows: an expert chosen by 3 rows takes one block (five
-    # rows of it scaled by zero), one chosen by 11 two, one by none none
-    x24 = jax.random.normal(jax.random.PRNGKey(5), (24, 64))
-    whole24, took24 = mm.feed_forward(cfg, "mla_moe", lp, x24,
-                                      model.held_experts)
-    monkeypatch.setattr(moe, "_EXPERT_BLOCK", 8)
-    blocked, _ = mm.feed_forward(cfg, "mla_moe", lp, x24,
-                                 model.held_experts)
-    assert rel(blocked, whole24) < 1e-6
-    per_expert = np.asarray(took24).sum(0)
-    assert per_expert.max() > 8 and (per_expert % 8).any()
+    grouped, took2 = mm.feed_forward(cfg, "mla_moe", lp, x, held)
+    assert rel(grouped, batched) < 1e-6
     assert np.array_equal(took, took2) and took.shape == (19, 4)
+    # the held experts are a share: most pairs lie on none of them
+    assert 0 < np.asarray(took).sum() < 19 * cfg.num_experts_per_tok / 2
     # a layer of a stacked run, addressed in place, is that layer
     run = {k: (jnp.stack([v * 0, v]), jnp.int32(1)) if "experts." in k
            and "shared" not in k else v for k, v in lp.items()}
-    in_place, _ = mm.feed_forward(cfg, "mla_moe", run, x,
-                                  model.held_experts)
-    assert rel(in_place, looped) < 1e-6
+    in_place, _ = mm.feed_forward(cfg, "mla_moe", run, x, held)
+    assert rel(in_place, grouped) < 1e-6
+    # the choice is the row count the function sees, and nothing else
+    monkeypatch.undo()
+    assert not moe.grouped(256) and moe.grouped(257)
+    x300 = jax.random.normal(jax.random.PRNGKey(5), (300, 64))
+    obs.tracer().configure()
+    long_run, _ = mm.feed_forward(cfg, "mla_moe", lp, x300, held)
+    assert [s.args["pairs"] for s in obs.tracer().spans
+            if s.name == "experts.grouped"] \
+        == [300 * cfg.num_experts_per_tok]
+    monkeypatch.setattr(moe, "_BATCHED_EXPERT_ROWS", 300)
+    whole, _ = mm.feed_forward(cfg, "mla_moe", lp, x300, held)
+    assert rel(long_run, whole) < 1e-6
 
 
 # -- the share ------------------------------------------------------------
@@ -470,6 +475,35 @@ def test_the_latent_pool_is_one_pool_behind_the_same_page_table(model):
         PagedKVCache(2, 2, 128, 8, latent=True)
     with pytest.raises(NotImplementedError, match="latent pool"):
         PagedKVCache(2, 1, 128, 8, latent=True, quant="int8")
+
+
+def test_a_long_chunk_runs_its_experts_grouped(model, monkeypatch):
+    """With the batched product kept to 4 rows (so that a toy chunk of 8
+    is a long run and a decode step is not), the engine serves the same
+    tokens, a chunk's ``req.prefill`` span says how many expert layers
+    ran grouped (absent on the chunk of 3 rows), and the scanned run's
+    one traced body leaves its static sizes once a chunk program."""
+    prompts = [prompt(27, 12), prompt(6, 13)]
+    _, want = serve(model, prompts, new=5)
+    monkeypatch.setattr(moe, "_BATCHED_EXPERT_ROWS", 4)
+    obs.reset()
+    eng, got = serve(model, prompts, new=5)
+    assert got == want
+    spans = list(obs.tracer().spans)
+    chunks = [s.args for s in spans if s.name == "req.prefill"]
+    assert sorted(c["tokens"] for c in chunks) == [3, 6, 8, 8, 8]
+    layers = eng.executor.n_expert_layers
+    assert layers == CFG["num_hidden_layers"] - 1
+    assert all(c.get("experts.grouped_layers") == (layers if c["tokens"] > 4
+                                                   else None)
+               for c in chunks)
+    k = model.config.num_experts_per_tok
+    sizes = [s.args for s in spans if s.name == "experts.grouped"]
+    programs = {s.args["tokens"] for s in spans if s.name == "exec.prep"
+                and s.args.get("tokens", 0) > 4}
+    assert len(sizes) >= len(programs) == 2
+    assert all(a["pairs"] in (6 * k, 8 * k) and a["tiles"] == a["pairs"]
+               and a["row_tile"] == 1 and not a["kernel"] for a in sizes)
 
 
 def test_the_decode_program_counts_the_rows_each_held_expert_took(model):

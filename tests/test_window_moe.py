@@ -506,23 +506,26 @@ def test_admission_refuses_when_only_the_window_pool_is_short(model,
 
 # -- the share -------------------------------------------------------------------------
 
-def test_the_shares_add_up_to_the_uncut_layer(model, reference):
+@pytest.mark.parametrize("rows", [19, 300])
+def test_the_shares_add_up_to_the_uncut_layer(model, reference, rows):
     """Four models that hold experts 0..3, 4..7, 8..11, 12..15 of an
     expert layer, with the layer's own weights: the routed parts add up,
     the shared expert counted once, to what the model that holds all 16
-    gives, and to the reference's layer."""
+    gives, and to the reference's layer: in a decode step's batched
+    product (19 rows) and in a chunk's grouped product (300)."""
     cfg = model.config
     lp = layer_params(model, 2)
-    x = jax.random.normal(jax.random.PRNGKey(4), (19, 64))
+    assert moe.grouped(rows) == (rows == 300)
+    x = jax.random.normal(jax.random.PRNGKey(4), (rows, 64))
     b = wm._norm(cfg, x, lp["pre_mlp_layernorm.weight"])
     sel, w = wm.route(cfg, lp, b)
     shared = moe.swiglu(b, lp["mlp.shared_experts.gate_up_proj.weight"],
                         lp["mlp.shared_experts.down_proj.weight"])
     whole = moe.routed_experts(
-        b, moe.held_weights(sel, w, range(16)),
+        b, sel, w, range(16),
         lp["mlp.experts.gate_up_proj"], lp["mlp.experts.down_proj"])
     parts = [moe.routed_experts(
-        b, moe.held_weights(sel, w, range(lo, lo + 4)),
+        b, sel, w, range(lo, lo + 4),
         lp["mlp.experts.gate_up_proj"][lo:lo + 4],
         lp["mlp.experts.down_proj"][lo:lo + 4]) for lo in range(0, 16, 4)]
     assert rel(sum(parts), whole) < 1e-6
@@ -601,6 +604,33 @@ def test_a_model_without_a_full_layer_is_refused():
 
 # -- spans and counters -------------------------------------------------------------------
 
+def test_a_long_chunk_runs_its_experts_grouped(model, monkeypatch):
+    """With the batched product kept to 4 rows (so that a toy chunk of 8
+    is a long run and a decode step of 2 is not), the engine serves the
+    same tokens, a chunk's ``req.prefill`` span says how many expert
+    layers ran grouped (absent on the chunk of 3 rows), and each expert
+    layer of each traced chunk program leaves its static sizes."""
+    prompts = [prompt(27, 12), prompt(6, 13)]
+    _, want = serve(model, prompts, new=5)
+    monkeypatch.setattr(moe, "_BATCHED_EXPERT_ROWS", 4)
+    obs.reset()
+    eng, got = serve(model, prompts, new=5)
+    assert got == want
+    spans = list(obs.tracer().spans)
+    chunks = [s.args for s in spans if s.name == "req.prefill"]
+    assert sorted(c["tokens"] for c in chunks) == [3, 6, 8, 8, 8]
+    assert eng.executor.n_expert_layers == 4
+    assert all(c.get("experts.grouped_layers") == (4 if c["tokens"] > 4
+                                                   else None)
+               for c in chunks)
+    sizes = [s.args for s in spans if s.name == "experts.grouped"]
+    assert sizes and len(sizes) % 4 == 0
+    assert all(a["pairs"] in (6 * 4, 8 * 4) and a["tiles"] == a["pairs"]
+               and a["row_tile"] == 1 and not a["kernel"] for a in sizes)
+    assert not any("experts.grouped_layers" in s.args for s in spans
+                   if s.name != "req.prefill")
+
+
 def test_spans_and_counters_of_a_run(model):
     obs.reset()
     eng, _ = serve(model, [prompt(27, 12), prompt(6, 13)], new=11)
@@ -614,6 +644,9 @@ def test_spans_and_counters_of_a_run(model):
     chunks = [s for s in spans if s.name == "req.prefill"]
     writes = [s for s in spans if s.name == "kv.write"]
     assert len(writes) == len(chunks) == 5      # 4 + 1 chunks, one write each
+    # chunks of 8 rows run their experts as a decode step does: batched
+    assert not any("experts.grouped_layers" in s.args for s in spans)
+    assert "experts.grouped" not in names
     assert all(s.args["dispatches"] == 1 for s in writes)
     released = [s.args for s in spans if s.name == "kv.release"]
     ex = eng.executor
