@@ -12,16 +12,39 @@ in the latent's space, already scaled (``models/mla_moe.absorb_query``):
     u[h]    = sum_t p[h, t] row[t, :rank]
 
 One program a sequence, all heads at once (the heads are the matmul's
-rows: 64 of them against one shared page).  The pages are fetched by the
-page table UP TO THE SEQUENCE'S LENGTH — a loop over ``cdiv(length,
-page_size)`` pages, double-buffered, with an online softmax — never the
-``max_len`` window.  The pool is the flat view ``[layers * pages,
-page_size, width]`` (``inference/paged._flat``) and stays in HBM: the
-layer is a prefetched scalar and page ``i`` of sequence ``b`` is row
-``layer * pages + table[b, i]``, so nothing the size of a layer is ever
-sliced out, and inside a ``lax.scan`` over layers the pool is the carry.
-A sequence of length 0 (a slot that is not live) reads nothing and
-returns zeros.
+rows: 64 of them against the same rows).  The program walks the live
+pages of the sequence's table — UP TO ITS LENGTH, never the ``max_len``
+window — in BLOCKS of whole pages (:func:`block_pages`: 512 keys, 4
+pages of 128), ``cdiv(live pages, pages a block)`` trips by the
+prefetched length:
+
+    start the DMAs of block j + 1's live pages   (the other VMEM slot)
+    wait for block j's
+    s      = q @ rows^T                  [heads, block]   float32
+    s      masked to col < length
+    m, l, acc  <- online softmax over the blocks, acc += p @ rows[:, :rank]
+
+and divides once at the end.  A page is ONE DMA (``[page_size, width]``,
+160 KB at the serving cell's size), so the block's copies fly while the
+block before it is multiplied, and each trip's fixed cost — the wait,
+the loop, the small products' fill and drain on the MXU, the softmax's
+bookkeeping — is paid once a block and not once a page (one page a trip
+ran at a third of the roofline, blocks of 2 to 12 pages all at ~60 % of
+it: PERF.md section 6).  The tail: pages of the last block past the
+live ones are not fetched, and their rows are zeroed in VMEM, which
+holds what an earlier program left — a NaN bit pattern there would
+poison ``p @ rows`` even at p == 0; rows of the last live page past the
+length are the pool's own (written or zero), masked out of the scores.
+
+The pool is the flat view ``[layers * pages, page_size, width]``
+(``inference/paged._flat``) and stays in HBM: the layer is a prefetched
+scalar and page ``i`` of sequence ``b`` is row ``layer * pages +
+table[b, i]``, so nothing the size of a layer is ever sliced out, and
+inside a ``lax.scan`` over layers the pool is the carry.  A sequence of
+length 0 (a slot that is not live) reads nothing and returns zeros.
+The products take their operands in the pool's dtype with float32
+accumulation; max, exp, sums, the accumulator and the divide are
+float32.
 
 Off the TPU the same attention is :func:`mla_decode_reference` in
 ``jax.numpy`` (a dense gather of every window), which is also the
@@ -35,6 +58,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ... import obs
 
 LANES = 128
 
@@ -70,57 +95,95 @@ def mla_decode_reference(q, pool, layer, pages, lengths, tables, rank):
     return (u / jnp.maximum(p.sum(-1, keepdims=True), 1e-30)).astype(q.dtype)
 
 
+BLOCK_KEYS = 512
+VMEM_BUDGET = 4 << 20
+
+
+def block_pages(page_size, width, itemsize):
+    """Pages in one block of :func:`_kernel`'s walk: whole pages that make
+    :data:`BLOCK_KEYS` keys — fewer where the scratch, two slots of a
+    block's rows, would pass :data:`VMEM_BUDGET`, and one page where a
+    page alone fills it.  Follows from the pool's shape alone."""
+    keys = min(BLOCK_KEYS, VMEM_BUDGET // (2 * width * itemsize))
+    return max(1, keys // page_size)
+
+
 def _kernel(len_ref, tbl_ref, layer_ref, q_ref, pool_hbm, o_ref, buf, sem, *,
             page_size, pages, rank):
+    # Scalars are explicitly i32 and combined by lax ops: the repo's
+    # global x64 mode turns weak Python-int constants into i64 at
+    # lowering, which Mosaic refuses.
+    lax, i32 = jax.lax, jnp.int32
     b = pl.program_id(0)
-    # every scalar explicitly i32 (the repo's global x64 mode would turn
-    # weak Python ints into i64, which Mosaic refuses)
-    ps = jnp.int32(page_size)
+    zero, one, two = i32(0), i32(1), i32(2)
+    ps, bp = i32(page_size), i32(buf.shape[1] // page_size)
     length = len_ref[b]
-    n = pl.cdiv(length, ps)
-    base = layer_ref[0] * jnp.int32(pages)
+    npages = lax.div(lax.add(length, lax.sub(ps, one)), ps)
+    nblocks = lax.div(lax.add(npages, lax.sub(bp, one)), bp)
+    base = lax.mul(layer_ref[0], i32(pages))
 
-    def fetch(i, slot):
-        return pltpu.make_async_copy(
-            pool_hbm.at[base + tbl_ref[b, i]], buf.at[slot], sem.at[slot])
+    def place(g):
+        """Where page ``g`` of the sequence lands: the slot of its block
+        and its rows there."""
+        rows = lax.mul(lax.rem(g, bp), ps)
+        return (lax.rem(lax.div(g, bp), two),
+                pl.ds(pl.multiple_of(rows, page_size), page_size))
 
-    @pl.when(n > 0)
-    def _first():
-        fetch(jnp.int32(0), jnp.int32(0)).start()
+    def copy(g, pid):
+        slot, rows = place(g)
+        return pltpu.make_async_copy(pool_hbm.at[pid], buf.at[slot, rows],
+                                     sem.at[slot])
+
+    def start(g, carry):
+        copy(g, lax.add(base, tbl_ref[b, g])).start()
+        return carry
+
+    def wait(g, carry):
+        copy(g, zero).wait()             # a wait names no source
+        return carry
+
+    def scrub(g, carry):
+        slot, rows = place(g)
+        buf[slot, rows] = jnp.zeros((page_size, buf.shape[2]), buf.dtype)
+        return carry
 
     q = q_ref[0]                                         # [heads, W]
     heads = q.shape[0]
+    cols = lax.broadcasted_iota(i32, (heads, buf.shape[1]), 1)
+    masked = jnp.full(cols.shape, -1e30, jnp.float32)
 
-    def page(i, carry):
+    def block(j, carry):
         m, l, acc = carry
-        slot = jax.lax.rem(i, jnp.int32(2))
+        here = lax.mul(j, bp)                    # the block's pages:
+        ahead = lax.add(here, bp)                # [here, ahead)
+        live = lax.min(npages, ahead)            # those with keys end here
+        # start the NEXT block's live pages (in the first trip this
+        # block's too), so their copies fly while this block is multiplied
+        lax.fori_loop(lax.select(lax.eq(j, zero), zero, ahead),
+                      lax.min(npages, lax.add(ahead, bp)), start, 0)
+        lax.fori_loop(here, live, wait, 0)       # wait for this block's
+        # zero the rows past the live pages (the last block's): VMEM
+        # scratch holds what an earlier program left
+        lax.fori_loop(live, ahead, scrub, 0)
 
-        @pl.when(i + 1 < n)
-        def _next():
-            fetch(i + 1, 1 - slot).start()
-
-        fetch(i, slot).wait()
-        rows = buf[slot]                                 # [ps, W]
-        s = jax.lax.dot_general(q, rows, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        col = i * ps + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        seen = col < length
-        s = jnp.where(seen, s, jnp.float32(-1e30))
+        rows = buf[lax.rem(j, two)]                      # [block, W]
+        s = lax.dot_general(q, rows, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+        s = lax.select(cols < lax.sub(length, lax.mul(here, ps)), s, masked)
         m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m - m_new)
-        # a page's tail past the length holds whatever the page held
-        # before: its weight is exactly zero
-        p = jnp.where(seen, jnp.exp(s - m_new), jnp.float32(0.0))
+        p = jnp.exp(s - m_new)           # exactly 0 past the length
         l = alpha * l + jnp.sum(p, axis=1, keepdims=True)
-        pv = jax.lax.dot_general(p.astype(rows.dtype), rows[:, :rank],
-                                 (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
+        pv = lax.dot_general(p.astype(rows.dtype), rows[:, :rank],
+                             (((1,), (0,)), ((), ())),
+                             preferred_element_type=jnp.float32)
         return m_new, l, alpha * acc + pv
 
     m0 = jnp.full((heads, 1), -1e30, jnp.float32)
     l0 = jnp.zeros((heads, 1), jnp.float32)
     acc0 = jnp.zeros((heads, rank), jnp.float32)
-    _, l, acc = jax.lax.fori_loop(jnp.int32(0), n, page, (m0, l0, acc0))
+    _, l, acc = lax.fori_loop(zero, nblocks, block, (m0, l0, acc0))
+    # a sequence of length 0 (a slot that is not live) read nothing: zeros
     o_ref[0] = (acc / jnp.maximum(l, jnp.float32(1e-30))).astype(o_ref.dtype)
 
 
@@ -131,6 +194,7 @@ def _mla_decode_call(q, pool, layer, lengths, tables, pages, rank,
     ``_mla_decode_call [tpu_custom_call]`` after it."""
     S, heads, W = q.shape
     ps = pool.shape[1]
+    block = ps * block_pages(ps, W, pool.dtype.itemsize)
     kernel = functools.partial(_kernel, page_size=ps, pages=pages, rank=rank)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,          # lengths, page table, layer
@@ -142,7 +206,7 @@ def _mla_decode_call(q, pool, layer, lengths, tables, pages, rank,
         ],
         out_specs=pl.BlockSpec((1, heads, rank), lambda b, lens, tbl, layer:
                                (b, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((2, ps, W), pool.dtype),
+        scratch_shapes=[pltpu.VMEM((2, block, W), pool.dtype),  # two slots
                         pltpu.SemaphoreType.DMA((2,))],
     )
     with jax.enable_x64(False):
@@ -161,7 +225,14 @@ def _on_tpu():
 def mla_decode(q, pool, layer, pages, lengths, tables, rank):
     """One token's absorbed-form attention for every sequence of one
     layer; shapes as :func:`mla_decode_reference`."""
-    if supported(q.shape[-1], rank, pool.shape[1], _on_tpu()):
+    ps, W = pool.shape[1], q.shape[-1]
+    kernel = supported(W, rank, ps, _on_tpu())
+    # at trace time, once a traced call: which form ran, at what block
+    # (0: the reference's dense gather, no walk)
+    obs.instant("attn.mla_decode", cat="serve", kernel=kernel,
+                block_pages=block_pages(ps, W, pool.dtype.itemsize)
+                if kernel else 0)
+    if kernel:
         return _mla_decode_call(q, pool, layer, lengths, tables,
                                 pages=int(pages), rank=int(rank))
     return mla_decode_reference(q, pool, layer, pages, lengths, tables, rank)

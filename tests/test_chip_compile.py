@@ -532,16 +532,26 @@ def test_the_latent_chunk_reads_its_past_and_moves_no_pool(
 def test_the_latent_kernel_compiles_at_the_cells_size(sds):
     """The kernel alone: 64 sequences x 64 heads on a shared 640-lane row,
     pages of 128 fetched by the table from a 3.36 GB pool that stays in
-    HBM (an argument, no temporary)."""
+    HBM (an argument, no temporary), walked in the blocks the pool's
+    shape picks: 4 pages of 128, two slots of 640 KB in VMEM."""
     from paddle_tpu.ops.pallas_kernels import mla_decode
 
     i32 = jnp.int32
+    args = (sds((64, 64, 640)), sds((5 * 4096, 128, 640)), sds((), i32),
+            sds((64,), i32), sds((64, 64), i32))
+    assert mla_decode.block_pages(128, 640, 2) == 4
     exe = mla_decode._mla_decode_call.lower(
-        sds((64, 64, 640)), sds((5 * 4096, 128, 640)), sds((), i32),
-        sds((64,), i32), sds((64, 64), i32), pages=4096, rank=512).compile()
+        *args, pages=4096, rank=512).compile()
     mem = exe.memory_analysis()
     assert mem.temp_size_in_bytes == 0
     assert mem.output_size_in_bytes == 64 * 64 * 512 * 2
+    traced = jax.make_jaxpr(
+        lambda *a: mla_decode._mla_decode_call(*a, pages=4096, rank=512))(
+            *args).jaxpr.eqns[0].params["jaxpr"].jaxpr
+    calls = [e for e in traced.eqns if e.primitive.name == "pallas_call"]
+    assert len(calls) == 1
+    rows = calls[0].params["grid_mapping"].scratch_avals[0]
+    assert (rows.shape, rows.dtype) == ((2, 512, 640), jnp.bfloat16)
 
 
 def test_the_latent_page_writer_moves_no_pool(sds):

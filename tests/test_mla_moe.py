@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 
 import paddle_tpu as paddle
 from chipbench import program_mla_moe, reference_mla_moe, weights_mla_moe
@@ -524,35 +525,121 @@ def test_the_decode_program_counts_the_rows_each_held_expert_took(model):
 
 # -- the decode kernel -------------------------------------------------------
 
-@pytest.mark.parametrize("lengths", [[37, 0, 64, 1], [16, 15, 17, 48]])
-def test_pallas_kernel_in_interpret_mode(lengths):
-    """The compiled kernel's layout (rows of 256 lanes, a latent of 128,
-    bf16 pages of 16) through the Pallas interpreter against the
-    ``jax.numpy`` form, at lengths that end inside a page, on a page's
-    edge and at zero (a slot that is not live reads nothing)."""
-    S, heads, W, rank, ps, pages, L = 4, 8, 256, 128, 16, 12, 3
-    assert mla_decode.supported(W, rank, ps, on_tpu=True)
-    assert not mla_decode.supported(40, 32, 4, on_tpu=True)
-    assert mla_decode.padded_width(576) == 640
+def walk_matches_the_numpy_form(lengths, ps, per_seq):
+    """Four sequences of ``lengths`` through the compiled kernel's layout
+    (rows of 256 lanes, a latent of 128, bf16 pages of ``ps``, tables of
+    ``per_seq`` pages, each sequence its own pages) in the Pallas TPU
+    interpreter — DMAs land at their wait, VMEM scratch starts as NaN —
+    against the ``jax.numpy`` form, on two layers of three; then again
+    with every page that no sequence reads up to its length — those past
+    each length, and those the unused table entries name — full of NaN:
+    nothing past the live pages reaches the output.  A sequence of length
+    0 (a slot that is not live) reads nothing and returns zeros."""
+    S, heads, W, rank, L = 4, 8, 256, 128, 3
+    pages = S * per_seq
     q = jax.random.normal(jax.random.PRNGKey(0), (S, heads, W)) \
         .astype(jnp.bfloat16)
     pool = jax.random.normal(jax.random.PRNGKey(1), (L * pages, ps, W)) \
         .astype(jnp.bfloat16)
-    tables = jnp.asarray(
-        np.random.default_rng(2).integers(0, pages, (S, 4)), jnp.int32)
+    tables = jnp.asarray(np.random.default_rng(2).permutation(pages)
+                         .reshape(S, per_seq), jnp.int32)
     lens = jnp.asarray(lengths, jnp.int32)
+    live = np.asarray(lens) > 0
     for layer in (0, 2):
-        want = mla_decode.mla_decode_reference(
-            q, pool, jnp.int32(layer), pages, lens, tables, rank)
-        got = mla_decode._mla_decode_call(
-            q, pool, jnp.int32(layer), lens, tables, pages=pages, rank=rank,
-            interpret=True)
-        assert got.shape == (S, heads, rank)
-        live = np.asarray(lens) > 0
-        np.testing.assert_allclose(
-            np.asarray(got, np.float32)[live],
-            np.asarray(want, np.float32)[live], atol=2e-2)
-        assert not np.asarray(got, np.float32)[~live].any()
+        read = np.zeros(L * pages, bool)
+        for t, n in zip(np.asarray(tables), lengths):
+            read[layer * pages + t[:-(-n // ps)]] = True
+        poisoned = jnp.where(jnp.asarray(read)[:, None, None], pool,
+                             jnp.nan).astype(pool.dtype)
+        want = np.asarray(mla_decode.mla_decode_reference(
+            q, pool, jnp.int32(layer), pages, lens, tables, rank), np.float32)
+        for held in (pool, poisoned):
+            got = mla_decode._mla_decode_call(
+                q, held, jnp.int32(layer), lens, tables, pages=pages,
+                rank=rank, interpret=pltpu.InterpretParams())
+            assert got.shape == (S, heads, rank)
+            got = np.asarray(got, np.float32)
+            np.testing.assert_allclose(got[live], want[live], atol=2e-2)
+            assert not got[~live].any()
+
+
+@pytest.mark.parametrize("lengths", [
+    [37, 0, 64, 1], [16, 15, 17, 48],
+    # several blocks of 32 pages (512 keys) each
+    [1152, 1100, 600, 1025],
+    # ends inside a block's last page, on a block's edge, one past it
+    [511, 512, 513, 1024],
+    # one page, one token, nothing
+    [16, 1, 0, 17],
+    # nothing first and last
+    [0, 1100, 513, 0],
+])
+def test_pallas_kernel_in_interpret_mode(lengths):
+    """Pages of 16, so a block of 512 keys is 32 pages: lengths that end
+    inside a page, on a page's or a block's edge, span several blocks,
+    and zero (:func:`walk_matches_the_numpy_form`)."""
+    assert mla_decode.supported(256, 128, 16, on_tpu=True)
+    assert not mla_decode.supported(40, 32, 4, on_tpu=True)
+    assert mla_decode.padded_width(576) == 640
+    assert mla_decode.block_pages(16, 256, 2) == 32
+    walk_matches_the_numpy_form(lengths, ps=16, per_seq=72)
+
+
+@pytest.mark.parametrize("lengths", [
+    [2048, 1, 128, 129],        # four blocks; one token; a page's edge
+    [512, 513, 1536, 1535],     # a block's edge and one past; 3 blocks
+    [0, 2000, 0, 640],          # nothing around the longest
+    [300, 700, 1100, 1900],     # last blocks of 3, 2, 1, 3 pages
+])
+def test_the_walk_in_blocks_of_the_cells_four_pages(lengths):
+    """Pages of 128 as in the serving cell's pool, so a block is 4 pages,
+    and tables of 16 pages: up to four blocks a sequence, a last block
+    of 1 to 4 live pages (:func:`walk_matches_the_numpy_form`)."""
+    assert mla_decode.block_pages(128, 256, 2) == 4
+    walk_matches_the_numpy_form(lengths, ps=128, per_seq=16)
+
+
+@pytest.mark.parametrize("ps, width, itemsize, pages", [
+    (128, 640, 2, 4),           # the serving cell's pool: 512 keys
+    (16, 256, 2, 32),
+    (64, 576, 2, 8),
+    (128, 640, 4, 4),           # a float32 pool: 2.6 MB, in the budget
+    (128, 4096, 2, 2),          # the budget binds: 256 keys
+    (16, 8192, 4, 4),           # 64 keys of 16
+    (256, 2048, 4, 1),          # two slots of one page fill it
+    (4096, 640, 2, 1),          # a page alone fills it
+    (1024, 4096, 4, 1),
+])
+def test_the_block_follows_the_pools_shape(ps, width, itemsize, pages):
+    """512 keys of whole pages, fewer where two slots of a block would
+    pass the VMEM budget, one where a page alone fills it."""
+    n = mla_decode.block_pages(ps, width, itemsize)
+    assert n == pages
+    assert n == 1 or (n * ps <= mla_decode.BLOCK_KEYS and
+                      2 * n * ps * width * itemsize <= mla_decode.VMEM_BUDGET)
+
+
+def test_the_kernels_form_and_block_are_recorded_once_a_trace(monkeypatch):
+    """``attn.mla_decode`` records at trace time which form ran and at
+    what block: the kernel at the serving cell's pool (pages of 128 x 640
+    lanes: 4 pages a block), the ``jax.numpy`` form off the TPU (0)."""
+    sds = jax.ShapeDtypeStruct
+    i32 = jnp.int32
+    args = (sds((64, 64, 640), jnp.bfloat16),
+            sds((5 * 4096, 128, 640), jnp.bfloat16), sds((), i32),
+            sds((64,), i32), sds((64, 64), i32))
+
+    def traced():
+        obs.reset()
+        jax.eval_shape(lambda q, pool, layer, lengths, tables:
+                       mla_decode.mla_decode(q, pool, layer, 4096, lengths,
+                                             tables, 512), *args)
+        return [s.args for s in obs.tracer().spans
+                if s.name == "attn.mla_decode"]
+
+    assert traced() == [{"kernel": False, "block_pages": 0}]
+    monkeypatch.setattr(mla_decode, "_on_tpu", lambda: True)
+    assert traced() == [{"kernel": True, "block_pages": 4}]
 
 
 def test_the_kernels_numpy_form_is_the_absorbed_attention(model):
